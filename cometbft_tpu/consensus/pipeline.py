@@ -468,11 +468,11 @@ class CommitPipeline:
     def prestage_next(self, validator_set) -> None:
         """While H's durable suffix drains: warm H+1's device windows —
         the next validator set's expanded pubkeys into the PubkeyArena
-        (crypto/batch.prestage_validators) and the hash plane's device
-        path (crypto/hashplane.prewarm), so the proposer's PartSet
-        build and the first verify windows of H+1 form without a cold
-        start.  Pure cache warm-up: results are bit-identical with or
-        without it, so inline/sim runs skip it entirely."""
+        (crypto/batch.prestage_validators), so the first verify windows
+        of H+1 form without a builder launch.  Pure cache warm-up:
+        results are bit-identical with or without it, so inline/sim
+        runs skip it entirely.  (Kernel shapes warm themselves: the
+        planes keep a cold shape on host while ops/warm compiles it.)"""
         if self.inline:
             return
 
@@ -480,12 +480,12 @@ class CommitPipeline:
             try:
                 with libdevledger.caller_class("proposal"):
                     from ..crypto import batch as crypto_batch
-                    from ..crypto import hashplane as crypto_hashplane
 
                     crypto_batch.prestage_validators(vs)
-                    crypto_hashplane.prewarm()
             except Exception:
-                pass  # warm-up must never take anything down
+                # warm-up must never take anything down
+                # (ops/verify.prestage_pubkeys counts its own faults)
+                pass
 
         alive = [t for t in self._prestage_threads if t.is_alive()]
         t = threading.Thread(

@@ -466,7 +466,7 @@ class ConsensusState(BaseService):
             # to per-signature host verification — but a persistent
             # failure here erases the batching win, so surface it once
             # per distinct failure type (a one-shot flag would let a
-            # transient relay hiccup permanently mask a later bug).
+            # transient device hiccup permanently mask a later bug).
             if type(e).__name__ not in self._preverify_warned_types:
                 self._preverify_warned_types.add(type(e).__name__)
                 import traceback

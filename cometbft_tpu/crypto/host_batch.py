@@ -61,23 +61,21 @@ def _load():
         if _lib is not None or _lib_failed:
             return _lib
         try:
+            # build_and_load only reuses a .so stamped with this exact
+            # source, so every symbol _bind names is there
             lib = build_and_load(_SRC, _SO)
-            try:
-                _bind(lib)
-            except AttributeError:
-                # a pre-existing .so from OLDER source (deploy that
-                # preserved mtimes) lacks newer symbols: force a clean
-                # rebuild from the current source once
-                try:
-                    os.remove(_SO)
-                except OSError:
-                    pass
-                lib = build_and_load(_SRC, _SO)
-                _bind(lib)
+            _bind(lib)
             _install_sha512_constants(lib)
             _lib = lib
-        except (NativeBuildError, AttributeError):
+        except NativeBuildError:
             _lib_failed = True
+            import logging
+
+            logging.getLogger(__name__).warning(
+                "native batch engine unavailable; sequential host "
+                "verification serves every host batch",
+                exc_info=True,
+            )
     return _lib
 
 

@@ -134,13 +134,35 @@ class LinkRelay:
             pass
 
 
+def child_env(chip_owner: bool = False) -> dict:
+    """Environment of one ``cometbft-tpu start`` child.
+
+    A chip belongs to one process. Every child is pinned to the CPU
+    (``JAX_PLATFORMS=cpu``) except the one the launcher names as the
+    chip's owner, which keeps the launcher's own setting — so which
+    node gets the device is stated, never a race between children that
+    each probe it in ``auto`` and go host-only when they lose. The
+    launcher itself must stay off jax while an owner child runs.
+    """
+    env = dict(os.environ)
+    if not chip_owner:
+        env["JAX_PLATFORMS"] = "cpu"
+    return env
+
+
 class ProcessNode:
     """One ``cometbft-tpu start`` child process + its home dir."""
 
-    def __init__(self, home: str, rpc_addr: str, env: dict | None = None):
+    def __init__(
+        self,
+        home: str,
+        rpc_addr: str,
+        env: dict | None = None,
+        chip_owner: bool = False,
+    ):
         self.home = home
         self.rpc_addr = rpc_addr
-        self.env = env if env is not None else dict(os.environ)
+        self.env = env if env is not None else child_env(chip_owner)
         self.proc: subprocess.Popen | None = None
 
     # -- lifecycle ---------------------------------------------------------
@@ -277,7 +299,16 @@ class Testnet:
 
     __test__ = False  # not a pytest class despite the name
 
-    def __init__(self, out_dir: str, n_vals: int, starting_port: int):
+    def __init__(
+        self,
+        out_dir: str,
+        n_vals: int,
+        starting_port: int,
+        chip_owner: int | None = None,
+    ):
+        """``chip_owner`` names the ONE node index allowed to open the
+        accelerator; every other child runs ``JAX_PLATFORMS=cpu``
+        (:func:`child_env`). None = an all-CPU net."""
         self.out_dir = out_dir
         self.starting_port = starting_port
         self.relays: dict[tuple[int, int], LinkRelay] = {}
@@ -285,13 +316,18 @@ class Testnet:
             ProcessNode(
                 home=os.path.join(out_dir, f"node{i}"),
                 rpc_addr=f"tcp://127.0.0.1:{starting_port + 2 * i + 1}",
+                chip_owner=(i == chip_owner),
             )
             for i in range(n_vals)
         ]
 
     @classmethod
     def generate(
-        cls, out_dir: str, n_vals: int, starting_port: int
+        cls,
+        out_dir: str,
+        n_vals: int,
+        starting_port: int,
+        chip_owner: int | None = None,
     ) -> "Testnet":
         from ..cmd.__main__ import main as cli_main
 
@@ -308,7 +344,7 @@ class Testnet:
         )
         if rc != 0:
             raise RuntimeError("testnet generation failed")
-        return cls(out_dir, n_vals, starting_port)
+        return cls(out_dir, n_vals, starting_port, chip_owner)
 
     @classmethod
     def generate_randomized(

@@ -407,9 +407,8 @@ class NodeMetrics:
         # Phase breakdown of the SAME interval verify_batch_seconds
         # covers: pack (host staging + arena lookup), dispatch (async
         # kernel launch), readback (device materialization), fallback
-        # (the host verifier). Buckets extend below the defaults — the
-        # cached dispatch floor is ~2 ms and pack at 2k lanes ~14 ms
-        # (BENCH_r05), both inside the default 1 ms bottom bucket.
+        # (the host verifier). Buckets extend below the defaults so
+        # millisecond-scale dispatch and pack phases resolve.
         self.verify_phase_seconds = r.histogram(
             "crypto_verify_phase_seconds",
             "Batched-verification per-phase latency",

@@ -11,6 +11,7 @@ handling keeps the two paths from drifting.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 from . import sync as libsync
@@ -22,26 +23,46 @@ class NativeBuildError(RuntimeError):
     pass
 
 
+def _stamp(src: str, extra_flags: tuple[str, ...]) -> str:
+    """What a built ``.so`` must have been built FROM: the source's
+    content hash plus the flags. File times say nothing on a machine
+    the tree was copied to (a stale or foreign ``.so`` copied along
+    with a checkout carries whatever mtime the copy gave it)."""
+    h = hashlib.sha256()
+    with open(src, "rb") as f:
+        h.update(f.read())
+    h.update(" ".join(extra_flags).encode())
+    return h.hexdigest()
+
+
+def _fresh(so: str, stamp: str) -> bool:
+    try:
+        with open(so + ".stamp") as f:
+            return os.path.exists(so) and f.read().strip() == stamp
+    except OSError:
+        return False
+
+
 def build_and_load(
     src: str,
     so: str,
     extra_flags: tuple[str, ...] = (),
     timeout: float = 120.0,
 ) -> ctypes.CDLL:
-    """Compile ``src`` -> ``so`` (when missing or stale) and dlopen it.
+    """Compile ``src`` -> ``so`` (unless a ``.so`` stamped with this
+    exact source already exists) and dlopen it.
 
     Raises NativeBuildError when the toolchain is unavailable or the
     compile fails; callers decide their own fallback policy.
     """
     with _lock:
-        if not os.path.exists(so) or os.path.getmtime(so) < os.path.getmtime(
-            src
-        ):
-            _compile(src, so, extra_flags, timeout)
+        stamp = _stamp(src, extra_flags)
+        if not _fresh(so, stamp):
+            _compile(src, so, extra_flags, timeout, stamp)
         try:
             return ctypes.CDLL(so)
         except OSError:
-            # A pre-existing .so that won't dlopen (truncated artifact,
+            # A stamped .so that won't dlopen (truncated artifact,
             # wrong architecture) must not take down callers that have a
             # pure-Python fallback: rebuild once from source, and map any
             # remaining failure to NativeBuildError so the callers'
@@ -50,7 +71,7 @@ def build_and_load(
                 os.remove(so)
             except OSError:
                 pass
-            _compile(src, so, extra_flags, timeout)
+            _compile(src, so, extra_flags, timeout, stamp)
             try:
                 return ctypes.CDLL(so)
             except OSError as e:
@@ -60,7 +81,11 @@ def build_and_load(
 
 
 def _compile(
-    src: str, so: str, extra_flags: tuple[str, ...], timeout: float
+    src: str,
+    so: str,
+    extra_flags: tuple[str, ...],
+    timeout: float,
+    stamp: str,
 ) -> None:
     cmd = [
         "g++", "-O3", "-funroll-loops", "-shared", "-fPIC",
@@ -80,3 +105,6 @@ def _compile(
             f"{r.stderr[:800]}"
         )
     os.replace(so + ".tmp", so)
+    with open(so + ".stamp.tmp", "w") as f:
+        f.write(stamp)
+    os.replace(so + ".stamp.tmp", so + ".stamp")

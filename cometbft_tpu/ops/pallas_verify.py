@@ -39,7 +39,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import curve, field
-from ..libs.accel import ACCELERATOR_BACKENDS
 
 BITS = field.BITS
 NLIMB = field.NLIMB
@@ -774,12 +773,10 @@ def _compiled8(n: int, block: int, interpret: bool):
 
 
 def verify_kernel8(y_a, sign_a, y_r, sign_r, s_bytes, kneg_nibs, *,
-                   interpret=None):
+                   interpret=False):
     """8-bit fixed-base-window Pallas lowering
     (COMETBFT_TPU_KERNEL=pallas8); same contract as
     curve.verify_kernel8."""
-    if interpret is None:
-        interpret = jax.default_backend() not in ACCELERATOR_BACKENDS
     n = y_a.shape[-1]
     block = _block_for(n)
     if n % block:
@@ -836,10 +833,8 @@ def _compiled8_cached(n: int, block: int, interpret: bool):
 
 
 def verify_kernel8_cached(table, ok_a, y_r, sign_r, s_bytes, kneg_nibs, *,
-                          interpret=None):
+                          interpret=False):
     """Cached-table 8-bit-window Pallas lowering."""
-    if interpret is None:
-        interpret = jax.default_backend() not in ACCELERATOR_BACKENDS
     n = y_r.shape[-1]
     block = _block_for(n)
     if n % block:
@@ -893,10 +888,8 @@ def _compiled_cached(n: int, block: int, interpret: bool):
 
 
 def verify_kernel_cached(table, ok_a, y_r, sign_r, s_nibs, kneg_nibs, *,
-                         interpret=None):
+                         interpret=False):
     """Cached-table drop-in for ops.curve.verify_kernel_cached (+ ok AND)."""
-    if interpret is None:
-        interpret = jax.default_backend() not in ACCELERATOR_BACKENDS
     n = y_r.shape[-1]
     block = _block_for(n)
     if n % block:
@@ -948,15 +941,14 @@ def _compiled(n: int, block: int, interpret: bool):
     return fn
 
 
-def verify_kernel(y_a, sign_a, y_r, sign_r, s_nibs, kneg_nibs, *, interpret=None):
+def verify_kernel(y_a, sign_a, y_r, sign_r, s_nibs, kneg_nibs, *,
+                  interpret=False):
     """Drop-in for ops.curve.verify_kernel with the same array contract.
 
-    ``interpret`` defaults to True off-TPU (Pallas Mosaic only targets
-    TPU; interpret mode keeps CPU tests and the virtual-device mesh path
-    working) and False on TPU.
+    ``interpret=False`` compiles for the chip through Mosaic, which
+    only targets TPU; callers that want the interpreter (CPU tests,
+    chip_smoke.py's dry run) ask for it. Nothing here reads the backend.
     """
-    if interpret is None:
-        interpret = jax.default_backend() not in ACCELERATOR_BACKENDS
     n = y_a.shape[-1]
     block = _block_for(n)
     if n % block:

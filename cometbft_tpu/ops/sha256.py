@@ -44,6 +44,7 @@ import numpy as np
 import jax
 
 from ..libs import devstats as libdevstats
+from . import warm as libwarm
 
 _MIN_LANES = 8
 # Lanes per launch cap, like ops/verify._CHUNK: one dispatch stays a
@@ -148,31 +149,46 @@ def _rotr(x, r: int):
 def _compress(state, words):
     """One SHA-256 compression: state (8, L) + block words (16, L).
 
-    The 48 schedule extensions and 64 rounds are unrolled in Python —
-    a few hundred fused VPU ops per block, compiled once per shape
-    bucket; uint32 adds wrap mod 2^32 natively.
+    The 64 rounds are ONE rolled ``lax.fori_loop`` carrying the working
+    variables a..h (8, L) and a 16-word ring of the message schedule
+    (16, L): round t consumes ring slot t % 16 and overwrites it with
+    w[t + 16], so no round's expression ever contains another's. (The
+    Python-unrolled form handed XLA one fused elementwise expression
+    that re-evaluated the w[t] recurrence exponentially: it compiled,
+    then never finished executing.) uint32 adds wrap mod 2^32 natively.
     """
     import jax.numpy as jnp
+    from jax import lax
 
-    k = jnp.asarray(_K)  # constant-folded per compile
-    w = [words[t] for t in range(16)]
-    for t in range(16, 64):
-        s0 = _rotr(w[t - 15], 7) ^ _rotr(w[t - 15], 18) ^ (w[t - 15] >> 3)
-        s1 = _rotr(w[t - 2], 17) ^ _rotr(w[t - 2], 19) ^ (w[t - 2] >> 10)
-        w.append(w[t - 16] + s0 + w[t - 7] + s1)
-    a, b, c, d, e, f, g, h = (state[i] for i in range(8))
-    for t in range(64):
+    k = jnp.asarray(_K)
+
+    def w_at(ring, i):
+        return lax.dynamic_index_in_dim(ring, i % 16, 0, keepdims=False)
+
+    def round_(t, carry):
+        v, ring = carry
+        a, b, c, d, e, f, g, h = (v[i] for i in range(8))
+        wt = w_at(ring, t)
         s1 = _rotr(e, 6) ^ _rotr(e, 11) ^ _rotr(e, 25)
         ch = (e & f) ^ (~e & g)
-        t1 = h + s1 + ch + k[t] + w[t]
+        t1 = h + s1 + ch + k[t] + wt
         s0 = _rotr(a, 2) ^ _rotr(a, 13) ^ _rotr(a, 22)
         maj = (a & b) ^ (a & c) ^ (b & c)
-        t2 = s0 + maj
-        h, g, f, e, d, c, b, a = g, f, e, d + t1, c, b, a, t1 + t2
-    return jnp.stack([
-        state[0] + a, state[1] + b, state[2] + c, state[3] + d,
-        state[4] + e, state[5] + f, state[6] + g, state[7] + h,
-    ])
+        v = jnp.stack([t1 + s0 + maj, a, b, c, d + t1, e, f, g])
+        # w[t+16] = w[t] + s0(w[t+1]) + w[t+9] + s1(w[t+14]); written
+        # past round 47 too — those slots are never read again
+        w1, w14 = w_at(ring, t + 1), w_at(ring, t + 14)
+        nxt = (
+            wt
+            + (_rotr(w1, 7) ^ _rotr(w1, 18) ^ (w1 >> 3))
+            + w_at(ring, t + 9)
+            + (_rotr(w14, 17) ^ _rotr(w14, 19) ^ (w14 >> 10))
+        )
+        ring = lax.dynamic_update_index_in_dim(ring, nxt, t % 16, 0)
+        return v, ring
+
+    v, _ = lax.fori_loop(0, 64, round_, (state, words))
+    return state + v
 
 
 def _sha256_kernel(blocks, nblocks):
@@ -199,15 +215,6 @@ def _sha256_kernel(blocks, nblocks):
     return state
 
 
-def _donatable(argnums):
-    from ..libs.accel import ACCELERATOR_BACKENDS
-
-    try:
-        return argnums if jax.default_backend() in ACCELERATOR_BACKENDS else ()
-    except Exception:
-        return ()
-
-
 @lru_cache(maxsize=None)
 def _jitted_kernel(blocks_bucket: int):
     """The tracked jit for ONE block bucket, built lazily (importing
@@ -225,7 +232,9 @@ def _jitted_kernel(blocks_bucket: int):
     _enable_compilation_cache()
     return libdevstats.track(
         f"sha256.xla.b{blocks_bucket}",
-        jax.jit(_sha256_kernel, donate_argnums=_donatable((0,))),
+        # no donation: the (8, L) state cannot alias the (B, 16, L)
+        # blocks, so XLA only answered "donated buffers not usable"
+        jax.jit(_sha256_kernel),
         axis=0,
     )
 
@@ -262,6 +271,26 @@ def sha256_many_async(msgs, blocks_cap: int | None = None):
         return _digests_from_state(arr, n)
 
     return materialize
+
+
+def _warm_shape(key) -> None:
+    """Compile one (block bucket, lane bucket) by hashing empty lanes
+    (ops/warm.WarmSet's contract)."""
+    blocks_bucket, lanes = key
+    sha256_many_async([b""] * lanes, blocks_bucket)()
+
+
+# Hash-plane windows launch only shapes that are warm here; a cold one
+# runs on hashlib while this compiles it (measured 0.2-2.0 s per shape
+# on a v5e, PERF.md Bring-up — under the 5 s ticket bound, but a bound
+# a loaded host should not have to race).
+WARM = libwarm.WarmSet("sha256", _warm_shape)
+
+
+def shape_ready(blocks_bucket: int, n: int) -> bool:
+    """Whether an ``n``-lane launch in ``blocks_bucket`` would compile
+    nothing; False queues the compile in the background."""
+    return WARM.ready((blocks_bucket, lane_bucket(n)))
 
 
 def sha256_many_host(msgs) -> list[bytes]:

@@ -91,12 +91,9 @@ def _sharded_verify_pallas(mesh: Mesh):
     block constraint (static shapes — padding targets are computed at
     trace time), and runs the VMEM-resident ladder. The per-commit
     verdict's ``jnp.all`` stays OUTSIDE the shard_map, so XLA still
-    lowers it to the one-byte-per-commit ICI all-reduce. ~2.5x the XLA
-    program per chip (round-5 A/B) — this is the multi-chip projection
-    of that measured single-chip win.
+    lowers it to the one-byte-per-commit ICI all-reduce.
     """
     from ..ops import pallas_verify
-    from jax.experimental.shard_map import shard_map
 
     lead = P(None, AXIS_COMMIT, AXIS_SIG)
     flat = P(AXIS_COMMIT, AXIS_SIG)
@@ -119,12 +116,12 @@ def _sharded_verify_pallas(mesh: Mesh):
         )
         return ok[:n].reshape(c_l, v_l)
 
-    sm = shard_map(
+    sm = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(lead, flat, lead, flat, lead, lead),
         out_specs=flat,
-        check_rep=False,
+        check_vma=False,
     )
 
     def step(y_a, sign_a, y_r, sign_r, s_nibs, kneg_nibs):
@@ -156,12 +153,8 @@ def _dispatch_sharded(mesh: Mesh, args, lanes_per_shard: int):
 
     from ..libs.accel import ACCELERATOR_BACKENDS
 
-    try:
-        on_accel = jax.default_backend() in ACCELERATOR_BACKENDS
-    except Exception:
-        on_accel = False
     if (
-        on_accel
+        jax.default_backend() in ACCELERATOR_BACKENDS
         and lanes_per_shard >= ov._PALLAS_MIN_LANES
         and ov._pallas_wanted()
         and not _SHARDED_PALLAS_BROKEN
@@ -171,16 +164,17 @@ def _dispatch_sharded(mesh: Mesh, args, lanes_per_shard: int):
             # cometlint: disable=CLNT002 -- sanctioned sharded readback:
             # materializing INSIDE the try is what lets a Mosaic runtime
             # fault retire the pallas path and fall through to XLA
-            return np.asarray(ok), np.asarray(verdict)
+            out = np.asarray(ok), np.asarray(verdict)
         except Exception as e:
             _SHARDED_PALLAS_BROKEN = True
-            from ..libs import log as _log
-
-            _log.default_logger().with_module("parallel.mesh").error(
-                "sharded pallas kernel failed; falling back to XLA",
-                err=repr(e)[:200],
-            )
+            # counted with the single-chip Pallas faults
+            # (ops.verify.dispatch_counters), never silent
+            ov._note_fault("pallas", e, flavor="sharded")
+        else:
+            ov._served("verify_sharded.pallas")
+            return out
     ok, verdict = _sharded_verify(mesh)(*args)
+    ov._served("verify_sharded.xla")
     # cometlint: disable=CLNT002 -- sanctioned readback of the XLA
     # sharded launch (single sync point of the multi-chip path)
     return np.asarray(ok), np.asarray(verdict)

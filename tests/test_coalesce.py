@@ -239,7 +239,7 @@ class TestInflightRescue:
     def test_stop_rescues_window_wedged_in_materialization(
         self, monkeypatch
     ):
-        # the executor blocks inside the window's materializer (a relay
+        # the executor blocks inside the window's materializer (a device
         # stall); on_stop's join times out and the safety net resolves
         # the in-flight tickets instead of leaving submitters hanging
         release = threading.Event()

@@ -131,122 +131,48 @@ class TestBatchDispatch:
 
 
 class TestHostThresholdDerivation:
-    """HOST_BATCH_THRESHOLD derives from env > chip-measured crossover >
-    static fallback (round-3 verdict weak #4: the 768 was an assertion)."""
+    """HOST_BATCH_THRESHOLD is the env pin or the static 768 seed — no
+    benchmark file steers it (the live AdaptiveCrossover refits it on
+    an accelerator backend)."""
 
-    def test_env_override_wins(self, monkeypatch, tmp_path):
+    def test_env_override_wins(self, monkeypatch):
         from cometbft_tpu.crypto import batch
 
         monkeypatch.setenv("COMETBFT_TPU_HOST_THRESHOLD", "96")
         assert batch._derive_host_threshold() == 96
-        # garbage env falls through to the next tier; isolate from the
-        # repo's real chip table (round 5 recorded an accelerator-
-        # measured crossover there) so this checks the STATIC fallback
         monkeypatch.setenv("COMETBFT_TPU_HOST_THRESHOLD", "garbage")
-        monkeypatch.setenv(
-            "COMETBFT_TPU_CHIP_TABLE", str(tmp_path / "absent.json")
-        )
         assert batch._derive_host_threshold() == (
             batch._DEFAULT_HOST_BATCH_THRESHOLD
         )
 
-    def test_chip_table_crossover(self, monkeypatch, tmp_path):
-        import json
-
-        from cometbft_tpu.crypto import batch
-
-        monkeypatch.delenv("COMETBFT_TPU_HOST_THRESHOLD", raising=False)
-        monkeypatch.setenv(
-            "COMETBFT_TPU_CHIP_TABLE",
-            str(tmp_path / "BENCH_CHIP_TABLE.json"),
-        )
-        (tmp_path / "BENCH_CHIP_TABLE.json").write_text(
-            json.dumps(
-                {
-                    "measured_on_accelerator": True,
-                    "table": [
-                        {
-                            "config": "9_device_floor",
-                            "measured_crossover_lanes": 256,
-                        }
-                    ],
-                }
-            )
-        )
-        assert batch._derive_host_threshold() == 256
-        # a CPU-measured table must NOT override the default
-        (tmp_path / "BENCH_CHIP_TABLE.json").write_text(
-            json.dumps(
-                {
-                    "measured_on_accelerator": False,
-                    "table": [
-                        {
-                            "config": "9_device_floor",
-                            "measured_crossover_lanes": 256,
-                        }
-                    ],
-                }
-            )
-        )
-        assert batch._derive_host_threshold() == (
-            batch._DEFAULT_HOST_BATCH_THRESHOLD
-        )
-
-    def test_no_table_falls_back(self, monkeypatch, tmp_path):
-        from cometbft_tpu.crypto import batch
-
-        monkeypatch.delenv("COMETBFT_TPU_HOST_THRESHOLD", raising=False)
-        monkeypatch.setenv(
-            "COMETBFT_TPU_CHIP_TABLE",
-            str(tmp_path / "missing.json"),
-        )
-        assert batch._derive_host_threshold() == (
-            batch._DEFAULT_HOST_BATCH_THRESHOLD
-        )
-
-    def test_threshold_tracks_recorded_numbers(self, monkeypatch, tmp_path):
-        """The knob MOVES when the recorded measurement moves, and a
-        measured-but-never-winning device routes everything host
-        (round-4 verdict task 4)."""
+    def test_no_file_steers_the_seed(self, monkeypatch, tmp_path):
+        """A chip table lying in the working directory (or named by the
+        retired COMETBFT_TPU_CHIP_TABLE knob) changes nothing."""
         import json
 
         from cometbft_tpu.crypto import batch
 
         monkeypatch.delenv("COMETBFT_TPU_HOST_THRESHOLD", raising=False)
         path = tmp_path / "BENCH_CHIP_TABLE.json"
-        monkeypatch.setenv("COMETBFT_TPU_CHIP_TABLE", str(path))
-
-        def table(xo, rows=({"n": 64}, {"n": 4096})):
-            return json.dumps(
+        path.write_text(
+            json.dumps(
                 {
                     "measured_on_accelerator": True,
                     "table": [
                         {
                             "config": "9_device_floor",
-                            "measured_crossover_lanes": xo,
-                            "rows": list(rows),
+                            "measured_crossover_lanes": 256,
                         }
                     ],
                 }
             )
-
-        path.write_text(table(512))
-        assert batch._derive_host_threshold() == 512
-        path.write_text(table(2048))
-        assert batch._derive_host_threshold() == 2048  # moved with data
-        # measured on chip, full sweep, device never won -> host always
-        path.write_text(table(None))
-        assert batch._derive_host_threshold() == 1 << 30
-        # no rows at all (probe died mid-run): static fallback, not host-always
-        path.write_text(table(None, rows=()))
+        )
+        monkeypatch.setenv("COMETBFT_TPU_CHIP_TABLE", str(path))
+        monkeypatch.chdir(tmp_path)
         assert batch._derive_host_threshold() == (
             batch._DEFAULT_HOST_BATCH_THRESHOLD
         )
-        # tiny/truncated sweep (max n < 2048) must NOT poison the knob
-        path.write_text(table(None, rows=({"n": 64}, {"n": 150})))
-        assert batch._derive_host_threshold() == (
-            batch._DEFAULT_HOST_BATCH_THRESHOLD
-        )
+        assert batch._DEFAULT_HOST_BATCH_THRESHOLD == 768
 
 
 class TestPureHandshakeCrypto:

@@ -2076,12 +2076,18 @@ class TestNoRecompileGuard:
             # wire rows + slot indices: 2 B/lane uint16 idxs (the
             # narrowed dtype — this arithmetic IS the proof the per-
             # window h2d shrank from the old 4 B/lane int32 lanes)
-            per_launch_up = 96 * 8 + 8 * 2
+            # Each launch reconciles at ITS OWN bucket: nearly all are
+            # the minimum bucket (8), but under load a drain can catch
+            # a straggler vote and form a 9+-lane batch (bucket 16) —
+            # seen once in a cold-cache tier-1 run.
+            buckets = [ov.bucket_size(e["lanes"]) for e in disp]
             assert (
                 c1["h2d_bytes"] - c0["h2d_bytes"]
-                == launches * per_launch_up
+                == sum(96 * b + 2 * b for b in buckets)
+            ), buckets
+            assert c1["d2h_bytes"] - c0["d2h_bytes"] == sum(
+                b // 8 for b in buckets
             )
-            assert c1["d2h_bytes"] - c0["d2h_bytes"] == launches * (8 // 8)
             # the same launches land in the Prometheus families at
             # scrape time (the sample bridge)
             devstats.sample(m)
