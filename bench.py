@@ -944,72 +944,6 @@ def bench_valset_update():
     return {"priority_increments_per_sec": round(reps / dt, 1)}
 
 
-def bench_trace_phases(n: int | None = None, device: bool = True):
-    """Config 11: per-phase attribution of one traced verify burst.
-
-    Runs one flat batch verify under libs/trace and aggregates the
-    verify.* phase events (pack / dispatch / readback on the device
-    path, fallback on host), so BENCH rows carry the phase breakdown
-    that locates a regression — cached dispatch vs readback vs pack —
-    instead of one end-to-end number.
-    """
-    from cometbft_tpu.libs import trace as libtrace
-
-    n = n if n is not None else _sz(4096, 64)
-    if device:
-        from cometbft_tpu.ops import verify as ov
-
-        pubkeys, msgs, sigs = _make_ed_batch(n)
-
-        def run():
-            return ov.verify_batch(pubkeys, msgs, sigs)
-
-    else:
-        from cometbft_tpu.crypto import batch as cbatch
-
-        # stay on the HOST path regardless of the routing threshold —
-        # this row documents the fallback phase
-        n = min(n, max(2, cbatch.HOST_BATCH_THRESHOLD - 1))
-        pubkeys, msgs, sigs = _make_ed_batch(n)
-
-        def run():
-            v = cbatch.Ed25519BatchVerifier()
-            for p, m, s in zip(pubkeys, msgs, sigs):
-                v.add(cbatch.Ed25519PubKey(p), m, s)
-            return v.verify()
-
-    ok, _bitmap = run()  # warm: compile/caches outside the traced burst
-    assert ok, "trace-phase burst failed verification"
-    libtrace.reset()
-    libtrace.enable()
-    try:
-        t0 = time.perf_counter()
-        run()
-        total = time.perf_counter() - t0
-        events = libtrace.ring_dump()
-    finally:
-        libtrace.disable()
-        libtrace.reset()
-    phases: dict = {}
-    for ev in events:
-        name = ev.get("name", "")
-        if not name.startswith("verify."):
-            continue
-        d = phases.setdefault(
-            name[len("verify."):], {"ms": 0.0, "events": 0}
-        )
-        d["ms"] += ev.get("dur_ns", 0) / 1e6
-        d["events"] += 1
-    for d in phases.values():
-        d["ms"] = round(d["ms"], 3)
-    return {
-        "n": n,
-        "total_ms": round(total * 1e3, 2),
-        "phases": phases,
-        "note": "verify.* phase events from libs/trace; ms sum ~ total",
-    }
-
-
 def bench_coalesce_steady_state(
     device: bool | None = None,
     n_threads: int | None = None,
@@ -3437,7 +3371,6 @@ def main() -> None:
         ("8_valset_update", bench_valset_update),
         ("9_device_floor", bench_device_floor),
         ("10_kernel_ab", bench_kernel_ab),
-        ("11_trace_phases", bench_trace_phases),
     ):
         try:
             row = fn()

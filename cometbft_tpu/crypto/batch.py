@@ -399,7 +399,7 @@ class Sr25519BatchVerifier(BatchVerifier):
         a_keys = [p[0] if p is not None else b"" for p in parts]
         t1 = _time.perf_counter()
         libmetrics.observe_verify_phase("pack", "sr25519-tpu", t1 - t0, n)
-        done = ov.verify_prepacked(buf, a_keys, n)
+        done = ov.verify_prepacked(buf, a_keys, n, "sr25519-tpu")
         t2 = _time.perf_counter()
         libmetrics.observe_verify_phase("dispatch", "sr25519-tpu", t2 - t1, n)
         device_ok = done()
@@ -633,7 +633,7 @@ class MixedBatchVerifier(BatchVerifier):
         buf, host_ok, a_keys = self._pack_rows()
         t1 = _time.perf_counter()
         libmetrics.observe_verify_phase("pack", "mixed-tpu", t1 - t0, n)
-        done = ov.verify_prepacked(buf, a_keys, n)
+        done = ov.verify_prepacked(buf, a_keys, n, "mixed-tpu")
         t2 = _time.perf_counter()
         libmetrics.observe_verify_phase("dispatch", "mixed-tpu", t2 - t1, n)
         device_ok = done()

@@ -926,10 +926,13 @@ class VerifyCoalescer(BaseService):
                 if hit is not None:
                     idxs, arena_buf, arena_ok = hit
                     finish = ov.verify_rsk_async(
-                        buf[32:], idxs, arena_buf, arena_ok, n
+                        buf[32:], idxs, arena_buf, arena_ok, n,
+                        "ed25519-coalesce",
                     )
                 else:
-                    finish = ov.verify_bytes_async(buf, n)
+                    finish = ov.verify_bytes_async(
+                        buf, n, "ed25519-coalesce"
+                    )
                 libmetrics.observe_verify_phase(
                     "dispatch",
                     "ed25519-coalesce",

@@ -9,9 +9,11 @@ exposition format the RPC server serves at GET /metrics.
 
 from __future__ import annotations
 
+import time
+from bisect import bisect_right
+
 from . import sync as libsync
 from . import trace as libtrace
-from bisect import bisect_right
 
 
 def escape_label_value(v: str) -> str:
@@ -413,6 +415,20 @@ class NodeMetrics:
             "crypto_verify_phase_seconds",
             "Batched-verification per-phase latency",
             label_names=("phase", "backend"),
+            buckets=(
+                0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
+                0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5,
+            ),
+        )
+        # one light-client header, phase by phase (libs/metrics.
+        # light_phase): header (the whole verification, fetch included),
+        # fetch (the provider's reply), valset_hash, header_basic,
+        # sign_bytes. The last four do not nest in one another, so with
+        # the verify phases above they tile a header; same buckets.
+        self.light_verify_phase_seconds = r.histogram(
+            "light_verify_phase_seconds",
+            "Light-client header verification per-phase latency",
+            label_names=("phase",),
             buckets=(
                 0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
                 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5,
@@ -1016,6 +1032,51 @@ def observe_verify_phase(
                 arena=arena,
                 dur_ns=int(seconds * 1e9),
             )
+
+
+class TimedPhase:
+    """``with`` block that times one phase for two sinks from one pair
+    of clock readings: ``hist`` (always on; None = the caller sums
+    ``dur_ns`` over several blocks and observes once) and, while
+    tracing is on, a libs/trace span of the same interval. One per
+    phase, never per lane."""
+
+    __slots__ = ("_hist", "_span", "_t0", "dur_ns")
+
+    def __init__(self, hist, span_name: str, **fields):
+        self._hist = hist
+        self._span = libtrace.span(span_name, **fields)
+        self.dur_ns = 0
+
+    def set(self, **fields) -> None:
+        """Span fields known only inside the block (libtrace.Span.set)."""
+        self._span.set(**fields)
+
+    def __enter__(self) -> "TimedPhase":
+        if self._span is libtrace.NOP_SPAN:
+            self._t0 = time.time_ns()  # the clock libs/trace reads
+        else:
+            self._span.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        sp = self._span
+        if sp is libtrace.NOP_SPAN:
+            self.dur_ns = max(0, time.time_ns() - self._t0)
+        else:
+            sp.__exit__(*exc)
+            self.dur_ns = sp.dur_ns
+        if self._hist is not None:
+            self._hist.observe(self.dur_ns / 1e9)
+
+
+def light_phase(phase: str, span_name: str, **fields) -> TimedPhase:
+    """One phase of a light-client header: ``light_verify_phase_seconds
+    {phase}`` and the ``span_name`` span."""
+    return TimedPhase(
+        node_metrics().light_verify_phase_seconds.labels(phase),
+        span_name, **fields,
+    )
 
 
 def observe_hash_phase(phase: str, seconds: float, lanes: int) -> None:

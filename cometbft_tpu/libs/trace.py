@@ -24,12 +24,30 @@ Design constraints (in priority order):
   single lock here (``libs.trace._mtx``) only serializes sink
   start/stop and is never held across blocking calls.
 
+* **One clock, the profiler's.** A span's ``start_ns`` and ``dur_ns``
+  are two readings of ``time.time_ns()``, the Unix-epoch clock that
+  the jax profiler's host plane stamps its own events with (an
+  xplane's ``profile_start_time`` + an event's offset is such a
+  reading), so a ring record and a device operation of a profiler
+  trace stand on one timeline.  While tracing is on, every ``with``-
+  span is also entered as a ``jax.profiler.TraceAnnotation`` named
+  ``"bft." + name`` on the same thread, but only in a process that has
+  imported jax already: tracing never imports it and never initialises
+  a backend.  Manual ``begin()/end()`` spans may end on another thread
+  and are not mirrored.
+
 Record schema (one JSON object per line in the file sink, same dicts
 from :func:`ring_dump`)::
 
     {"ts": <wall-clock ns>, "kind": "event"|"span", "name": str,
      "thread": str, ...}
-    span records add:   "span": id, "parent": id, "dur_ns": int
+    span records add:   "span": id, "parent": id, "start_ns": int,
+                        "dur_ns": int ("ts" = start_ns + dur_ns, the
+                        end); with-spans also "root": id of the
+                        outermost with-span on the thread (one
+                        request's spans share it) and "cpu_ns": the
+                        thread's CPU time inside the span (the rest of
+                        dur_ns is waiting: GIL, device, I/O)
     event records add:  "span": id of the enclosing with-span (if any)
                         plus free-form fields ("dur_ns", "backend",
                         "lanes", "height", ...)
@@ -76,6 +94,20 @@ _ids = itertools.count(1)  # span ids; count.__next__ is GIL-atomic
 _tls = threading.local()  # .spans: stack of with-entered Span objects
 _mtx = libsync.Mutex("libs.trace._mtx")  # sink start/stop only
 _sink: "_FileSink | None" = None
+# jax.profiler.TraceAnnotation once a process that imported jax traces
+_annotation = None
+_ANNOTATION_STR_MAX = 64
+
+
+def _annotation_cls():
+    """The profiler's annotation type if this process has jax, found
+    without importing anything."""
+    global _annotation
+    cls = _annotation
+    if cls is None:
+        prof = getattr(sys.modules.get("jax"), "profiler", None)
+        cls = _annotation = getattr(prof, "TraceAnnotation", None)
+    return cls
 
 
 def enabled() -> bool:
@@ -130,32 +162,25 @@ def status() -> dict:
 # ------------------------------------------------------------- emission
 
 
-def _emit(
-    kind: str,
-    name: str,
-    fields: dict | None,
-    span_id: int = 0,
-    parent_id: int = 0,
-    dur_ns: int | None = None,
-) -> None:
-    rec: dict = {
-        "ts": time.time_ns(),
-        "kind": kind,
-        "name": name,
-        "thread": threading.current_thread().name,
-    }
-    if span_id:
-        rec["span"] = span_id
-    if parent_id:
-        rec["parent"] = parent_id
-    if dur_ns is not None:
-        rec["dur_ns"] = dur_ns
+def _emit(rec: dict, fields: dict | None) -> None:
     if fields:
         rec.update(fields)
     _ring.append(rec)
     s = _sink
     if s is not None:
         s.put(rec)
+
+
+def _emit_event(name: str, fields: dict | None, span_id: int) -> None:
+    rec: dict = {
+        "ts": time.time_ns(),
+        "kind": "event",
+        "name": name,
+        "thread": threading.current_thread().name,
+    }
+    if span_id:
+        rec["span"] = span_id
+    _emit(rec, fields)
 
 
 def _span_stack() -> list:
@@ -171,64 +196,105 @@ def event(name: str, **fields) -> None:
     if not _enabled:
         return
     stack = getattr(_tls, "spans", None)
-    _emit("event", name, fields, span_id=stack[-1].id if stack else 0)
+    _emit_event(name, fields, stack[-1].id if stack else 0)
 
 
 class Span:
     """A timed interval.  Two usage modes:
 
     * ``with span("name", k=v): ...`` — nests on the per-thread stack,
-      so events inside attribute to it automatically;
+      so events inside attribute to it automatically, and is mirrored
+      into the jax profiler's trace as ``bft.name`` (module docstring);
     * ``sp = begin("name", parent=outer); ...; sp.end()`` — manual
       lifetime for state-machine phases (consensus height/round/step)
       that do not nest lexically.  Manual spans never touch the thread
       stack, so they are safe to end from a different callback.
 
-    One record is emitted at ``end()`` carrying the measured
-    ``dur_ns``; a span never ends twice.
+    One record is emitted at ``end()`` carrying ``start_ns`` and the
+    measured ``dur_ns``, one clock reading per edge; a span never ends
+    twice.  ``dur_ns`` stays on the object for a caller that feeds a
+    second sink from the same reading (libs/metrics.TimedPhase).
     """
 
-    __slots__ = ("name", "id", "parent", "fields", "_t0", "_ended")
+    __slots__ = (
+        "name", "id", "parent", "root", "fields", "start_ns", "dur_ns",
+        "_cpu0", "_cpu_ns", "_mirror", "_ended",
+    )
 
     def __init__(self, name: str, parent_id: int, fields: dict | None):
         self.name = name
         self.id = next(_ids)
         self.parent = parent_id
+        self.root = 0
         self.fields = fields
-        self._t0 = time.monotonic_ns()
+        self.dur_ns = 0
+        self._cpu_ns = None
+        self._mirror = None
         self._ended = False
+        self.start_ns = time.time_ns()
 
     def event(self, name: str, **fields) -> None:
         if not _enabled:
             return
-        _emit("event", name, fields, span_id=self.id)
+        _emit_event(name, fields, self.id)
+
+    def set(self, **fields) -> None:
+        """Fields known only once the work is under way (a result, a
+        lane count) join the record that ``end()`` emits."""
+        if self.fields is None:
+            self.fields = fields
+        else:
+            self.fields.update(fields)
 
     def end(self, **fields) -> None:
         if self._ended:
             return
         self._ended = True
+        # the epoch clock can be stepped back under a span: never negative
+        self.dur_ns = max(0, time.time_ns() - self.start_ns)
         if not _enabled:
             # tracing was turned off mid-span: drop the record — once
             # disabled, nothing reaches the ring or sink
             return
-        merged = self.fields
         if fields:
-            merged = dict(merged or ())
-            merged.update(fields)
-        _emit(
-            "span",
-            self.name,
-            merged,
-            span_id=self.id,
-            parent_id=self.parent,
-            dur_ns=time.monotonic_ns() - self._t0,
-        )
+            self.set(**fields)
+        rec: dict = {
+            "ts": self.start_ns + self.dur_ns,
+            "kind": "span",
+            "name": self.name,
+            "thread": threading.current_thread().name,
+            "span": self.id,
+        }
+        if self.parent:
+            rec["parent"] = self.parent
+        if self.root:
+            rec["root"] = self.root
+        rec["start_ns"] = self.start_ns
+        rec["dur_ns"] = self.dur_ns
+        if self._cpu_ns is not None:
+            rec["cpu_ns"] = self._cpu_ns
+        _emit(rec, self.fields)
 
     def __enter__(self) -> "Span":
-        _span_stack().append(self)
+        stack = _span_stack()
+        self.root = stack[0].id if stack else self.id
+        stack.append(self)
+        cls = _annotation_cls()
+        if cls is not None:
+            self._mirror = cls("bft." + self.name, **{
+                k: v for k, v in (self.fields or {}).items()
+                if isinstance(v, int)
+                or (isinstance(v, str) and len(v) <= _ANNOTATION_STR_MAX)
+            })
+            self._mirror.__enter__()
+        self._cpu0 = time.thread_time_ns()
         return self
 
     def __exit__(self, *exc) -> None:
+        self._cpu_ns = time.thread_time_ns() - self._cpu0
+        if self._mirror is not None:
+            self._mirror.__exit__(*exc)
+            self._mirror = None
         stack = _span_stack()
         for i in range(len(stack) - 1, -1, -1):
             if stack[i] is self:
@@ -244,6 +310,9 @@ class _NopSpan:
     id = 0
 
     def event(self, name: str, **fields) -> None:
+        pass
+
+    def set(self, **fields) -> None:
         pass
 
     def end(self, **fields) -> None:

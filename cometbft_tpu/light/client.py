@@ -11,11 +11,17 @@ a few TPU launches rather than thousands of host verifies.
 
 from __future__ import annotations
 
+import contextlib
 import time as _time
 from dataclasses import dataclass, field
 
-from ..types.validation import DEFAULT_TRUST_LEVEL, Fraction
-from ..types.light_block import LightBlock
+from ..libs import metrics as libmetrics
+from ..types.validation import (
+    DEFAULT_TRUST_LEVEL,
+    Fraction,
+    VerificationError,
+)
+from ..types.light_block import LightBlock, LightBlockError
 from . import verifier
 from .errors import (
     BadLightBlockError,
@@ -35,6 +41,35 @@ SECOND_NS = verifier.SECOND_NS
 # pivot = trusted + 9/10 * (target - trusted)  (client.go:46-52)
 _PIVOT_NUM = 9
 _PIVOT_DEN = 10
+
+
+# a header's span ends "refuse" when the client looked at a block and said
+# no: one of _REFUSAL that is not of _NO_ANSWER (the provider gave nothing
+# to judge). Anything else that escapes is "error".
+_REFUSAL = (LightClientError, LightBlockError, VerificationError)
+_NO_ANSWER = (
+    FailedHeaderCrossReferencingError,
+    LightBlockNotFoundError,
+    NoWitnessesError,
+)
+
+
+@contextlib.contextmanager
+def _header_phase(height: int):
+    """Root of one header's spans and the ``header`` phase: everything
+    the client does to come to hold ``height``, the fetch included."""
+    with libmetrics.light_phase(
+        "header", "light.verify_header", height=height
+    ) as ph:
+        try:
+            yield
+        except Exception as e:
+            refused = isinstance(e, _REFUSAL) and not isinstance(
+                e, _NO_ANSWER
+            )
+            ph.set(result="refuse" if refused else "error")
+            raise
+        ph.set(result="accept")
 
 
 @dataclass(frozen=True)
@@ -83,30 +118,31 @@ class Client:
         last_h = self.trusted_store.last_light_block_height()
         if last_h > 0:
             return  # previously initialized: keep the store's root of trust
-        lb = self._block_from(self.primary, self.trust_options.height)
-        if lb.height != self.trust_options.height:
-            raise LightClientError(
-                f"trusted provider returned height {lb.height}, "
-                f"expected {self.trust_options.height}"
+        with _header_phase(self.trust_options.height):
+            lb = self._block_from(self.primary, self.trust_options.height)
+            if lb.height != self.trust_options.height:
+                raise LightClientError(
+                    f"trusted provider returned height {lb.height}, "
+                    f"expected {self.trust_options.height}"
+                )
+            if lb.hash() != self.trust_options.hash:
+                raise LightClientError(
+                    f"trusted header hash mismatch: got {lb.hash().hex()}, "
+                    f"expected {self.trust_options.hash.hex()}"
+                )
+            lb.validate_basic(self.chain_id)
+            # 2/3 of the block's own validator set must have signed it
+            # (initializeWithTrustOptions, client.go:362-401) — through the
+            # plane, so the proof service's root checks cache/dedupe too.
+            cv = self.commit_verifier or verifier.DEFAULT_COMMIT_VERIFIER
+            cv.verify_commit_light(
+                self.chain_id,
+                lb.validator_set,
+                lb.signed_header.commit.block_id,
+                lb.height,
+                lb.signed_header.commit,
             )
-        if lb.hash() != self.trust_options.hash:
-            raise LightClientError(
-                f"trusted header hash mismatch: got {lb.hash().hex()}, "
-                f"expected {self.trust_options.hash.hex()}"
-            )
-        lb.validate_basic(self.chain_id)
-        # 2/3 of the block's own validator set must have signed it
-        # (initializeWithTrustOptions, client.go:362-401) — through the
-        # plane, so the proof service's root checks cache/dedupe too.
-        cv = self.commit_verifier or verifier.DEFAULT_COMMIT_VERIFIER
-        cv.verify_commit_light(
-            self.chain_id,
-            lb.validator_set,
-            lb.signed_header.commit.block_id,
-            lb.height,
-            lb.signed_header.commit,
-        )
-        self.trusted_store.save_light_block(lb)
+            self.trusted_store.save_light_block(lb)
 
     # -- public API --------------------------------------------------------
 
@@ -144,8 +180,9 @@ class Client:
             return self.trusted_store.light_block(height)
         except LightBlockNotFoundError:
             pass
-        lb = self._block_from(self.primary, height)
-        self.verify_light_block(lb, now_ns)
+        with _header_phase(height):
+            lb = self._block_from(self.primary, height)
+            self.verify_light_block(lb, now_ns)
         return lb
 
     def verify_light_block(
@@ -304,7 +341,8 @@ class Client:
     # -- internals ---------------------------------------------------------
 
     def _block_from(self, p: Provider, height: int) -> LightBlock:
-        lb = p.light_block(height)
+        with libmetrics.light_phase("fetch", "light.fetch", height=height):
+            lb = p.light_block(height)
         if lb is None:
             raise LightBlockNotFoundError(height)
         try:

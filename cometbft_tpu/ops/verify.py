@@ -42,6 +42,9 @@ from . import warm as libwarm
 
 L = curve.L
 _MIN_BUCKET = 8
+# the phase label of this module's own batches (verify_batch); callers
+# that bring their own packing (coalescer, sr25519, mixed) pass theirs
+_BACKEND = "ed25519-tpu"
 
 _LIMB_WEIGHTS = (1 << np.arange(field.BITS, dtype=np.int32))  # (13,)
 _NIB_WEIGHTS = np.array([1, 2, 4, 8], np.int32)
@@ -1108,7 +1111,34 @@ def _run_kernel(buf):
     return out, None
 
 
-def _materialize(out, used_pallas, buf):
+def _start_readback(out) -> None:
+    """Queue the ok mask's d2h copy behind its kernel at launch, so that
+    materializing blocks once (:func:`_kernel_wait`) and ``np.asarray``
+    finds the bytes on the host. Fetching only after the wait is a
+    second blocking call, one more hand-over of the GIL per launch:
+    with two light clients under one GIL that read 3% of sigs_per_s
+    (PERF.md, PR 24)."""
+    out.copy_to_host_async()
+
+
+def _kernel_wait(out, backend: str, lanes: int) -> None:
+    """Block until a launch's output is ready: the wait for the kernel
+    (and the copy :func:`_start_readback` queued behind it), apart from
+    the ``np.asarray`` and the unpack that follow
+    (``crypto_verify_phase_seconds{phase="kernel_wait"}`` and the
+    ``verify.kernel_wait`` span, inside the caller's readback)."""
+    with libmetrics.TimedPhase(
+        libmetrics.node_metrics().verify_phase_seconds.labels(
+            "kernel_wait", backend
+        ),
+        "verify.kernel_wait", backend=backend, lanes=lanes,
+    ):
+        # cometlint: disable=CLNT002 -- first half of the sanctioned
+        # per-launch readback: the wait, timed apart from the copy
+        out.block_until_ready()
+
+
+def _materialize(out, used_pallas, buf, backend: str, lanes: int):
     """np.asarray(out) with device-side pallas faults rerouted: the
     faulting flavor is retired and the launch retried through
     :func:`_run_kernel` (sibling flavor, then XLA). Bounded — each
@@ -1118,6 +1148,7 @@ def _materialize(out, used_pallas, buf):
     bucket/8 uint8 words, what record_d2h counts); the return value is
     the unpacked (bucket,) bool bitmap callers slice."""
     try:
+        _kernel_wait(out, backend, lanes)
         # cometlint: disable=CLNT002 -- THE sanctioned per-launch readback:
         # every async dispatch materializes exactly once, here
         arr = np.asarray(out)
@@ -1126,7 +1157,7 @@ def _materialize(out, used_pallas, buf):
             raise
         _note_pallas_broken(used_pallas, e)
         out2, which2 = _run_kernel(buf)
-        return _materialize(out2, which2, buf)
+        return _materialize(out2, which2, buf, backend, lanes)
     libdevstats.record_d2h(arr.nbytes)
     return unpack_ok_bits(arr, 8 * arr.shape[0])
 
@@ -1145,14 +1176,14 @@ _CHUNK = 16384
 _PIPE_CHUNK = 16384
 
 
-def verify_bytes_async(buf: np.ndarray, n: int):
+def verify_bytes_async(buf: np.ndarray, n: int, backend: str = _BACKEND):
     """Dispatch a packed wire buffer to the device without blocking.
 
     Returns a zero-arg closure that materializes the (n,) validity bitmap;
     callers can overlap host work (packing the next batch, consensus
     bookkeeping) with device execution and pay the readback sync once.
     Batches beyond the per-launch sweet spot are auto-chunked and
-    pipelined.
+    pipelined. ``backend`` labels the closure's kernel-wait phase.
     """
     if n > _CHUNK:
         outs = []
@@ -1166,15 +1197,17 @@ def verify_bytes_async(buf: np.ndarray, n: int):
             if hi - lo < size:
                 piece = np.pad(piece, [(0, 0), (0, size - (hi - lo))])
             out, used_pallas = _run_kernel(piece)
+            _start_readback(out)
             outs.append((out, used_pallas, piece, hi - lo))
         return lambda: np.concatenate(
-            [_materialize(o, up, p)[:m] for o, up, p, m in outs]
+            [_materialize(o, up, p, backend, m)[:m] for o, up, p, m in outs]
         )
     size = bucket_size(n)
     if size != n:
         buf = np.pad(buf, [(0, 0), (0, size - n)])
     out, used_pallas = _run_kernel(buf)
-    return lambda: _materialize(out, used_pallas, buf)[:n]
+    _start_readback(out)
+    return lambda: _materialize(out, used_pallas, buf, backend, n)[:n]
 
 
 def _cache_enabled() -> bool:
@@ -1244,7 +1277,7 @@ def _verify_batch_sharded(pubkeys, msgs, sigs, n_dev: int):
 
 
 def verify_rsk_async(buf: np.ndarray, idxs: np.ndarray, arena, arena_ok,
-                     n: int):
+                     n: int, backend: str = _BACKEND):
     """Dispatch a cached-table launch: (96, n) R|S|kneg rows + arena slots.
 
     Same async contract as :func:`verify_bytes_async`. ``n`` must be
@@ -1254,11 +1287,13 @@ def verify_rsk_async(buf: np.ndarray, idxs: np.ndarray, arena, arena_ok,
         buf = np.pad(buf, [(0, 0), (0, size - n)])
         idxs = np.pad(idxs, (0, size - n))  # slot 0 gather: harmless
     out, used_pallas = _run_cached_kernel(arena, arena_ok, idxs, buf)
+    _start_readback(out)
 
     def materialize():
         o, which = out, used_pallas
         while True:
             try:
+                _kernel_wait(o, backend, n)
                 # cometlint: disable=CLNT002 -- sanctioned readback of the
                 # cached-table launch (the _materialize analog)
                 arr = np.asarray(o)
@@ -1278,7 +1313,7 @@ def verify_rsk_async(buf: np.ndarray, idxs: np.ndarray, arena, arena_ok,
     return materialize
 
 
-def verify_prepacked(buf: np.ndarray, keys, n: int):
+def verify_prepacked(buf: np.ndarray, keys, n: int, backend: str):
     """Async verify of a pre-packed (128, n) wire buffer with cache routing.
 
     ``keys``: per-lane 32-byte edwards A encodings (b"" / short for
@@ -1289,7 +1324,7 @@ def verify_prepacked(buf: np.ndarray, keys, n: int):
     encoding itself.
     """
     if not _cache_enabled():
-        return verify_bytes_async(buf, n)
+        return verify_bytes_async(buf, n, backend)
     finals = []
     for lo in range(0, n, _CHUNK):
         hi = min(lo + _CHUNK, n)
@@ -1298,11 +1333,14 @@ def verify_prepacked(buf: np.ndarray, keys, n: int):
             idxs, arena, arena_ok = hit
             finals.append(
                 verify_rsk_async(
-                    buf[32:, lo:hi], idxs, arena, arena_ok, hi - lo
+                    buf[32:, lo:hi], idxs, arena, arena_ok, hi - lo,
+                    backend,
                 )
             )
         else:
-            finals.append(verify_bytes_async(buf[:, lo:hi], hi - lo))
+            finals.append(
+                verify_bytes_async(buf[:, lo:hi], hi - lo, backend)
+            )
     if len(finals) == 1:
         return finals[0]
     return lambda: np.concatenate([f() for f in finals])
@@ -1362,6 +1400,14 @@ def window_ready(pubkeys) -> bool:
     return ready
 
 
+def _chunk_phase(phase: str, lanes: int, **fields):
+    """One verify_batch phase of one pipelined chunk: the span alone;
+    verify_batch observes the histogram once per batch."""
+    return libmetrics.TimedPhase(
+        None, "verify." + phase, backend=_BACKEND, lanes=lanes, **fields
+    )
+
+
 def verify_batch(pubkeys, msgs, sigs) -> tuple[bool, np.ndarray]:
     """Verify a batch of ed25519 signatures on device.
 
@@ -1381,57 +1427,59 @@ def verify_batch(pubkeys, msgs, sigs) -> tuple[bool, np.ndarray]:
     if devs is not None:
         return _verify_batch_sharded(pubkeys, msgs, sigs, len(devs))
     use_cache = _cache_enabled()
-    finals, host_oks = [], []
-    # Phase attribution (crypto_verify_phase_seconds + verify.* trace
-    # events): pack = host staging incl. the arena lookup (a miss's
-    # builder launch is part of staging cost), dispatch = the async jit
-    # launches, readback = the one sanctioned materialization. Summed
-    # across pipelined chunks so the three phases tile the end-to-end
-    # crypto_verify_batch_seconds interval.
-    pack_s = disp_s = 0.0
-    arena_state = "hit" if use_cache else "off"
-    builds_before = _PUBKEY_CACHE.builds
+    chunks = []  # (materialize, host_ok, lanes, arena disposition)
+    # Phase attribution: pack = host staging incl. the arena lookup (a
+    # miss's builder launch is part of staging cost), dispatch = the
+    # async jit launches, readback = the one sanctioned materialization
+    # (the wait for the kernel, then copy and unpack). Each is a
+    # verify.<phase> span per pipelined chunk, so the lanes of a batch's
+    # spans of one name add up to the batch; their durations are summed
+    # into ONE crypto_verify_phase_seconds observation per batch, so the
+    # three phases tile the crypto_verify_batch_seconds interval.
+    pack_ns = disp_ns = read_ns = 0
     step = min(_PIPE_CHUNK, _CHUNK)
     for lo in range(0, n, step):
         hi = min(lo + step, n)
         # Pipeline host packing with device execution: each chunk is
         # dispatched as soon as it is packed, so the per-lane SHA-512 /
         # packing cost of chunk i+1 overlaps chunk i's kernel time.
-        tp = time.perf_counter()
-        buf, hok = pack_bytes(pubkeys[lo:hi], msgs[lo:hi], sigs[lo:hi])
-        hit = _PUBKEY_CACHE.lookup(pubkeys[lo:hi]) if use_cache else None
-        td = time.perf_counter()
-        pack_s += td - tp
-        if hit is not None:
-            idxs, arena, arena_ok = hit
-            finals.append(
-                verify_rsk_async(buf[32:], idxs, arena, arena_ok, hi - lo)
-            )
-        else:
-            if use_cache:
+        builds_before = _PUBKEY_CACHE.builds
+        with _chunk_phase("pack", hi - lo) as ph:
+            buf, hok = pack_bytes(pubkeys[lo:hi], msgs[lo:hi], sigs[lo:hi])
+            hit = _PUBKEY_CACHE.lookup(pubkeys[lo:hi]) if use_cache else None
+            if not use_cache:
+                arena_state = "off"
+            elif hit is None:
                 arena_state = "bypass"  # churn exhausted the arena
-            finals.append(verify_bytes_async(buf, hi - lo))
-        disp_s += time.perf_counter() - td
-        host_oks.append(hok)
-    if use_cache and arena_state == "hit" and (
-        _PUBKEY_CACHE.builds > builds_before
-    ):
-        arena_state = "miss"  # lookup succeeded but had to build tables
-    tr = time.perf_counter()
-    if len(finals) == 1:
-        device_ok, host_ok = finals[0](), host_oks[0]
+            elif _PUBKEY_CACHE.builds > builds_before:
+                arena_state = "miss"  # lookup had to build tables
+            else:
+                arena_state = "hit"
+            ph.set(arena=arena_state)
+        pack_ns += ph.dur_ns
+        with _chunk_phase("dispatch", hi - lo, arena=arena_state) as ph:
+            if hit is not None:
+                idxs, arena, arena_ok = hit
+                finish = verify_rsk_async(
+                    buf[32:], idxs, arena, arena_ok, hi - lo
+                )
+            else:
+                finish = verify_bytes_async(buf, hi - lo)
+        disp_ns += ph.dur_ns
+        chunks.append((finish, hok, hi - lo, arena_state))
+    device_oks = []
+    for finish, _, lanes, arena_state in chunks:
+        with _chunk_phase("readback", lanes, arena=arena_state) as ph:
+            device_oks.append(finish())
+        read_ns += ph.dur_ns
+    if len(chunks) == 1:
+        device_ok, host_ok = device_oks[0], chunks[0][1]
     else:
-        device_ok = np.concatenate([f() for f in finals])
-        host_ok = np.concatenate(host_oks)
-    read_s = time.perf_counter() - tr
-    libmetrics.observe_verify_phase(
-        "pack", "ed25519-tpu", pack_s, n, arena=arena_state
-    )
-    libmetrics.observe_verify_phase(
-        "dispatch", "ed25519-tpu", disp_s, n, arena=arena_state
-    )
-    libmetrics.observe_verify_phase(
-        "readback", "ed25519-tpu", read_s, n, arena=arena_state
-    )
+        device_ok = np.concatenate(device_oks)
+        host_ok = np.concatenate([c[1] for c in chunks])
+    hist = libmetrics.node_metrics().verify_phase_seconds
+    hist.labels("pack", _BACKEND).observe(pack_ns / 1e9)
+    hist.labels("dispatch", _BACKEND).observe(disp_ns / 1e9)
+    hist.labels("readback", _BACKEND).observe(read_ns / 1e9)
     valid = device_ok & host_ok
     return bool(valid.all()), valid

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+from ..libs import metrics as libmetrics
 from .block import Commit, Header
 from .validator_set import ValidatorSet
 
@@ -42,27 +43,28 @@ class SignedHeader:
     def validate_basic(self, chain_id: str) -> None:
         """types/light.go SignedHeader.ValidateBasic: header/commit present,
         matching chain id and height, commit signs THIS header."""
-        if self.header is None:
-            raise LightBlockError("missing header")
-        if self.commit is None:
-            raise LightBlockError("missing commit")
-        self.header.validate_basic()
-        self.commit.validate_basic()
-        if self.header.chain_id != chain_id:
-            raise LightBlockError(
-                f"header chain id {self.header.chain_id!r} != {chain_id!r}"
-            )
-        if self.commit.height != self.header.height:
-            raise LightBlockError(
-                f"commit height {self.commit.height} != header height "
-                f"{self.header.height}"
-            )
-        if self.commit.block_id.hash != self.header.hash():
-            raise LightBlockError(
-                "commit signs a different header "
-                f"({self.commit.block_id.hash.hex()} != "
-                f"{(self.header.hash() or b'').hex()})"
-            )
+        with libmetrics.light_phase("header_basic", "light.header_basic"):
+            if self.header is None:
+                raise LightBlockError("missing header")
+            if self.commit is None:
+                raise LightBlockError("missing commit")
+            self.header.validate_basic()
+            self.commit.validate_basic()
+            if self.header.chain_id != chain_id:
+                raise LightBlockError(
+                    f"header chain id {self.header.chain_id!r} != {chain_id!r}"
+                )
+            if self.commit.height != self.header.height:
+                raise LightBlockError(
+                    f"commit height {self.commit.height} != header height "
+                    f"{self.header.height}"
+                )
+            if self.commit.block_id.hash != self.header.hash():
+                raise LightBlockError(
+                    "commit signs a different header "
+                    f"({self.commit.block_id.hash.hex()} != "
+                    f"{(self.header.hash() or b'').hex()})"
+                )
 
 
 @dataclass(frozen=True, slots=True)

@@ -17,6 +17,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 
 from ..crypto import batch as crypto_batch
+from ..libs import metrics as libmetrics
 from .block import (
     BLOCK_ID_FLAG_ABSENT,
     BLOCK_ID_FLAG_COMMIT,
@@ -173,28 +174,32 @@ def _verify_batch(
     seen: dict[int, int] = {}
     batch_sig_idxs: list[int] = []
     tallied = 0
-    for idx, cs in enumerate(commit.signatures):
-        if ignore(cs):
-            continue
-        if by_index:
-            val = vals.validators[idx]
-        else:
-            val_idx, val = vals.get_by_address(cs.validator_address)
-            if val is None:
+    # one span for the whole walk (sign-bytes, bv.add, tally), never one
+    # per lane; bv.verify() has the verify.* phases of its own
+    with libmetrics.light_phase("sign_bytes", "commit.sign_bytes") as ph:
+        for idx, cs in enumerate(commit.signatures):
+            if ignore(cs):
                 continue
-            if val_idx in seen:
-                raise VerificationError(
-                    f"double vote from validator {val_idx} "
-                    f"({seen[val_idx]} and {idx})"
-                )
-            seen[val_idx] = idx
-        sign_bytes = commit.vote_sign_bytes(chain_id, idx)
-        bv.add(val.pub_key, sign_bytes, cs.signature)
-        batch_sig_idxs.append(idx)
-        if count(cs):
-            tallied += val.voting_power
-        if not count_all and tallied > needed:
-            break
+            if by_index:
+                val = vals.validators[idx]
+            else:
+                val_idx, val = vals.get_by_address(cs.validator_address)
+                if val is None:
+                    continue
+                if val_idx in seen:
+                    raise VerificationError(
+                        f"double vote from validator {val_idx} "
+                        f"({seen[val_idx]} and {idx})"
+                    )
+                seen[val_idx] = idx
+            sign_bytes = commit.vote_sign_bytes(chain_id, idx)
+            bv.add(val.pub_key, sign_bytes, cs.signature)
+            batch_sig_idxs.append(idx)
+            if count(cs):
+                tallied += val.voting_power
+            if not count_all and tallied > needed:
+                break
+        ph.set(lanes=len(batch_sig_idxs))
     if tallied <= needed:
         raise NotEnoughVotingPowerError(got=tallied, needed=needed)
     ok, valid_sigs = bv.verify()

@@ -19,6 +19,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 
 from ..crypto import merkle
+from ..libs import metrics as libmetrics
 from . import proto
 
 INT64_MAX = (1 << 63) - 1
@@ -135,9 +136,13 @@ class ValidatorSet:
         return self.get_by_address(address)[0] >= 0
 
     def hash(self) -> bytes:
-        return merkle.hash_from_byte_slices(
-            [v.bytes() for v in self.validators]
-        )
+        with libmetrics.light_phase(
+            "valset_hash", "types.valset_hash",
+            validators=len(self.validators),
+        ):
+            return merkle.hash_from_byte_slices(
+                [v.bytes() for v in self.validators]
+            )
 
     def copy(self) -> "ValidatorSet":
         cp = ValidatorSet.__new__(ValidatorSet)

@@ -585,6 +585,51 @@ class TestTrace:
         assert conn_stats._cols[0][0] == 0  # no packets counted while off
         assert netstats.gossip_lag_s() == 0.0
 
+    def test_disabled_light_path_retains_no_trace_allocations(self):
+        """The same guard over the light-client path: with tracing off,
+        headers verified through light.Client (fetch, three validator-
+        set hashes, validate_basic, the sign-bytes walk, the batch
+        verifier) build no span, retain nothing allocated in
+        libs/trace and leave the ring empty; the always-on phase
+        histograms still count them."""
+        import helpers
+        from cometbft_tpu import light
+        from cometbft_tpu.light.store import MemStore
+        from test_light import PERIOD, DictProvider, now_after
+
+        assert not libtrace.enabled()
+        libtrace.reset()
+        blocks = helpers.make_light_chain(4, n_vals=8)
+        provider = DictProvider(blocks)
+        now = now_after(blocks, 4)
+        m = NodeMetrics()
+        libmetrics.push_node_metrics(m)
+
+        def hot():
+            for _ in range(20):
+                client = light.Client(
+                    chain_id=helpers.CHAIN_ID,
+                    trust_options=light.TrustOptions(
+                        PERIOD, 1, blocks[1].hash()
+                    ),
+                    primary=provider,
+                    trusted_store=MemStore(),
+                )
+                for h in (2, 3, 4):
+                    client.verify_light_block_at_height(h, now)
+
+        try:
+            hot()  # warm interpreter caches outside the measured window
+            stats = _retained_after(hot, [libtrace.__file__])
+        finally:
+            libmetrics.pop_node_metrics(m)
+        assert sum(s.size for s in stats) == 0, stats
+        assert libtrace.ring_dump() == []
+        assert not getattr(libtrace._tls, "spans", None)
+        headers = m.light_verify_phase_seconds.labels("header")
+        assert headers._n >= 2 * 20 * 4  # root check + three heights
+        assert m.light_verify_phase_seconds.labels("valset_hash")._n > 0
+
     def test_flight_recorder_steady_state_allocation_free(self):
         """The health layer's stricter guard: the flight recorder is ON
         by default for every node, so its ENABLED record path — and the
@@ -1029,15 +1074,20 @@ class TestVerifyPhases:
         finally:
             libmetrics.pop_node_metrics(m)
         assert ok and all(bitmap)
-        evs = [
+        recs = [
             e
             for e in libtrace.ring_dump()
             if e["name"].startswith("verify.")
             and e.get("backend") == "ed25519-tpu"
         ]
+        # real spans now, one record per phase (no event beside it)
+        assert all(e["kind"] == "span" for e in recs)
+        assert all(e["lanes"] == 8 for e in recs)
+        # kernel_wait lies inside readback: it is not one of the tiles
+        evs = [e for e in recs if e["name"] != "verify.kernel_wait"]
         phases = {e["name"].split(".", 1)[1] for e in evs}
-        assert {"pack", "dispatch", "readback"} <= phases, phases
-        assert all(e["lanes"] == 8 for e in evs)
+        assert phases == {"pack", "dispatch", "readback"}, phases
+        assert len(evs) == 3 and len(recs) == 4
         assert all(
             e["arena"] in ("hit", "miss", "bypass", "off") for e in evs
         )
@@ -1048,7 +1098,7 @@ class TestVerifyPhases:
         assert phase_s >= total_s * 0.3, (phase_s, total_s)
         # Prometheus carries the same families
         text = m.registry.render()
-        for ph in ("pack", "dispatch", "readback"):
+        for ph in ("pack", "dispatch", "readback", "kernel_wait"):
             assert f'phase="{ph}",backend="ed25519-tpu"' in text
 
 
@@ -1693,10 +1743,12 @@ class TestConsensusTraceBurst:
         assert any(e["name"] == "consensus.preverify" for e in events)
 
         # device phase events tile the end-to-end batch observations
+        # (kernel_wait lies inside readback: it is not one of the tiles)
         phase_evs = [
             e
             for e in events
             if e["name"].startswith("verify.")
+            and e["name"] != "verify.kernel_wait"
             and e.get("backend") == "ed25519-tpu"
         ]
         phases = {e["name"].split(".", 1)[1] for e in phase_evs}
