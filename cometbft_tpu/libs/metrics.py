@@ -434,6 +434,17 @@ class NodeMetrics:
                 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5,
             ),
         )
+        # ValidatorSet.hash() calls by what answered: "computed" (the
+        # leaves were encoded and the tree built) or "reused" (the set's
+        # kept root, its witness checked). Both series exist from the
+        # start, so a ratio over them reads 0 and not nothing.
+        self.valset_hash_total = r.counter(
+            "types_valset_hash_total",
+            "ValidatorSet.hash() calls by result",
+            label_names=("result",),
+        )
+        for result in ("computed", "reused"):
+            self.valset_hash_total.labels(result)
         # cross-caller verify coalescer (crypto/coalesce.py): window
         # shape, flush triggers, and the submit-to-verdict latency its
         # callers actually pay. Lane-count buckets are the shape
@@ -1077,6 +1088,13 @@ def light_phase(phase: str, span_name: str, **fields) -> TimedPhase:
         node_metrics().light_verify_phase_seconds.labels(phase),
         span_name, **fields,
     )
+
+
+def observe_valset_hash(reused: bool) -> None:
+    """One ``ValidatorSet.hash()`` call: types_valset_hash_total{result}."""
+    node_metrics().valset_hash_total.labels(
+        "reused" if reused else "computed"
+    ).inc()
 
 
 def observe_hash_phase(phase: str, seconds: float, lanes: int) -> None:

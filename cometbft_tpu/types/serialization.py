@@ -112,6 +112,7 @@ def _valset_dec(payload: dict) -> ValidatorSet:
     vs = ValidatorSet.__new__(ValidatorSet)
     vs.validators = list(payload["validators"])
     vs._total = None
+    vs._root_memo = None  # _valset_enc never writes it
     vs.proposer = None
     addr = payload["proposer_address"]
     if addr:

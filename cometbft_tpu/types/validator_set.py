@@ -7,7 +7,8 @@ Reference: types/validator.go, types/validator_set.go:
   then `times` rounds of (everyone += power; max -= total)
   (validator_set.go:116-178);
 * set hash = merkle root of SimpleValidator proto encodings
-  (validator.go:117-133);
+  (validator.go:117-133), kept per set behind a check of what the leaves
+  were made of (ValidatorSet.hash);
 * updates: changed/added vals merged, added vals start at
   -1.125*new-total priority (validator_set.go:477-495).
 
@@ -99,6 +100,8 @@ class ValidatorSet:
         )
         self.proposer: Validator | None = None
         self._total: int | None = None
+        # (root, pub_keys, voting_powers) of the last hash(); see hash()
+        self._root_memo: tuple[bytes, tuple, tuple] | None = None
         if self.validators:
             self.increment_proposer_priority(1)
 
@@ -136,19 +139,48 @@ class ValidatorSet:
         return self.get_by_address(address)[0] >= 0
 
     def hash(self) -> bytes:
+        """Merkle root of the validators' SimpleValidator leaves, in order.
+
+        A leaf is made of ``pub_key`` and ``voting_power`` alone (address
+        and proposer priority are not hashed). The set keeps the root it
+        last computed with a witness of those leaves: the ordered
+        ``pub_key`` objects (referenced, so an identity is never reused;
+        the key classes are frozen and compare by their bytes) and the
+        ordered powers. The kept root is returned only while both still
+        equal the set's present contents, so a change made in place
+        (a power, a key, a validator replaced, added or removed) is seen
+        by the next call, which then runs ``merkle.hash_from_byte_slices``
+        as a set without a memo does. The memo is one tuple published in
+        one store (two threads on a cold set both compute the same value);
+        ``copy()`` carries it, ``update_with_change_set`` drops it, and
+        types/serialization never writes it: it lives in this process only.
+        """
+        vals = self.validators
         with libmetrics.light_phase(
-            "valset_hash", "types.valset_hash",
-            validators=len(self.validators),
-        ):
-            return merkle.hash_from_byte_slices(
-                [v.bytes() for v in self.validators]
+            "valset_hash", "types.valset_hash", validators=len(vals),
+        ) as phase:
+            keys = tuple([v.pub_key for v in vals])
+            powers = tuple([v.voting_power for v in vals])
+            memo = self._root_memo
+            reused = (
+                memo is not None and memo[1] == keys and memo[2] == powers
             )
+            if reused:
+                root = memo[0]
+            else:
+                root = merkle.hash_from_byte_slices([v.bytes() for v in vals])
+                self._root_memo = (root, keys, powers)
+            phase.set(reused=int(reused))
+        libmetrics.observe_valset_hash(reused)
+        return root
 
     def copy(self) -> "ValidatorSet":
         cp = ValidatorSet.__new__(ValidatorSet)
         cp.validators = [v.copy() for v in self.validators]
         cp.proposer = None
         cp._total = self._total
+        # Validator.copy() keeps the pub_key object: the witness holds
+        cp._root_memo = self._root_memo
         if self.proposer is not None:
             idx, _ = cp.get_by_address(self.proposer.address)
             cp.proposer = cp.validators[idx] if idx >= 0 else self.proposer.copy()
@@ -274,6 +306,7 @@ class ValidatorSet:
 
         self.validators = sorted(merged.values(), key=_sort_key)
         self._total = None
+        self._root_memo = None
         self.rescale_priorities(
             PRIORITY_WINDOW_SIZE_FACTOR * self.total_voting_power()
         )

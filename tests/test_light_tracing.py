@@ -140,6 +140,11 @@ def test_one_header_is_one_tree_that_tiles(one_header):
     assert {t[2] for t in tiles} == set(TILING)
     per_name = {n: sum(1 for t in tiles if t[2] == n) for n in TILING}
     assert per_name["types.valset_hash"] == 3  # what a later PR may cut
+    # each of the three is answered from the set's kept root: the chain's
+    # builder hashed the set, and the provider serves that object
+    hashes = [s for s in spans if s["name"] == "types.valset_hash"]
+    assert [s["reused"] for s in hashes] == [1, 1, 1]
+    assert all(s["validators"] == N_VALS for s in hashes)
     assert per_name["light.header_basic"] == 3
     assert per_name["commit.sign_bytes"] == 1
     (sb,) = [s for s in spans if s["name"] == "commit.sign_bytes"]
@@ -159,6 +164,48 @@ def test_histogram_sum_is_the_rings_duration(one_header, span_name):
     # one observation per span, except the three phases verify_batch
     # sums over a batch's chunks (one chunk here)
     assert child._n == sum(1 for s in spans if s["name"] == span_name)
+
+
+def test_valset_hash_counter_has_both_series(one_header):
+    """What valset_hash_reuse_pct.replay divides: one count per span."""
+    _, m = one_header
+    assert m.valset_hash_total.labels("reused").value() == 3
+    assert m.valset_hash_total.labels("computed").value() == 0
+    text = m.registry.render()
+    for result in ("computed", "reused"):
+        assert (
+            'cometbft_tpu_types_valset_hash_total{result="%s"} ' % result
+        ) in text
+
+
+class _FreshSetProvider(DictProvider):
+    """A provider that decodes its reply: a set object of its own per
+    light block, with no root kept."""
+
+    def light_block(self, height):
+        import dataclasses
+
+        from cometbft_tpu.types import serialization
+
+        lb = super().light_block(height)
+        vals = serialization.loads(serialization.dumps(lb.validator_set))
+        return dataclasses.replace(lb, validator_set=vals)
+
+
+def test_a_decoded_set_is_hashed_once_a_header(device_route, tracer, metrics):
+    blocks = helpers.make_light_chain(3, n_vals=N_VALS)
+    client = _client(blocks, _FreshSetProvider(blocks))
+    libtrace.reset()
+    m = NodeMetrics()
+    libmetrics.push_node_metrics(m)
+    try:
+        client.verify_light_block_at_height(2, now_after(blocks, 2))
+    finally:
+        libmetrics.pop_node_metrics(m)
+    hashes = [s for s in _spans() if s["name"] == "types.valset_hash"]
+    assert [s["reused"] for s in hashes] == [0, 1, 1]
+    assert m.valset_hash_total.labels("computed").value() == 1
+    assert m.valset_hash_total.labels("reused").value() == 2
 
 
 class _DownProvider(DictProvider):
@@ -370,6 +417,7 @@ NEW_METRICS = (
     "provider_fetch_ms_per_header", "valset_hash_ms_per_header",
     "header_basic_ms_per_header", "sign_bytes_ms_per_header",
     "kernel_wait_ms_per_header", "light_span_coverage_pct.replay",
+    "valset_hash_reuse_pct.replay",
 )
 
 

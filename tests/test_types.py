@@ -274,6 +274,254 @@ class TestValidatorSet:
             )
 
 
+# --- the validator set's kept root (ValidatorSet.hash) ----------------------
+
+
+def _leaves_root(vals):
+    """What a set without a memo computes: the reference's definition."""
+    from cometbft_tpu.crypto import merkle
+
+    return merkle.hash_from_byte_slices([v.bytes() for v in vals.validators])
+
+
+def _other_key(i=0x70):
+    return MockPV(Ed25519PrivKey.from_seed(bytes([i]) * 32)).get_pub_key()
+
+
+def _set_power(vals):
+    vals.validators[1].voting_power += 7
+
+
+def _replace_validator(vals):
+    vals.validators[2] = Validator(pub_key=_other_key(), voting_power=10)
+
+
+def _append_validator(vals):
+    vals.validators.append(Validator(pub_key=_other_key(), voting_power=10))
+
+
+def _remove_validator(vals):
+    del vals.validators[0]
+
+
+def _swap_pub_key(vals):
+    vals.validators[3].pub_key = _other_key()
+
+
+def _swap_two_validators(vals):
+    v = vals.validators
+    v[0], v[1] = v[1], v[0]
+
+
+def _new_list_object(vals):
+    vals.validators = [v.copy() for v in vals.validators[:-1]]
+
+
+@pytest.fixture
+def hash_counts():
+    """{result: calls} of types_valset_hash_total, on a registry of this
+    test's own."""
+    from cometbft_tpu.libs import metrics as libmetrics
+
+    m = libmetrics.NodeMetrics()
+    libmetrics.push_node_metrics(m)
+
+    def read():
+        return {
+            r: int(m.valset_hash_total.labels(r).value())
+            for r in ("computed", "reused")
+        }
+
+    yield read
+    libmetrics.pop_node_metrics(m)
+
+
+@pytest.fixture
+def no_tree_after(monkeypatch):
+    """Call it, and from then on building a Merkle tree fails the test."""
+    from cometbft_tpu.crypto import merkle
+
+    def refuse(_items):
+        raise AssertionError("the root was computed again")
+
+    return lambda: monkeypatch.setattr(
+        merkle, "hash_from_byte_slices", refuse)
+
+
+class TestValidatorSetRootMemo:
+    def test_hit_returns_what_a_fresh_set_computes(self, hash_counts):
+        pvs, vals = _pv_set(5)
+        first = vals.hash()
+        assert hash_counts() == {"computed": 1, "reused": 0}
+        again = vals.hash()
+        assert hash_counts() == {"computed": 1, "reused": 1}
+        fresh = ValidatorSet(
+            [Validator(pub_key=pv.get_pub_key(), voting_power=10)
+             for pv in pvs]
+        )
+        assert fresh._root_memo is None
+        assert again == first == fresh.hash() == _leaves_root(vals)
+        assert isinstance(again, bytes) and len(again) == 32
+
+    @pytest.mark.parametrize("change", [
+        _set_power, _replace_validator, _append_validator,
+        _remove_validator, _swap_pub_key, _swap_two_validators,
+        _new_list_object,
+    ])
+    def test_change_in_place_gives_the_new_root(self, hash_counts, change):
+        _, vals = _pv_set(5)
+        old = vals.hash()
+        change(vals)
+        got = vals.hash()
+        assert got == _leaves_root(vals)
+        assert got != old
+        assert hash_counts() == {"computed": 2, "reused": 0}
+        # and the new root is the one kept from here on
+        assert vals.hash() == got
+        assert hash_counts() == {"computed": 2, "reused": 1}
+
+    def test_change_and_change_back_gives_the_old_root(self, hash_counts):
+        _, vals = _pv_set(4)
+        old = vals.hash()
+        vals.validators[0].voting_power += 1
+        assert vals.hash() != old
+        vals.validators[0].voting_power -= 1
+        assert vals.hash() == old == _leaves_root(vals)
+        # one root is kept, the last: the way back is computed too
+        assert hash_counts() == {"computed": 3, "reused": 0}
+
+    def test_equal_key_of_another_object_is_the_same_leaf(
+        self, hash_counts, no_tree_after
+    ):
+        """The witness compares keys by their bytes (the key classes are
+        frozen dataclasses), as a leaf does."""
+        _, vals = _pv_set(4)
+        root = vals.hash()
+        no_tree_after()
+        for v in vals.validators:
+            v.pub_key = type(v.pub_key)(bytes(v.pub_key.bytes()))
+        assert vals.hash() == root
+        assert hash_counts() == {"computed": 1, "reused": 1}
+
+    def test_unhashable_key_type_keeps_no_root(self):
+        class _Sr:
+            type = "sr25519"
+
+            def bytes(self):
+                return b"\x01" * 32
+
+        _, vals = _pv_set(3)
+        vals.hash()
+        vals.validators[1].pub_key = _Sr()
+        with pytest.raises(ValueError):
+            vals.hash()
+        with pytest.raises(ValueError):  # no stale root the second time
+            vals.hash()
+
+    def test_update_with_change_set_gives_the_fresh_sets_root(
+        self, hash_counts
+    ):
+        pvs, vals = _pv_set(4)
+        old = vals.hash()
+        vals.update_with_change_set([
+            Validator(pub_key=_other_key(), voting_power=5),
+            Validator(pub_key=pvs[0].get_pub_key(), voting_power=25),
+        ])
+        assert vals._root_memo is None
+        fresh = ValidatorSet([
+            Validator(pub_key=v.pub_key, voting_power=v.voting_power)
+            for v in vals.validators
+        ])
+        got = vals.hash()
+        assert got == fresh.hash() == _leaves_root(vals) != old
+        assert hash_counts()["reused"] == 0
+
+    @pytest.mark.parametrize("derive", [
+        lambda vs: vs.copy(),
+        lambda vs: vs.copy_increment_proposer_priority(3),
+        lambda vs: vs.copy().copy_increment_proposer_priority(1),
+    ], ids=["copy", "copy_increment", "copy_of_copy"])
+    def test_copies_hit_without_recomputing(
+        self, hash_counts, monkeypatch, derive
+    ):
+        from cometbft_tpu.crypto import merkle
+
+        _, vals = _pv_set(5)
+        root = vals.hash()
+        calls = []
+        real = merkle.hash_from_byte_slices
+        monkeypatch.setattr(
+            merkle, "hash_from_byte_slices",
+            lambda items: calls.append(len(items)) or real(items),
+        )
+        cp = derive(vals)
+        assert cp.hash() == root
+        assert not calls
+        assert hash_counts() == {"computed": 1, "reused": 1}
+        # the copy's validators are its own: a change there is seen
+        # there and not in the original
+        cp.validators[0].voting_power += 1
+        assert cp.hash() == real([v.bytes() for v in cp.validators]) != root
+        assert calls == [5]
+        assert vals.hash() == root
+        assert calls == [5]
+
+    def test_priorities_are_not_hashed_and_keep_the_root(
+        self, hash_counts, no_tree_after
+    ):
+        _, vals = _pv_set(5)
+        root = vals.hash()
+        no_tree_after()
+        vals.increment_proposer_priority(7)
+        vals.rescale_priorities(1)
+        vals.validators[0].proposer_priority = 12345
+        assert vals.hash() == root
+        assert hash_counts() == {"computed": 1, "reused": 1}
+
+    def test_round_trip_through_serialization_carries_no_memo(
+        self, hash_counts
+    ):
+        from cometbft_tpu.types import serialization
+
+        _, vals = _pv_set(4)
+        cold = serialization.dumps(vals)
+        root = vals.hash()
+        assert vals._root_memo is not None
+        warm = serialization.dumps(vals)
+        assert warm == cold  # the memo is never written
+        back = serialization.loads(warm)
+        assert isinstance(back, ValidatorSet)
+        assert back._root_memo is None
+        assert back.hash() == root
+        assert hash_counts() == {"computed": 2, "reused": 0}
+
+    def test_two_threads_on_one_cold_set_get_one_root(self, hash_counts):
+        import threading
+
+        _, vals = _pv_set(64)
+        gate = threading.Barrier(2)
+        got, failed = [], []
+
+        def worker():
+            try:
+                gate.wait(timeout=30)
+                for _ in range(20):
+                    got.append(vals.hash())
+            except Exception as e:  # read on the test's thread
+                failed.append(e)
+
+        threads = [threading.Thread(target=worker) for _ in range(2)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not failed, failed
+        assert len(got) == 40 and set(got) == {_leaves_root(vals)}
+        counts = hash_counts()
+        assert counts["computed"] in (1, 2)  # a race costs one duplicate
+        assert counts["computed"] + counts["reused"] == 40
+
+
 # --- commit verification (hot path) -----------------------------------------
 
 
