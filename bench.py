@@ -559,13 +559,11 @@ def _bench_device_floor_measured(libdevstats):
             # path — falling back through the remaining candidates to
             # XLA so one broken pallas flavor can't erase the whole
             # decomposition this probe exists to capture. The live
-            # path's jit IDENTITY matters too: with the lane arena
-            # active, launches use the non-donating variants, and
-            # small buckets their dedicated small-grid jits — probe
-            # the exact (flavor, donation, grid) triple live windows
-            # launch, or the n<=256 rows (the crossover's home) would
-            # time a kernel the production path never runs.
-            probe_donate = not ov._lane_arena_enabled()
+            # path's jit IDENTITY matters too: small buckets launch
+            # their dedicated small-grid jits — probe the exact
+            # (flavor, grid) pair live windows launch, or the n<=256
+            # rows (the crossover's home) would time a kernel the
+            # production path never runs.
             probe_grid = ov._small_grid(min(size, ov._CHUNK))
             cands = (
                 ov._pallas_candidates()
@@ -576,7 +574,7 @@ def _bench_device_floor_measured(libdevstats):
             for probe_try in [*cands, ov._xla_which()]:
                 try:
                     fn = ov._jitted_kernel(
-                        probe_try, probe_donate, probe_grid
+                        probe_try, probe_grid
                     )
                     # fresh device buffer per attempt: the kernels jit
                     # with input donation on TPU, so a faulting
@@ -792,8 +790,6 @@ def _bench_device_floor_measured(libdevstats):
         "measured_crossover_lanes": crossover,
         "crossover_lanes": crossover,
         "window_fixed_cost_ms": fixed,
-        # the LIVE adaptive floor fit, when the run calibrated one
-        "adaptive_fit": cbatch.CROSSOVER.fit_summary(),
         "current_HOST_BATCH_THRESHOLD": cbatch.HOST_BATCH_THRESHOLD,
     }
 

@@ -38,8 +38,8 @@ ENV_KNOBS: dict[str, str] = {
     ),
     "COMETBFT_TPU_HOST_THRESHOLD": (
         "batch size below which verification stays on host; overrides "
-        "the static 768 seed and pins the adaptive crossover "
-        "(crypto/batch.py)"
+        "the static seeds (768 on CPU backends, 96 with an accelerator) "
+        "and pins the hash plane's adaptive crossover (crypto/batch.py)"
     ),
     "COMETBFT_TPU_DEADLOCK": (
         "1 swaps every libs/sync mutex for a deadlock-detecting "
@@ -164,7 +164,7 @@ ENV_KNOBS: dict[str, str] = {
     ),
     "COMETBFT_TPU_COALESCE_MIN_DEVICE_LANES": (
         "pin the lane count above which coalescer windows go to the "
-        "device; unset defers to the live host/device crossover "
+        "device; unset defers to the static host/device cut "
         "(crypto/batch.host_batch_threshold) — sub-cutover windows "
         "still coalesce into one host MSM (crypto/coalesce.py)"
     ),
@@ -178,12 +178,6 @@ ENV_KNOBS: dict[str, str] = {
         "hash-plane analog of COMETBFT_TPU_COALESCE_INFLIGHT: device "
         "hash windows in flight across the executor + readback drain "
         "thread (default 2; crypto/hashplane.py)"
-    ),
-    "COMETBFT_TPU_LANE_ARENA": (
-        "persistent donated device staging buffers for per-launch wire "
-        "rows (ops/verify.LaneArena): auto (default, accelerator "
-        "backends only) | 1 force (tests exercise staging on XLA-CPU) "
-        "| 0 off — fresh h2d allocations per launch"
     ),
     "COMETBFT_TPU_HASH": (
         "cross-caller SHA-256 hash plane: auto (default, node starts "
@@ -286,10 +280,11 @@ ENV_KNOBS: dict[str, str] = {
         "(cometbft_tpu/simnet/net.py)"
     ),
     "COMETBFT_TPU_ADAPTIVE_THRESHOLD": (
-        "adaptive host/device batch crossover from measured timings: "
-        "auto (default, accelerator-only) | 1 force | 0 static seed "
-        "only; a COMETBFT_TPU_HOST_THRESHOLD pin always wins "
-        "(crypto/batch.py AdaptiveCrossover)"
+        "the hash plane's adaptive host/device crossover from measured "
+        "timings: auto (default, accelerator-only) | 1 force | 0 static "
+        "seed only; a COMETBFT_TPU_HOST_THRESHOLD pin always wins "
+        "(crypto/hashplane.py over crypto/batch.AdaptiveCrossover; the "
+        "verify plane's cut is static)"
     ),
     "COMETBFT_TPU_POSTMORTEM": (
         "timeline.json in watchdog black-box bundles — the merged "

@@ -45,11 +45,24 @@ class BatchVerifier:
 # (batchVerifyThreshold, types/validation.go:13-17: below it batching
 # isn't worth setup).
 #
-# 768 is a static seed, not a measurement of the attached chip: no
-# benchmark file steers it. COMETBFT_TPU_HOST_THRESHOLD overrides it
-# (operator / a bench probe); on an accelerator backend the live
-# AdaptiveCrossover below refits it from the process's own windows.
+# Two static seeds, no live refit (see host_batch_threshold): 768 where
+# jax runs on the CPU (tests, a host-only node: the "device" is XLA-CPU
+# and never wins), and _ACCEL_HOST_BATCH_THRESHOLD where an accelerator
+# is attached. COMETBFT_TPU_HOST_THRESHOLD overrides both (operator / a
+# bench probe).
 _DEFAULT_HOST_BATCH_THRESHOLD = 768
+# Measured on a v5e in a quiet process (PERF.md section 6, PR 26): the
+# host RLC batch costs 1.7 ms + 26.6 us a lane, a device window (pack,
+# dispatch, readback) 4.5 ms in buckets 64 and 128, 6.0 in 256, 5.0 in
+# 512, 7.8 in 1024: the lines cross at 107 lanes, within 7% of each
+# other from 90 to 130. Every real validator set's commit checks (a
+# 59-lane trusting check, a 117-lane light check) stand on either side
+# of it, so a cut that is refitted from timings taken under load flips
+# between them from process to process (eleven runs read 64 to 16,384).
+# The cut stands a little under the crossing because a host window also
+# holds the coalescer's executor for the whole verify, a device window
+# only for pack and dispatch.
+_ACCEL_HOST_BATCH_THRESHOLD = 96
 
 
 def _derive_host_threshold() -> int:
@@ -68,9 +81,9 @@ HOST_BATCH_THRESHOLD = _derive_host_threshold()
 class AdaptiveCrossover:
     """Runtime-calibrated host/device batch-size crossover.
 
-    The static cutover (HOST_BATCH_THRESHOLD: env pin, else 768) is a
-    boot-time guess; this class refines it from the SAME
-    measurements the phase metrics record. Both sides get the same
+    Used by the hash plane alone (crypto/hashplane, one instance per
+    SHA block bucket); the verify plane's cut is static, see
+    :func:`host_batch_threshold`. Both sides get the same
     model, matching what 9_device_floor measures:
     ``time(n) = floor + slope * n`` — the device floor is the launch
     cost that dominates small batches, and the host floor is the fixed
@@ -84,9 +97,9 @@ class AdaptiveCrossover:
     ``h_floor + h_rate * n = d_floor + d_slope * n`` and is clamped to
     [64, 16384].
 
-    Until both sides have ``MIN_SAMPLES`` the seed answers, so boot
-    behavior is exactly the old static routing; an operator env pin
-    (COMETBFT_TPU_HOST_THRESHOLD) disables adaptation entirely.
+    Until both sides have ``MIN_SAMPLES`` the caller's seed answers; an
+    operator env pin (COMETBFT_TPU_HOST_THRESHOLD) disables adaptation
+    entirely.
     """
 
     DECAY = 0.98  # per-observation decay of the running moments
@@ -146,8 +159,8 @@ class AdaptiveCrossover:
         """Drop every accumulated sample (a refit from scratch).
 
         The decayed moments forget slowly (~50-sample half-life); when
-        the device cost profile steps — lane arenas flip on, the
-        readback drain lands, a kernel swap — stale samples would keep
+        the device cost profile steps — the readback drain lands, a
+        kernel swap — stale samples would keep
         answering for the OLD floor for hundreds of windows. Callers
         that change the profile (bench captures, an operator toggling
         staging knobs) reset so the live fit re-converges on the new
@@ -208,15 +221,14 @@ class AdaptiveCrossover:
         return int(min(self.HI, max(self.LO, n_star)))
 
 
-CROSSOVER = AdaptiveCrossover()
-
 _ENV_PINNED = bool(os.environ.get("COMETBFT_TPU_HOST_THRESHOLD"))
 
 
 def _adaptive_enabled() -> bool:
-    """Adaptation applies when not env-pinned and either forced
-    (COMETBFT_TPU_ADAPTIVE_THRESHOLD=1) or running on an accelerator
-    backend — CPU test runs must stay deterministically on the seed."""
+    """The hash plane's live refit (crypto/hashplane) applies when not
+    env-pinned and either forced (COMETBFT_TPU_ADAPTIVE_THRESHOLD=1) or
+    running on an accelerator backend — CPU test runs must stay
+    deterministically on the seed."""
     if _ENV_PINNED:
         return False
     mode = os.environ.get("COMETBFT_TPU_ADAPTIVE_THRESHOLD", "auto")
@@ -224,33 +236,29 @@ def _adaptive_enabled() -> bool:
         return False
     if mode == "1":
         return True
-    # live peek only: host_batch_threshold() sits inside every batch
-    # verify, which must never pay (or hang in) jax backend init
     from ..libs.accel import accelerator_backend_live
 
     return accelerator_backend_live()
 
 
 def host_batch_threshold() -> int:
-    """The LIVE host/device cutover: operator env pin > adaptive
-    runtime calibration > the boot seed (module attr
-    HOST_BATCH_THRESHOLD — monkeypatchable)."""
+    """Lanes from which a batch (a lone one, or a coalescer window) runs
+    on the device rather than in the host batch verifier: operator env
+    pin > the accelerator's static seed where one is attached > the
+    module seed (HOST_BATCH_THRESHOLD — monkeypatchable). Nothing a
+    process measures moves it, so a deployment routes the same way in
+    every process and all through each.
+
+    Whether a batch SHARES a window is another question, answered by
+    :meth:`Ed25519BatchVerifier.verify`."""
     base = HOST_BATCH_THRESHOLD
-    if not _adaptive_enabled():
+    if _ENV_PINNED or base != _DEFAULT_HOST_BATCH_THRESHOLD:
         return base
-    t = CROSSOVER.threshold()
-    return base if t is None else t
+    # live peek only: this sits inside every batch verify, which must
+    # never pay (or hang in) jax backend init
+    from ..libs.accel import accelerator_backend_live
 
-
-def note_device_window(n: int, seconds: float) -> None:
-    """Adaptive-crossover feed from the coalescer's device windows."""
-    if _adaptive_enabled():
-        CROSSOVER.observe_device(n, seconds)
-
-
-def note_host_window(n: int, seconds: float) -> None:
-    if _adaptive_enabled():
-        CROSSOVER.observe_host(n, seconds)
+    return _ACCEL_HOST_BATCH_THRESHOLD if accelerator_backend_live() else base
 
 
 class Ed25519BatchVerifier(BatchVerifier):
@@ -274,30 +282,34 @@ class Ed25519BatchVerifier(BatchVerifier):
     def verify(self) -> tuple[bool, list[bool]]:
         import time as _time
 
-        t0 = _time.perf_counter()
-        if len(self._pubkeys) < host_batch_threshold():
-            # Sub-crossover batches first try the cross-caller
-            # coalescer: concurrent small callers (per-vote admission,
-            # commit checks, preverify windows) share ONE device
-            # micro-batch instead of each paying the host path alone.
-            # Not routed / unavailable -> the native RLC host batch
-            # (one multiscalar mult, the voi algorithm), which itself
-            # falls back to sequential OpenSSL when the engine can't
-            # build.
-            from . import coalesce, host_batch
+        from . import coalesce
 
-            bits = coalesce.verify_bytes(
-                self._pubkeys, self._msgs, self._sigs
-            )
+        t0 = _time.perf_counter()
+        n = len(self._pubkeys)
+        # Share a window: a batch smaller than one coalescer window
+        # from a process with a routed coalescer ALWAYS rides it —
+        # concurrent small callers (per-vote admission, commit checks,
+        # a light service's 59- and 117-lane checks) pack into one
+        # launch instead of each paying a launch or a host pass alone.
+        # Device or host is then the window's question, decided by its
+        # lanes (crypto/coalesce._launch_inner), never this batch's.
+        co = coalesce.active()
+        if co is not None and n < co.max_lanes:
+            bits = co.try_verify(self._pubkeys, self._msgs, self._sigs)
             if bits is not None:
                 _observe("ed25519-coalesce", t0, len(bits))
                 return all(bits), list(bits)
-            # restart the clock: a failed coalesce attempt's wait
-            # (worst case a stalled-device ticket timeout) must not be
-            # charged to the host backend's metrics or the crossover's
-            # host-rate fit — that would collapse the threshold toward
-            # the device exactly when the device path is unhealthy
+            # not served (stopped, tripped, deadline): the lone paths
+            # below. Restart the clock: a failed attempt's wait (worst
+            # case a stalled-device ticket timeout) must not be charged
+            # to the backend that then answers
             t0 = _time.perf_counter()
+        if n < host_batch_threshold():
+            # the native RLC host batch (one multiscalar mult, the voi
+            # algorithm), which itself falls back to sequential OpenSSL
+            # when the engine can't build
+            from . import host_batch
+
             bitmap = host_batch.verify_many(
                 self._pubkeys, self._msgs, self._sigs
             )
@@ -314,7 +326,7 @@ class Ed25519BatchVerifier(BatchVerifier):
         # pack/dispatch/readback phase attribution happens inside
         # ops.verify.verify_batch (the phases live there)
         ok_all, bitmap = ov.verify_batch(self._pubkeys, self._msgs, self._sigs)
-        _observe("ed25519-tpu", t0, len(self._pubkeys))
+        _observe("ed25519-tpu", t0, n)
         return ok_all, list(np.asarray(bitmap, bool))
 
 
@@ -685,24 +697,13 @@ def create_commit_batch_verifier(validator_set) -> BatchVerifier:
 def _observe(backend: str, t0: float, n: int) -> None:
     """Record end-to-end batch-verify latency/volume. Routed through
     node_metrics() like every other instrumentation site: the running
-    node's registry when one is up, a throwaway sink otherwise. The
-    same measurement feeds the adaptive host/device crossover — the
-    phase metrics and the routing decision see one set of timings."""
+    node's registry when one is up, a throwaway sink otherwise."""
     import time as _time
 
     dt = _time.perf_counter() - t0
     m = libmetrics.node_metrics()
     m.verify_batch_seconds.labels(backend).observe(dt)
     m.verify_batch_sigs.labels(backend).inc(n)
-    # Only ed25519 lanes feed the crossover: its linear host/device
-    # model is fit for ONE kernel's cost profile, and an sr25519 or
-    # mixed sample (pure-Python host sr25519 runs ~1000x the ed25519
-    # per-lane cost when the native engine is absent) would poison the
-    # shared fit and misroute every verifier.
-    if backend == "ed25519-host":
-        note_host_window(n, dt)
-    elif backend == "ed25519-tpu":
-        note_device_window(n, dt)
 
 
 def prestage_validators(validator_set) -> int:

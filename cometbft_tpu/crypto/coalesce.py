@@ -17,7 +17,9 @@ feeder for the verify kernel:
   proposal-signature check (consensus/state.py), evidence/light single
   verifies (types/vote.py routes them all), and sub-crossover batch
   verifiers (crypto/batch.py) — submit signature lanes and block on a
-  per-submit ticket;
+  per-submit ticket. A batch smaller than a window always shares one
+  when a coalescer is routed; whether the WINDOW runs on the device is
+  decided by its lanes (crypto/batch.host_batch_threshold, static);
 * the executor thread coalesces lanes into fixed-shape-bucket device
   micro-batches (the same bucket discipline as every other launch —
   the no-recompile guard stays green), flushed by a size threshold
@@ -84,13 +86,11 @@ _DEFAULT_WINDOW_US = 500
 # buckets.
 _DEFAULT_MAX_LANES = 1024
 # Windows below the device cutover verify on host — still ONE RLC MSM
-# per window, so coalescing wins there too (the container bench
-# measures 4-12x over serial); the cutover defaults to the LIVE
-# host/device crossover (crypto/batch.host_batch_threshold: env pin >
-# adaptive calibration > chip-table seed) because a sub-crossover
-# window on the device is, by that same measurement, slower than the
-# host MSM it displaces. The knob/ctor arg pins a fixed count (tests,
-# bench device-path probes).
+# per window, so coalescing wins there too; the cutover is
+# crypto/batch.host_batch_threshold (env pin > the accelerator's static
+# seed > the module seed), which nothing a process measures moves, so a
+# deployment's windows route the same way in every process. The
+# knob/ctor arg pins a fixed count (tests, bench device-path probes).
 
 # Ticket wait bound for the routed helpers. Routed callers hold engine
 # mutexes while they wait (vote admission under vote_set, the proposal
@@ -120,6 +120,8 @@ _DEFAULT_MAX_INFLIGHT = 2
 # bounded stall per cooldown and a recovered device is picked back up
 # without a node restart.
 _TRIP_COOLDOWN_S = 30.0
+# the backend label of everything a window does (metrics and spans)
+_BACKEND = "ed25519-coalesce"
 
 
 class CoalescerStoppedError(ServiceError):
@@ -189,7 +191,9 @@ class _Ticket:
     submit's lanes — never the whole window's.
     """
 
-    __slots__ = ("n", "caller", "t_submit", "_done", "_bits", "_exc")
+    __slots__ = (
+        "n", "caller", "t_submit", "wait_span", "_done", "_bits", "_exc",
+    )
 
     def __init__(self, n: int, caller: int = 0):
         self.n = n
@@ -198,6 +202,12 @@ class _Ticket:
         # ledger's attribution key
         self.caller = caller
         self.t_submit = time.perf_counter()
+        # coalesce.queue_wait: begun here, on the submitter's thread and
+        # under its innermost span (one request's spans stay one tree),
+        # ended by the executor when it pops the ticket's window
+        self.wait_span = libtrace.begin(
+            "coalesce.queue_wait", parent=libtrace.current(), lanes=n
+        )
         self._done = threading.Event()
         self._bits: list[bool] | None = None
         self._exc: BaseException | None = None
@@ -241,12 +251,12 @@ class _Inflight:
 
     __slots__ = (
         "finish", "host_ok", "groups", "lanes", "reason", "prep_s",
-        "wire", "t_launch",
+        "wire", "t_launch", "span",
     )
 
     def __init__(
         self, finish, host_ok, groups, lanes, reason, prep_s, wire,
-        t_launch=0.0,
+        t_launch=0.0, span=libtrace.NOP_SPAN,
     ):
         self.finish = finish  # zero-arg materializer from ops/verify
         self.host_ok = host_ok
@@ -254,7 +264,7 @@ class _Inflight:
         self.lanes = lanes
         self.reason = reason
         # pack-start-to-dispatch-end seconds, banked at launch: the
-        # adaptive-crossover feed is prep + readback, NOT wall time to
+        # ledger's execute time is prep + readback, NOT wall time to
         # _finish — the double buffer interleaves window N+1's collect
         # wait and pack before N materializes, and charging that idle
         # gap to the device would systematically overstate its cost
@@ -264,6 +274,19 @@ class _Inflight:
         # tickets against (submit -> launch is queueing; launch ->
         # resolve is execute)
         self.t_launch = t_launch
+        self.span = span  # the window's coalesce.window span
+
+
+def _window_phase(phase: str, win, lanes: int, **fields):
+    """One phase of one window: ``crypto_verify_phase_seconds{phase,
+    backend="ed25519-coalesce"}`` and a ``verify.<phase>`` span under
+    the window's ``coalesce.window`` span, whichever thread runs it."""
+    return libmetrics.TimedPhase(
+        libmetrics.node_metrics().verify_phase_seconds.labels(
+            phase, _BACKEND
+        ),
+        "verify." + phase, win, backend=_BACKEND, lanes=lanes, **fields,
+    )
 
 
 class VerifyCoalescer(BaseService):
@@ -865,21 +888,38 @@ class VerifyCoalescer(BaseService):
         double buffer); host windows resolve synchronously and return
         None."""
         t_pop = time.perf_counter()
+        # the window's life: pop on this thread -> its tickets resolved
+        # (on the drain thread for a device window); pack, dispatch,
+        # readback or fallback are its children
+        win = libtrace.begin(
+            "coalesce.window", reason=reason, backend=_BACKEND
+        )
+        hist = libmetrics.node_metrics().coalesce_queue_wait_seconds
+        for ticket, *_ in groups:
+            sp = ticket.wait_span
+            sp.end(window=win.id)
+            hist.observe(
+                sp.dur_ns / 1e9 if sp is not libtrace.NOP_SPAN
+                else t_pop - ticket.t_submit
+            )
         libdevledger.exec_begin(libdevledger.PLANE_VERIFY)
         try:
-            return self._launch_inner(groups, lanes, reason, t_pop)
+            return self._launch_inner(groups, lanes, reason, t_pop, win)
         finally:
             # the executor-busy marker brackets staging, pack, dispatch
             # AND the inline host resolve — the occupancy view's
             # overlap estimator reads it from the readback drain
             libdevledger.exec_end(libdevledger.PLANE_VERIFY)
 
-    def _launch_inner(self, groups, lanes, reason, t_pop) -> _Inflight | None:
+    def _launch_inner(
+        self, groups, lanes, reason, t_pop, win
+    ) -> _Inflight | None:
         pubkeys, msgs, sigs, staged = self._stage(groups)
         if not staged:
             # every group failed staging: nothing flushed, nothing to
             # count — a window of all-malformed lanes must not inflate
             # the flush/lane metrics
+            win.end(lanes=0, tickets=0, route="none")
             return None
         n = len(pubkeys)
         m = libmetrics.node_metrics()
@@ -912,34 +952,26 @@ class VerifyCoalescer(BaseService):
             try:
                 from ..ops import verify as ov
 
-                buf, host_ok = ov.pack_bytes(pubkeys, msgs, sigs)
-                hit = (
-                    ov._PUBKEY_CACHE.lookup(pubkeys)
-                    if ov._cache_enabled()
-                    else None
-                )
-                arena = "hit" if hit is not None else "bypass"
-                t1 = time.perf_counter()
-                libmetrics.observe_verify_phase(
-                    "pack", "ed25519-coalesce", t1 - t0, n, arena=arena
-                )
-                if hit is not None:
-                    idxs, arena_buf, arena_ok = hit
-                    finish = ov.verify_rsk_async(
-                        buf[32:], idxs, arena_buf, arena_ok, n,
-                        "ed25519-coalesce",
+                with _window_phase("pack", win, n, route="device") as ph:
+                    buf, host_ok = ov.pack_bytes(pubkeys, msgs, sigs)
+                    hit = (
+                        ov._PUBKEY_CACHE.lookup(pubkeys)
+                        if ov._cache_enabled()
+                        else None
                     )
-                else:
-                    finish = ov.verify_bytes_async(
-                        buf, n, "ed25519-coalesce"
-                    )
-                libmetrics.observe_verify_phase(
-                    "dispatch",
-                    "ed25519-coalesce",
-                    time.perf_counter() - t1,
-                    n,
-                    arena=arena,
-                )
+                    arena = "hit" if hit is not None else "bypass"
+                    ph.set(arena=arena)
+                with _window_phase(
+                    "dispatch", win, n, route="device", arena=arena
+                ):
+                    if hit is not None:
+                        idxs, arena_buf, arena_ok = hit
+                        finish = ov.verify_rsk_async(
+                            buf[32:], idxs, arena_buf, arena_ok, n,
+                            _BACKEND,
+                        )
+                    else:
+                        finish = ov.verify_bytes_async(buf, n, _BACKEND)
                 self.device_windows += 1
                 libdevledger.note_window(
                     libdevledger.PLANE_VERIFY, n, True
@@ -947,7 +979,7 @@ class VerifyCoalescer(BaseService):
                 return _Inflight(
                     finish, host_ok, staged, n, reason,
                     time.perf_counter() - t0, (pubkeys, msgs, sigs),
-                    t_launch=t_pop,
+                    t_launch=t_pop, span=win,
                 )
             except Exception:
                 # device staging/dispatch fault: clean host fallback
@@ -956,7 +988,7 @@ class VerifyCoalescer(BaseService):
 
                 traceback.print_exc()
         libdevledger.note_window(libdevledger.PLANE_VERIFY, n, False)
-        self._resolve_host(pubkeys, msgs, sigs, staged, reason, t_pop)
+        self._resolve_host(pubkeys, msgs, sigs, staged, reason, t_pop, win)
         return None
 
     def _finish(self, fl: _Inflight) -> None:
@@ -965,7 +997,10 @@ class VerifyCoalescer(BaseService):
         t0_ns = time.monotonic_ns()
         busy0 = libdevledger.exec_busy_ns(libdevledger.PLANE_VERIFY)
         try:
-            device_ok = fl.finish()
+            with _window_phase(
+                "readback", fl.span, fl.lanes, route="device"
+            ):
+                device_ok = fl.finish()
         except Exception:
             # device-side fault at materialization: clean host fallback
             # for the window (tickets resolve with host verdicts, not
@@ -975,65 +1010,58 @@ class VerifyCoalescer(BaseService):
             traceback.print_exc()
             pubkeys, msgs, sigs = fl.wire
             self._resolve_host(
-                pubkeys, msgs, sigs, fl.groups, fl.reason, fl.t_launch
+                pubkeys, msgs, sigs, fl.groups, fl.reason, fl.t_launch,
+                fl.span,
             )
             return
         now = time.perf_counter()
         libdevledger.note_readback(
             libdevledger.PLANE_VERIFY, t0_ns, busy0
         )
-        libmetrics.observe_verify_phase(
-            "readback", "ed25519-coalesce", now - t0, fl.lanes
-        )
-        from . import batch as crypto_batch
-
-        crypto_batch.note_device_window(fl.lanes, fl.prep_s + (now - t0))
         valid = device_ok & fl.host_ok
         self._resolve_bits(
             fl.groups, valid, fl.reason, "device",
             t_launch=fl.t_launch, exec_s=fl.prep_s + (now - t0),
+            win=fl.span,
         )
 
     def _resolve_host(
-        self, pubkeys, msgs, sigs, staged, reason, t_launch=None
+        self, pubkeys, msgs, sigs, staged, reason, t_launch=None,
+        win=libtrace.NOP_SPAN,
     ) -> None:
         """Host-window verdicts: one native RLC batch for the whole
         window (coalescing still wins on host), sequential per-lane
         verify if the batch engine throws."""
-        t0 = time.perf_counter()
-        try:
-            from . import host_batch
-
-            bitmap = host_batch.verify_many(pubkeys, msgs, sigs)
-        except Exception:
-            from . import fast25519
-
-            bitmap = []
-            for pk, m, s in zip(pubkeys, msgs, sigs):
-                try:
-                    bitmap.append(bool(fast25519.verify_one(pk, m, s)))
-                except Exception:
-                    bitmap.append(False)
-        dt = time.perf_counter() - t0
         n = len(pubkeys)
-        libmetrics.observe_verify_phase(
-            "fallback", "ed25519-coalesce", dt, n
-        )
-        from . import batch as crypto_batch
+        with _window_phase("fallback", win, n, route="host") as ph:
+            try:
+                from . import host_batch
 
-        crypto_batch.note_host_window(n, dt)
+                bitmap = host_batch.verify_many(pubkeys, msgs, sigs)
+            except Exception:
+                from . import fast25519
+
+                bitmap = []
+                for pk, m, s in zip(pubkeys, msgs, sigs):
+                    try:
+                        bitmap.append(bool(fast25519.verify_one(pk, m, s)))
+                    except Exception:
+                        bitmap.append(False)
         self._resolve_bits(
-            staged, bitmap, reason, "host", t_launch=t_launch, exec_s=dt
+            staged, bitmap, reason, "host", t_launch=t_launch,
+            exec_s=ph.dur_ns / 1e9, win=win,
         )
 
     def _resolve_bits(
-        self, staged, bits, reason, backend, t_launch=None, exec_s=0.0
+        self, staged, bits, reason, backend, t_launch=None, exec_s=0.0,
+        win=libtrace.NOP_SPAN,
     ) -> None:
         m = libmetrics.node_metrics()
         now = time.perf_counter()
         total = 0
         for _, _, n in staged:
             total += n
+        m.coalesce_lanes.labels(backend).inc(total)
         exec_ns = int(exec_s * 1e9)
         device = backend == "device"
         plane = libdevledger.PLANE_VERIFY
@@ -1077,14 +1105,7 @@ class VerifyCoalescer(BaseService):
             libhealth.record(
                 libhealth.EV_BUDGET, 0, plane, bw, bx
             )
-        if libtrace.enabled():
-            libtrace.event(
-                "coalesce.flush",
-                reason=reason,
-                backend=backend,
-                lanes=sum(n for _, _, n in staged),
-                tickets=len(staged),
-            )
+        win.end(lanes=total, tickets=len(staged), route=backend)
 
     def _rescue_inflight(self, fl: _Inflight) -> None:
         """Resolve an in-flight window's still-undone tickets on host.

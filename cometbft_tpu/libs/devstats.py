@@ -512,37 +512,6 @@ def _sample_arena(m) -> dict:
     return out
 
 
-def _sample_lane_arena(m) -> dict:
-    """Lane staging arena gauges + stage-outcome counters (the
-    persistent donated wire-row buffers of ops/verify.LaneArena)."""
-    try:
-        from ..ops.verify import _LANE_ARENA as arena
-    except Exception:
-        return {}
-    # unlocked reads, like the pubkey-arena sample: GIL-consistent int
-    # snapshots are fine for gauges
-    out = {
-        "buffers": arena.buffers(),
-        "resident_bytes": arena.resident_bytes(),
-        "stages": arena.stages,
-        "reuses": arena.reuses,
-        "allocs": arena.allocs,
-    }
-    m.lane_arena_staging.labels("buffers").set(out["buffers"])
-    m.lane_arena_staging.labels("resident_bytes").set(
-        out["resident_bytes"]
-    )
-    with _mtx:
-        store = m.__dict__.setdefault("_devstats_bridge", {})
-        reuse_d = _bridge_delta(store, "lane_reuses", arena.reuses)
-        alloc_d = _bridge_delta(store, "lane_allocs", arena.allocs)
-    if reuse_d:
-        m.lane_arena_stages.labels("reuse").inc(reuse_d)
-    if alloc_d:
-        m.lane_arena_stages.labels("alloc").inc(alloc_d)
-    return out
-
-
 def _sample_dispatch() -> dict:
     """Launches served per kernel and the fallbacks the dispatch layer
     absorbed (ops/verify.dispatch_counters) — what tells a device that
@@ -589,7 +558,6 @@ def sample(metrics=None) -> dict:
     return {
         "device_memory": _sample_device_memory(m),
         "pubkey_arena": _sample_arena(m),
-        "lane_arena": _sample_lane_arena(m),
         "verify_dispatch": _sample_dispatch(),
     }
 

@@ -469,6 +469,26 @@ class NodeMetrics:
             "Coalescer window flushes by trigger",
             label_names=("reason",),
         )
+        # what each window's lanes ran on, and how long a ticket stood
+        # in the queue (submit on the caller's thread -> the window's
+        # pop on the executor's; the coalesce.queue_wait span reads the
+        # same interval). Both route series exist from the start, so a
+        # share over them reads 0 and not nothing.
+        self.coalesce_lanes = r.counter(
+            "crypto_coalesce_lanes_total",
+            "Lanes of resolved coalescer windows by route",
+            label_names=("route",),
+        )
+        for route in ("device", "host"):
+            self.coalesce_lanes.labels(route)
+        self.coalesce_queue_wait_seconds = r.histogram(
+            "crypto_coalesce_queue_wait_seconds",
+            "Submit-to-window-pop wait of coalesced verify tickets",
+            buckets=(
+                0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
+                0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5,
+            ),
+        )
         # cross-caller hash plane (crypto/hashplane.py): window shape,
         # flush triggers, and the per-phase latency of the SHA-256
         # pipeline (dispatch = pack + async launch, readback = device
@@ -656,23 +676,6 @@ class NodeMetrics:
         )
         self.arena_evictions = r.counter(
             "pubkey_arena_evictions_total", "Arena LRU evictions"
-        )
-        # persistent lane staging arenas (ops/verify.LaneArena): slot
-        # occupancy + whether stages recycle a donated device buffer
-        # (reuse) or mint one (alloc — should happen once per kind x
-        # bucket slot; a growing alloc rate means shape churn is
-        # defeating the arena)
-        self.lane_arena_staging = r.gauge(
-            "lane_arena_staging",
-            "Lane staging arena occupancy (buffers held / resident "
-            "bytes)",
-            label_names=("kind",),
-        )
-        self.lane_arena_stages = r.counter(
-            "lane_arena_stage_total",
-            "Lane staging operations by outcome (reuse = donated "
-            "in-place update, alloc = one-time slot allocation)",
-            label_names=("outcome",),
         )
         self.transfer_bytes = r.counter(
             "device_transfer_bytes_total",
@@ -1049,14 +1052,15 @@ class TimedPhase:
     """``with`` block that times one phase for two sinks from one pair
     of clock readings: ``hist`` (always on; None = the caller sums
     ``dur_ns`` over several blocks and observes once) and, while
-    tracing is on, a libs/trace span of the same interval. One per
+    tracing is on, a libs/trace span of the same interval (``parent``:
+    a span of another thread that the phase belongs to). One per
     phase, never per lane."""
 
     __slots__ = ("_hist", "_span", "_t0", "dur_ns")
 
-    def __init__(self, hist, span_name: str, **fields):
+    def __init__(self, hist, span_name: str, parent=None, **fields):
         self._hist = hist
-        self._span = libtrace.span(span_name, **fields)
+        self._span = libtrace.span(span_name, parent, **fields)
         self.dur_ns = 0
 
     def set(self, **fields) -> None:

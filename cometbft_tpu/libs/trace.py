@@ -328,12 +328,26 @@ class _NopSpan:
 NOP_SPAN = _NopSpan()
 
 
-def span(name: str, **fields):
-    """A span for ``with`` use; parent = innermost entered span."""
+def span(name: str, parent: "Span | None" = None, **fields):
+    """A span for ``with`` use; parent = innermost entered span, or
+    ``parent`` where the work belongs to a span of another thread (a
+    coalescer window's phases on the executor and the drain thread)."""
     if not _enabled:
         return NOP_SPAN
+    if parent is not None:
+        return Span(name, parent.id, fields or None)
     stack = getattr(_tls, "spans", None)
     return Span(name, stack[-1].id if stack else 0, fields or None)
+
+
+def current() -> "Span | None":
+    """The innermost with-entered span on this thread (None when there
+    is none or tracing is off): what a caller hands to :func:`begin` as
+    the explicit parent of a span that ends on another thread."""
+    if not _enabled:
+        return None
+    stack = getattr(_tls, "spans", None)
+    return stack[-1] if stack else None
 
 
 def begin(name: str, parent: "Span | None" = None, **fields):

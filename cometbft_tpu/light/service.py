@@ -63,6 +63,7 @@ from ..crypto import tmhash
 from ..libs import devledger as libdevledger
 from ..libs import metrics as libmetrics
 from ..libs import sync as libsync
+from ..libs import trace as libtrace
 from ..libs.service import BaseService
 from ..types import serialization as ser
 from ..types.validation import (
@@ -104,6 +105,14 @@ class ServiceStoppedError(LightServiceError):
 
 class DeadlineExceededError(LightServiceError):
     """The request's deadline expired before verification finished."""
+
+
+# what _admit raises -> the outcome a refused request is counted under
+_REJECTIONS = {
+    ServiceBusyError: "rejected",
+    ServiceStoppedError: "stopped",
+    DeadlineExceededError: "deadline",
+}
 
 
 def _env_int(name: str, default: int) -> int:
@@ -622,42 +631,50 @@ class LightService(BaseService):
         if deadline_s is not None:
             dl = min(max(float(deadline_s), 0.0), dl)
         deadline = time.monotonic() + dl
+        # the request's root span: admission wait, the per-request
+        # client's header phases and its coalescer tickets' queue waits
+        # are its descendants
+        with libtrace.span("light.service.request", height=height) as sp:
+            self._admitted(deadline)
+            outcome = "error"
+            try:
+                with crypto_coalesce.request_deadline(deadline):
+                    result = self._serve(
+                        height, trust_height, trust_hash, now_ns
+                    )
+                outcome = "ok"
+                return result
+            except BaseException as e:
+                dexc = _find_deadline(e)
+                if dexc is not None:
+                    outcome = "deadline"
+                    if dexc is e:
+                        raise
+                    raise DeadlineExceededError(str(dexc)) from e
+                raise
+            finally:
+                m = libmetrics.node_metrics()
+                left = self._release(outcome)
+                m.light_requests.labels(outcome).inc()
+                m.light_inflight.set(left)
+                sp.set(outcome=outcome)
+
+    def _admitted(self, deadline: float) -> None:
+        """Take an in-flight slot or raise, counting the rejection. The
+        wait is ``light_service_queue_wait_seconds`` (admitted requests
+        only) and the ``light.service.admit`` span, one clock pair."""
         m = libmetrics.node_metrics()
-        t_enq = time.perf_counter()
-        try:
-            self._admit(deadline)
-        except ServiceBusyError:
-            self._count_rejection("rejected")
-            m.light_requests.labels("rejected").inc()
-            raise
-        except ServiceStoppedError:
-            self._count_rejection("stopped")
-            m.light_requests.labels("stopped").inc()
-            raise
-        except DeadlineExceededError:
-            self._count_rejection("deadline")
-            m.light_requests.labels("deadline").inc()
-            raise
-        m.light_queue_wait.observe(time.perf_counter() - t_enq)
+        with libmetrics.TimedPhase(None, "light.service.admit") as ph:
+            try:
+                self._admit(deadline)
+            except LightServiceError as e:
+                rejected = _REJECTIONS[type(e)]
+                ph.set(outcome=rejected)
+                self._count_rejection(rejected)
+                m.light_requests.labels(rejected).inc()
+                raise
+        m.light_queue_wait.observe(ph.dur_ns / 1e9)
         m.light_inflight.set(self._inflight)
-        outcome = "error"
-        try:
-            with crypto_coalesce.request_deadline(deadline):
-                result = self._serve(height, trust_height, trust_hash, now_ns)
-            outcome = "ok"
-            return result
-        except BaseException as e:
-            dexc = _find_deadline(e)
-            if dexc is not None:
-                outcome = "deadline"
-                if dexc is e:
-                    raise
-                raise DeadlineExceededError(str(dexc)) from e
-            raise
-        finally:
-            left = self._release(outcome)
-            m.light_requests.labels(outcome).inc()
-            m.light_inflight.set(left)
 
     def _serve(self, height, trust_height, trust_hash, now_ns) -> dict:
         provider = _DeadlineProvider(self.provider)

@@ -29,7 +29,7 @@ from ..libs import devstats as libdevstats
 from ..libs.accel import ACCELERATOR_BACKENDS
 from ..libs import metrics as libmetrics
 from ..libs import sync as libsync
-from collections import OrderedDict, deque
+from collections import OrderedDict
 from functools import lru_cache
 
 import numpy as np
@@ -473,136 +473,12 @@ def _donatable(argnums: tuple[int, ...]) -> tuple[int, ...]:
     return argnums if jax.default_backend() in ACCELERATOR_BACKENDS else ()
 
 
-# ------------------------------------------------- persistent lane arenas
-# The wire rows of every launch used to arrive as fresh host numpy
-# arrays: each dispatch paid an implicit host->device transfer INTO A
-# FRESH DEVICE ALLOCATION, and the buffer died after unpacking. The
-# LaneArena keeps one persistent, donated device staging buffer per
-# (kind, shape) — a window writes its rows into the arena through a
-# jitted ``lax.dynamic_update_slice`` whose FIRST argument (the previous
-# arena) is donated, so steady-state launches reuse the same device
-# allocation instead of minting one per window and never call
-# ``jax.device_put``. Two slots ping-pong per key so staging
-# window N+1 never writes into a buffer window N's launch still reads.
-#
-# COMETBFT_TPU_LANE_ARENA: "auto" (default) stages only on accelerator
-# backends — on the CPU test backend donation is unsupported, so the
-# arena would only add a copy; "1" forces (tests exercise the full
-# staging path on XLA-CPU), "0" disables.
-
-_LANE_ARENA_MODE = None
-
-
-def _lane_arena_enabled() -> bool:
-    global _LANE_ARENA_MODE
-    if _LANE_ARENA_MODE is None:
-        _LANE_ARENA_MODE = os.environ.get("COMETBFT_TPU_LANE_ARENA", "auto")
-    mode = _LANE_ARENA_MODE
-    if mode == "0":
-        return False
-    if mode == "1":
-        return True
-    return jax.default_backend() in ACCELERATOR_BACKENDS
-
-
-def _stage_write(arena, rows):
-    """Write one window's rows into the staging arena, in place when the
-    arena is donated (full-shape dynamic_update_slice: XLA lowers it to
-    a copy into the donated buffer — no fresh allocation)."""
-    from jax import lax
-
-    return lax.dynamic_update_slice(
-        arena, rows, tuple(0 for _ in rows.shape)
-    )
-
-
-@lru_cache(maxsize=None)
-def _staging_jit(kind: str):
-    _enable_compilation_cache()
-    return libdevstats.track(
-        "stage." + kind,
-        jax.jit(_stage_write, donate_argnums=_donatable((0,))),
-        axis=0,
-    )
-
-
-class LaneArena:
-    """Persistent device staging buffers for per-launch wire rows.
-
-    ``stage(kind, buf)`` returns a device-resident copy of ``buf``
-    whose allocation is recycled window-over-window (donation of the
-    previous arena slot). Kernels consuming a staged buffer must NOT
-    donate it — the arena owns the allocation across launches; the
-    dispatchers below select non-donating jit variants when staging is
-    on. Thread-safe: verify paths stage from the coalescer executor,
-    consensus, blocksync and RPC threads concurrently; the mutex guards
-    only the slot bookkeeping, never a device wait (the staging jit
-    dispatch is asynchronous).
-    """
-
-    # slots per key: window N+1 stages into the OTHER slot while window
-    # N's launch may still read its staged rows (the readback drain
-    # overlaps execute of N+1 with d2h of N)
-    PING_PONG = 2
-
-    def __init__(self) -> None:
-        self._lock = libsync.Mutex("ops.verify._lane_mtx")
-        self._bufs: dict[tuple, deque] = {}
-        self.stages = 0  # total staging operations
-        self.reuses = 0  # stages that recycled a donated arena slot
-        self.allocs = 0  # one-time arena-slot allocations
-
-    def stage(self, kind: str, buf):
-        key = (kind, buf.shape, buf.dtype.str)
-        with self._lock:
-            self.stages += 1
-            slots = self._bufs.setdefault(key, deque())
-            if len(slots) < self.PING_PONG:
-                # A new slot is allocated on the device and then
-                # written through the SAME donated-slot jit every later
-                # window uses, so that jit compiles with the shape's
-                # first window. (It used to compile with the third — a
-                # cold compile inside what callers took for steady
-                # state; seen on the v5e as a 0.05-0.2 s compile on the
-                # first "warm" repeat of every bucket.)
-                import jax.numpy as jnp
-
-                self.allocs += 1
-                slot = jnp.zeros(buf.shape, buf.dtype)
-            else:
-                self.reuses += 1
-                slot = slots.popleft()
-            staged = _staging_jit(kind)(slot, buf)
-            slots.append(staged)
-            return staged
-
-    def buffers(self) -> int:
-        # snapshot under the lock: a concurrent stage() inserting a new
-        # (kind, shape) key must not resize the dict under this walk
-        # (the devstats scrape path calls these from other threads)
-        with self._lock:
-            return sum(len(v) for v in self._bufs.values())
-
-    def resident_bytes(self) -> int:
-        with self._lock:
-            arrs = [arr for slots in self._bufs.values() for arr in slots]
-        return sum(int(getattr(arr, "nbytes", 0) or 0) for arr in arrs)
-
-    def clear(self) -> None:
-        """Drop every staged slot (tests; a backend teardown)."""
-        with self._lock:
-            self._bufs.clear()
-
-
-_LANE_ARENA = LaneArena()
-
-
 # Every degradation the dispatch layer absorbs is counted here and
 # logged at its site, so whoever needs the device (chip_smoke.py, an
 # operator reading /debug/devstats) can tell a served launch from a
 # covered fault. Plain ints bumped without a lock: a lost update under
 # a race costs one count, never a verdict.
-_FAULTS = {"pallas": 0, "stage": 0, "prestage": 0}
+_FAULTS = {"pallas": 0, "prestage": 0}
 _LAUNCHES: dict[str, int] = {}  # devstats kernel name -> launches served
 
 
@@ -629,19 +505,6 @@ def dispatch_counters() -> dict:
         "faults": dict(_FAULTS),
         "pallas_broken": sorted(_PALLAS_BROKEN),
     }
-
-
-def _stage_wire(kind: str, buf):
-    """Stage ``buf`` into the lane arena when enabled; None = launch
-    from host memory (arena off, or staging faulted — staging is an
-    optimization and must never kill a launch; the fault is counted)."""
-    if not _lane_arena_enabled():
-        return None
-    try:
-        return _LANE_ARENA.stage(kind, buf)
-    except Exception as e:
-        _note_fault("stage", e, buffer=kind)
-        return None
 
 
 # Buckets at or below this get a DEDICATED jit per (flavor, bucket):
@@ -679,11 +542,9 @@ def _cached_jits():
 
 
 @lru_cache(maxsize=None)
-def _jitted_cached_kernel(which: str, donate: bool = True, grid=None):
-    """The cached-table jit for one (flavor, donation, grid) triple.
+def _jitted_cached_kernel(which: str, grid=None):
+    """The cached-table jit for one (flavor, grid) pair.
 
-    ``donate=False`` variants serve lane-arena-staged launches (the
-    staged rows must survive the launch — the arena owns them);
     ``grid`` pins a dedicated small-bucket jit (see _SMALL_GRID_MAX):
     its own executable cache and its own devstats kernel name, so
     small-window compiles and launches are attributable per bucket.
@@ -701,7 +562,7 @@ def _jitted_cached_kernel(which: str, donate: bool = True, grid=None):
     # donate the per-launch R|S|kneg wire rows (arg 3) — NEVER the arena
     return libdevstats.track(
         "verify_cached." + label,
-        jax.jit(fn, donate_argnums=_donatable((3,)) if donate else ()),
+        jax.jit(fn, donate_argnums=_donatable((3,))),
         axis=3,
     )
 
@@ -709,21 +570,18 @@ def _jitted_cached_kernel(which: str, donate: bool = True, grid=None):
 def _run_cached_kernel(arena, arena_ok, idxs, buf):
     """Cached-table launch with the same Pallas/XLA selection and Mosaic
     fallback discipline as :func:`_run_kernel`. Wire rows and slot
-    indices go through the persistent lane arena when enabled; small
-    buckets launch their dedicated small-grid jits."""
-    staged_buf = _stage_wire("rsk", buf)
-    staged_idx = _stage_wire("idx", idxs) if staged_buf is not None else None
-    if staged_buf is not None and staged_idx is None:
-        staged_buf = None  # stage both or neither
-    donate = staged_buf is None
-    buf_in = buf if donate else staged_buf
-    idx_in = idxs if staged_idx is None else staged_idx
+    indices are launched from host memory, the rows donated: every
+    launch owns its inputs, so any number of threads may launch one
+    shape at once, with one jit dispatch each (a staging step before
+    the launch costs the coalescer's executor two more waits for the
+    GIL and the chip showed no gain from it: PERF.md section 6, PR 26).
+    Small buckets launch their dedicated small-grid jits."""
     grid = _small_grid(buf.shape[1])
     if buf.shape[1] >= _PALLAS_MIN_LANES and _pallas_wanted():
         for which in _pallas_candidates():
-            kernel = _jitted_cached_kernel(which, donate, grid)
+            kernel = _jitted_cached_kernel(which, grid)
             try:
-                out = kernel(arena, arena_ok, idx_in, buf_in)
+                out = kernel(arena, arena_ok, idxs, buf)
             except Exception as e:
                 _note_pallas_broken(which, e)
             else:
@@ -732,8 +590,8 @@ def _run_cached_kernel(arena, arena_ok, idxs, buf):
                 libdevstats.record_h2d(buf.nbytes + idxs.nbytes)
                 _served(kernel.kernel)
                 return out, which
-    kernel = _jitted_cached_kernel(_xla_which(), donate, grid)
-    out = kernel(arena, arena_ok, idx_in, buf_in)
+    kernel = _jitted_cached_kernel(_xla_which(), grid)
+    out = kernel(arena, arena_ok, idxs, buf)
     libdevstats.record_h2d(buf.nbytes + idxs.nbytes)
     _served(kernel.kernel)
     return out, None
@@ -1000,11 +858,11 @@ def _enable_compilation_cache() -> str:
 
 
 @lru_cache(maxsize=None)
-def _jitted_kernel(which: str = "xla", donate: bool = True, grid=None):
-    """The uncached-path jit for one (flavor, donation, grid) triple —
-    same contract as :func:`_jitted_cached_kernel`: ``donate=False``
-    variants serve lane-arena-staged launches, ``grid`` pins a
-    dedicated small-bucket jit with its own devstats identity."""
+def _jitted_kernel(which: str = "xla", grid=None):
+    """The uncached-path jit for one (flavor, grid) pair — same contract
+    as :func:`_jitted_cached_kernel`: the wire buffer is donated,
+    ``grid`` pins a dedicated small-bucket jit with its own devstats
+    identity."""
     _enable_compilation_cache()
     flavors = {
         "pallas": _kernel_from_bytes_pallas,
@@ -1017,7 +875,7 @@ def _jitted_kernel(which: str = "xla", donate: bool = True, grid=None):
         label = f"{label}.g{grid}"
     return libdevstats.track(
         "verify." + label,
-        jax.jit(fn, donate_argnums=_donatable((0,)) if donate else ()),
+        jax.jit(fn, donate_argnums=_donatable((0,))),
         axis=0,
     )
 
@@ -1089,23 +947,20 @@ def _run_kernel(buf):
     result materializes — callers resolve through :func:`_materialize`,
     which marks the flavor broken and re-dispatches.
     """
-    staged = _stage_wire("wire", buf)
-    donate = staged is None
-    buf_in = buf if donate else staged
     grid = _small_grid(buf.shape[1])
     if buf.shape[1] >= _PALLAS_MIN_LANES and _pallas_wanted():
         for which in _pallas_candidates():
-            kernel = _jitted_kernel(which, donate, grid)
+            kernel = _jitted_kernel(which, grid)
             try:
-                out = kernel(buf_in)
+                out = kernel(buf)
             except Exception as e:  # synchronous trace/compile failure
                 _note_pallas_broken(which, e)
             else:
                 libdevstats.record_h2d(buf.nbytes)
                 _served(kernel.kernel)
                 return out, which
-    kernel = _jitted_kernel(_xla_which(), donate, grid)
-    out = kernel(buf_in)
+    kernel = _jitted_kernel(_xla_which(), grid)
+    out = kernel(buf)
     libdevstats.record_h2d(buf.nbytes)
     _served(kernel.kernel)
     return out, None
@@ -1356,8 +1211,7 @@ def _warm_shape(key) -> None:
     """Compile everything a coalescer window touches for one shape, by
     launching that shape on dummy lanes (ops/warm.WarmSet's contract).
 
-    ``("window", bucket)``: the verify launch itself, lane-arena
-    staging included.
+    ``("window", bucket)``: the verify launch itself.
     ``("build", size)``: the arena builder + scatter for ``size`` new
     keys; the scatter targets the scratch slot and its result is
     dropped (no donation), so the live arena is untouched.

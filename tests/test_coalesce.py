@@ -860,25 +860,24 @@ class TestAdaptiveCrossover:
             xo2.observe_device(256, 10.0)
         assert xo2.threshold() == xo2.HI
 
-    def test_host_batch_threshold_respects_seed_and_calibration(
-        self, monkeypatch
-    ):
-        # adaptive off: the (monkeypatchable) module seed answers
-        monkeypatch.setenv("COMETBFT_TPU_ADAPTIVE_THRESHOLD", "0")
+    def test_host_batch_threshold_respects_seed_and_pin(self, monkeypatch):
+        # the (monkeypatchable) module seed answers on the CPU backend
         monkeypatch.setattr(cbatch, "HOST_BATCH_THRESHOLD", 123)
         assert cbatch.host_batch_threshold() == 123
-        # forced on + calibrated instance: the calibration answers
-        monkeypatch.setenv("COMETBFT_TPU_ADAPTIVE_THRESHOLD", "1")
-        monkeypatch.setattr(cbatch, "_ENV_PINNED", False)
-        xo = cbatch.AdaptiveCrossover()
-        for _ in range(xo.MIN_SAMPLES + 1):
-            xo.observe_host(200, 200 * 100e-6)
-            xo.observe_device(128, 0.05 + 128 * 2e-6)
-            xo.observe_device(1024, 0.05 + 1024 * 2e-6)
-        monkeypatch.setattr(cbatch, "CROSSOVER", xo)
-        assert cbatch.host_batch_threshold() == xo.threshold() != 123
-        # an operator env pin always wins over calibration
+        # an attached accelerator has a static seed of its own ...
+        monkeypatch.undo()
+        from cometbft_tpu.libs import accel
+
+        monkeypatch.setattr(accel, "accelerator_backend_live", lambda: True)
+        assert (
+            cbatch.host_batch_threshold()
+            == cbatch._ACCEL_HOST_BATCH_THRESHOLD
+        )
+        # ... which an operator env pin, or a patched seed, overrides
         monkeypatch.setattr(cbatch, "_ENV_PINNED", True)
+        assert cbatch.host_batch_threshold() == cbatch.HOST_BATCH_THRESHOLD
+        monkeypatch.setattr(cbatch, "_ENV_PINNED", False)
+        monkeypatch.setattr(cbatch, "HOST_BATCH_THRESHOLD", 123)
         assert cbatch.host_batch_threshold() == 123
 
     def test_post_optimization_device_profile_converges_below_256(
@@ -902,8 +901,6 @@ class TestAdaptiveCrossover:
                 xo.observe_device(n, 2e-3 + n * 1e-6)
         t = xo.threshold()
         assert t is not None and t < 256, t
-        monkeypatch.setattr(cbatch, "CROSSOVER", xo)
-        assert cbatch.host_batch_threshold() < 256
         fit = xo.fit_summary()
         assert fit["crossover_lanes"] == t
         assert fit["device_floor_s"] == pytest.approx(2e-3, rel=0.1)
@@ -924,6 +921,108 @@ class TestAdaptiveCrossover:
         xo.reset()
         assert xo.threshold() is None
         assert xo.fit_summary()["host_samples"] == 0
+
+
+class TestRouteHoldsStill:
+    """Two questions, two answers: a batch smaller than a window shares
+    one whenever a coalescer is routed, whatever the device/host cut
+    reads; and nothing a process measures moves that cut."""
+
+    @pytest.mark.parametrize("cut", [2, 64, 96, 117, 118, 768, 16384])
+    def test_117_lane_batch_shares_a_window_at_every_cut(
+        self, metrics, monkeypatch, cut
+    ):
+        monkeypatch.setattr(cbatch, "HOST_BATCH_THRESHOLD", cut)
+        pubs, _pks, msgs, sigs = _lanes(117, seed=21)
+        co = _coalescer(window_us=1_000)
+        coalesce.push_active(co)
+        try:
+            bv = cbatch.Ed25519BatchVerifier()
+            for p, m, s in zip(pubs, msgs, sigs):
+                bv.add(p, m, s)
+            ok, bits = bv.verify()
+        finally:
+            coalesce.pop_active(co)
+            co.stop()
+        assert ok and bits == [True] * 117
+        assert co.tickets == 1 and co.windows == 1
+        sigs_by = metrics.verify_batch_sigs.labels
+        assert sigs_by("ed25519-coalesce").value() == 117
+        assert sigs_by("ed25519-tpu").value() == 0
+        assert sigs_by("ed25519-host").value() == 0
+        assert metrics.coalesce_lanes.labels("host").value() == 117
+        assert metrics.coalesce_queue_wait_seconds._n == 1
+
+    def test_a_window_sized_batch_does_not_queue(self, metrics, monkeypatch):
+        monkeypatch.setattr(cbatch, "HOST_BATCH_THRESHOLD", 1 << 20)
+        pubs, _pks, msgs, sigs = _lanes(8, seed=22)
+        co = _coalescer(window_us=1_000, max_lanes=8)
+        coalesce.push_active(co)
+        try:
+            bv = cbatch.Ed25519BatchVerifier()
+            for p, m, s in zip(pubs, msgs, sigs):
+                bv.add(p, m, s)
+            assert bv.verify() == (True, [True] * 8)
+        finally:
+            coalesce.pop_active(co)
+            co.stop()
+        assert co.tickets == 0
+        assert metrics.verify_batch_sigs.labels("ed25519-host").value() == 8
+
+    def test_cut_does_not_move_on_outlying_samples(self, monkeypatch):
+        from cometbft_tpu.crypto import host_batch
+        from cometbft_tpu.libs import accel
+
+        monkeypatch.setattr(accel, "accelerator_backend_live", lambda: True)
+        before = cbatch.host_batch_threshold()
+        real = host_batch.verify_many
+
+        def slow(pks, msgs, sigs):  # a host pass 100x its usual time
+            time.sleep(0.2)
+            return real(pks, msgs, sigs)
+
+        monkeypatch.setattr(host_batch, "verify_many", slow)
+        _, pks, msgs, sigs = _lanes(4, seed=23)
+        co = _coalescer(window_us=500)
+        try:
+            assert co.submit(pks, msgs, sigs).result(10) == [True] * 4
+            monkeypatch.setattr(host_batch, "verify_many", real)
+            for _ in range(6):
+                assert co.submit(pks, msgs, sigs).result(10) == [True] * 4
+        finally:
+            co.stop()
+        assert cbatch.host_batch_threshold() == before
+        assert before == cbatch._ACCEL_HOST_BATCH_THRESHOLD
+
+
+class TestWindowSpans:
+    def test_queue_wait_and_window_form_one_tree_across_threads(self):
+        from cometbft_tpu.libs import trace as libtrace
+
+        was = libtrace.enabled()
+        libtrace.enable()
+        libtrace.reset()
+        _, pks, msgs, sigs = _lanes(3, seed=24)
+        co = _coalescer(window_us=1_000)
+        try:
+            with libtrace.span("caller.request") as outer:
+                assert co.submit(pks, msgs, sigs).result(10) == [True] * 3
+        finally:
+            co.stop()
+            recs = libtrace.ring_dump()
+            libtrace.reset()
+            if not was:
+                libtrace.disable()
+        by_name = {r["name"]: r for r in recs if r["kind"] == "span"}
+        wait, win = by_name["coalesce.queue_wait"], by_name["coalesce.window"]
+        assert wait["parent"] == outer.id and wait["window"] == win["span"]
+        assert wait["thread"] == "verify-coalescer" and wait["lanes"] == 3
+        fb = by_name["verify.fallback"]
+        assert fb["parent"] == win["span"] and fb["route"] == "host"
+        assert fb["backend"] == "ed25519-coalesce" and fb["lanes"] == 3
+        assert (win["lanes"], win["tickets"], win["route"]) == (3, 1, "host")
+        assert win["start_ns"] <= fb["start_ns"]
+        assert fb["start_ns"] + fb["dur_ns"] <= win["start_ns"] + win["dur_ns"]
 
 
 class TestReadbackDrain:

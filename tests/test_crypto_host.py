@@ -132,8 +132,8 @@ class TestBatchDispatch:
 
 class TestHostThresholdDerivation:
     """HOST_BATCH_THRESHOLD is the env pin or the static 768 seed — no
-    benchmark file steers it (the live AdaptiveCrossover refits it on
-    an accelerator backend)."""
+    benchmark file steers it and nothing refits it (an attached
+    accelerator has a static seed of its own: host_batch_threshold)."""
 
     def test_env_override_wins(self, monkeypatch):
         from cometbft_tpu.crypto import batch

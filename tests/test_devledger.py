@@ -388,12 +388,12 @@ class TestBurstReconciliation:
             assert r["window_ns"] > 0
             assert abs(1.0 - r["ratio"]) <= 0.01, r
             # the ledger's window lanes reconcile with the traced
-            # coalesce.flush dispatch events and the coalescer's own
-            # window counters
+            # coalesce.window spans and the coalescer's own window
+            # counters
             flush_lanes = sum(
                 e.get("lanes", 0)
                 for e in trace_events
-                if e.get("name") == "coalesce.flush"
+                if e.get("name") == "coalesce.window"
             )
             occ = devledger.occupancy()["verify"]
             assert flush_lanes == occ["window_lanes"]

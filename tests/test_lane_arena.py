@@ -1,7 +1,9 @@
-"""Persistent lane staging arenas (ops/verify.LaneArena), the narrowed
-index/mask dtypes, and the small-grid jit split — the fixed-cost levers
-of the device-floor work. Verdict identity is the bar everywhere: the
-staged path must answer exactly what ``pub_key.verify_signature`` does.
+"""The launch path of ops/verify: every launch owns its inputs (the
+persistent lane staging arena that stood here went in PR 26; the file
+keeps its name), the narrowed index/mask dtypes, and the small-grid jit
+split. Verdict identity is the bar everywhere: the device path must
+answer exactly what ``pub_key.verify_signature`` does, from any number
+of threads at once.
 """
 
 from __future__ import annotations
@@ -30,19 +32,14 @@ def _lanes(n: int, seed: int = 1):
 
 
 @pytest.fixture
-def staged_arena(monkeypatch):
-    """Force the lane arena ON (XLA-CPU exercises the full staging
-    path minus donation) with a fresh, isolated arena instance."""
-    monkeypatch.setattr(ov, "_LANE_ARENA_MODE", "1")
-    arena = ov.LaneArena()
-    monkeypatch.setattr(ov, "_LANE_ARENA", arena)
+def device_path(monkeypatch):
+    """Every batch takes the device path (XLA-CPU here), unsharded."""
     monkeypatch.setenv("COMETBFT_TPU_SHARD", "0")
     monkeypatch.setattr(cbatch, "HOST_BATCH_THRESHOLD", 2)
-    return arena
 
 
-class TestStagedIdentity:
-    def test_staged_verdicts_match_unrouted_verify(self, staged_arena):
+class TestLaunchIdentity:
+    def test_device_verdicts_match_unrouted_verify(self, device_path):
         pks, msgs, sigs = _lanes(8, seed=2)
         sigs[2] = bytes(64)  # zero sig
         sigs[5] = sigs[4]  # wrong message for that key
@@ -54,70 +51,68 @@ class TestStagedIdentity:
         ok, bits = ov.verify_batch(pks, msgs, sigs)
         assert list(bits) == oracle
         assert ok is all(oracle)
-        assert staged_arena.stages > 0, "arena never staged a launch"
 
-    def test_staging_fault_falls_back_to_host_buffers(
-        self, staged_arena, monkeypatch
-    ):
-        # a faulting stage must degrade to the unstaged launch, never
-        # kill the verify
-        monkeypatch.setattr(
-            staged_arena,
-            "stage",
-            lambda *a, **k: (_ for _ in ()).throw(RuntimeError("x")),
-        )
+    def test_host_rows_survive_a_launch_for_the_retry(self, device_path):
+        # a launch is handed host arrays (donation consumes the device
+        # copy, never the caller's numpy rows): the Pallas-fault retry
+        # in materialize() launches the same rows again
         pks, msgs, sigs = _lanes(4, seed=3)
-        ok, bits = ov.verify_batch(pks, msgs, sigs)
-        assert ok and list(bits) == [True] * 4
+        sigs[1] = bytes(64)
+        buf, host_ok = ov.pack_bytes(pks, msgs, sigs)
+        idxs, arena, arena_ok = ov._PUBKEY_CACHE.lookup(pks)
+        rows, before = np.ascontiguousarray(buf[32:]), buf[32:].copy()
+        first = ov.verify_rsk_async(rows, idxs, arena, arena_ok, 4)()
+        again = ov.verify_rsk_async(rows, idxs, arena, arena_ok, 4)()
+        assert list(first) == list(again) == [True, False, True, True]
+        assert (rows == before).all()
 
 
-class TestArenaReuse:
-    def test_allocs_bounded_by_ping_pong_then_reuse(self, staged_arena):
+class TestLaunchAccounting:
+    def test_each_launch_is_served_once_and_no_fault_kind_is_stage(
+        self, device_path
+    ):
         pks, msgs, sigs = _lanes(6, seed=4)
+        ov.verify_batch(pks, msgs, sigs)
+        c0 = ov.dispatch_counters()
         for _ in range(5):
             ov.verify_batch(pks, msgs, sigs)
-        # one (kind, shape) key per wire kind; each allocates at most
-        # PING_PONG slots, every later stage recycles a donated slot
-        per_key_cap = ov.LaneArena.PING_PONG
-        kinds = {k[0] for k in staged_arena._bufs}
-        assert staged_arena.allocs <= per_key_cap * len(kinds)
-        assert staged_arena.reuses > 0
-        assert (
-            staged_arena.stages
-            == staged_arena.reuses + staged_arena.allocs
-        )
-        assert staged_arena.buffers() <= per_key_cap * len(kinds)
-        assert staged_arena.resident_bytes() > 0
+        c1 = ov.dispatch_counters()
+        served = {
+            k: v - c0["launches"].get(k, 0)
+            for k, v in c1["launches"].items()
+            if v != c0["launches"].get(k, 0)
+        }
+        assert served == {"verify_cached.xla.g8": 5}
+        assert c1["faults"] == c0["faults"]
+        assert set(c1["faults"]) == {"pallas", "prestage"}
 
-    def test_no_recompile_across_staged_windows(self, staged_arena):
+    def test_no_recompile_across_windows(self, device_path):
         pks, msgs, sigs = _lanes(6, seed=5)
         devstats.enable()
         try:
-            ov.verify_batch(pks, msgs, sigs)  # warm: compiles + stages
+            ov.verify_batch(pks, msgs, sigs)  # warm: compiles
             ov.verify_batch(pks, msgs, sigs)
             before = devstats.compile_count()
             for _ in range(3):
                 ok, bits = ov.verify_batch(pks, msgs, sigs)
                 assert ok
             assert devstats.compile_count() == before, (
-                "staged steady-state windows recompiled:\n"
+                "steady-state windows recompiled:\n"
                 + str(devstats.snapshot()["xla"]["per_kernel_bucket"])
             )
         finally:
             devstats.disable()
 
-    def test_transfer_reconciliation_staged_cached_path(
-        self, staged_arena
-    ):
-        # the staged cached-arena launch still counts exactly ONE h2d
-        # op per launch, and its bytes are the 96 B/lane wire rows plus
-        # the NARROWED uint16 slot indexes — 2 B/lane, half the old
-        # int32 lanes (this is the dtype-shrink proof at launch grain)
+    def test_transfer_reconciliation_cached_path(self, device_path):
+        # the cached-arena launch counts exactly ONE h2d op per launch,
+        # and its bytes are the 96 B/lane wire rows plus the NARROWED
+        # uint16 slot indexes — 2 B/lane, half the old int32 lanes
+        # (this is the dtype-shrink proof at launch grain)
         pks, msgs, sigs = _lanes(8, seed=6)
         assert ov._PUBKEY_CACHE.lookup(pks) is not None  # prestage
         devstats.enable()
         try:
-            ov.verify_batch(pks, msgs, sigs)  # warm the staged jits
+            ov.verify_batch(pks, msgs, sigs)  # warm the jits
             c0 = devstats.counters()
             ok, _bits = ov.verify_batch(pks, msgs, sigs)
             assert ok
@@ -128,6 +123,74 @@ class TestArenaReuse:
             assert c1["d2h_bytes"] - c0["d2h_bytes"] == 8 // 8
         finally:
             devstats.disable()
+
+
+class TestConcurrentLaunches:
+    """Every launch owns its inputs, so any number of threads may launch
+    one shape at once and each gets the verdicts of its own rows. (Until
+    PR 26 a staging arena handed the third concurrent stager of a shape
+    a buffer another thread was about to launch with.)"""
+
+    @pytest.mark.parametrize("n_threads", [4, 8])
+    def test_threads_launching_one_shape_get_their_own_verdicts(
+        self, device_path, n_threads
+    ):
+        import threading
+
+        pks, msgs, sigs = _lanes(8, seed=11)
+        idxs, arena, arena_ok = ov._PUBKEY_CACHE.lookup(pks)
+        ov.verify_batch(pks, msgs, sigs)  # compile once, on this thread
+        wrong: list = []
+        start = threading.Barrier(n_threads)
+
+        def launcher(k: int) -> None:
+            # thread k's rows differ from every other thread's in the
+            # one lane it breaks
+            mine = list(sigs)
+            mine[k % 8] = bytes(64)
+            want = [i != k % 8 for i in range(8)]
+            buf, host_ok = ov.pack_bytes(pks, msgs, mine)
+            start.wait(30)
+            for _ in range(10):
+                finish = ov.verify_rsk_async(
+                    buf[32:], idxs, arena, arena_ok, 8
+                )
+                if list(finish() & host_ok) != want:
+                    wrong.append(k)
+
+        threads = [
+            threading.Thread(target=launcher, args=(k,))
+            for k in range(n_threads)
+        ]
+        faults0 = ov.dispatch_counters()["faults"]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120)
+        assert not wrong, f"threads {sorted(set(wrong))} read foreign rows"
+        assert ov.dispatch_counters()["faults"] == faults0
+
+    def test_concurrent_verifies_of_one_shape_agree_with_the_oracle(
+        self, device_path
+    ):
+        import threading
+
+        pks, msgs, sigs = _lanes(8, seed=9)
+        sigs[3] = bytes(64)
+        want = [True] * 3 + [False] + [True] * 4
+        ov.verify_batch(pks, msgs, sigs)  # compile once, on this thread
+        got: list = []
+
+        def run() -> None:
+            for _ in range(5):
+                got.append(list(ov.verify_batch(pks, msgs, sigs)[1]))
+
+        threads = [threading.Thread(target=run) for _ in range(4)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120)
+        assert got == [want] * 20
 
 
 class TestDtypeShrink:
@@ -181,20 +244,20 @@ class TestSmallGridSplit:
         calls: list[tuple] = []
         real = ov._jitted_kernel
 
-        def spy(which="xla", donate=True, grid=None):
-            calls.append((which, donate, grid))
-            return real(which, donate, grid)
+        def spy(which="xla", grid=None):
+            calls.append((which, grid))
+            return real(which, grid)
 
         monkeypatch.setattr(ov, "_jitted_kernel", spy)
         pks, msgs, sigs = _lanes(4, seed=8)
         buf, host_ok = ov.pack_bytes(pks, msgs, sigs)
         bits = ov.verify_bytes_async(buf, 4)()
         assert (bits & host_ok).all()
-        assert calls and calls[-1][2] == 8, calls
+        assert calls and calls[-1][1] == 8, calls
         # the dedicated jit carries its own devstats kernel identity,
         # so small-window compiles/launches attribute per bucket
-        assert real("xla", True, 8).kernel == "verify.xla.g8"
-        assert real("xla", True, None).kernel == "verify.xla"
+        assert real("xla", 8).kernel == "verify.xla.g8"
+        assert real("xla", None).kernel == "verify.xla"
 
 
 
@@ -208,46 +271,41 @@ class TestKnobsRegisteredAndDocumented:
             os.path.join(os.path.dirname(__file__), "..", "docs", "perf.md")
         ).read()
         for knob in (
-            "COMETBFT_TPU_LANE_ARENA",
             "COMETBFT_TPU_COALESCE_INFLIGHT",
             "COMETBFT_TPU_HASH_INFLIGHT",
         ):
             assert knob in ENV_KNOBS, knob
             assert knob in doc, f"{knob} missing from docs/perf.md"
+        # retired with the staging arena it switched (PR 26)
+        assert "COMETBFT_TPU_LANE_ARENA" not in ENV_KNOBS
 
 
-class TestKnobAndSampling:
-    def test_knob_off_stages_nothing(self, monkeypatch):
-        monkeypatch.setattr(ov, "_LANE_ARENA_MODE", "0")
-        arena = ov.LaneArena()
-        monkeypatch.setattr(ov, "_LANE_ARENA", arena)
-        monkeypatch.setenv("COMETBFT_TPU_SHARD", "0")
-        monkeypatch.setattr(cbatch, "HOST_BATCH_THRESHOLD", 2)
+class TestRetiredArena:
+    def test_retired_knob_changes_nothing(self, device_path, monkeypatch):
+        monkeypatch.setenv("COMETBFT_TPU_LANE_ARENA", "1")
         pks, msgs, sigs = _lanes(4, seed=9)
-        ok, _ = ov.verify_batch(pks, msgs, sigs)
-        assert ok
-        assert arena.stages == 0
+        devstats.enable()
+        try:
+            ok, _ = ov.verify_batch(pks, msgs, sigs)
+            assert ok
+            kernels = {row["kernel"] for row in devstats.compile_log()}
+            assert not [
+                k for k in kernels if k.startswith("stage.")
+            ]
+        finally:
+            devstats.disable()
 
-    def test_devstats_samples_lane_arena(self, staged_arena):
+    def test_devstats_sample_has_no_lane_arena_block(self, device_path):
         pks, msgs, sigs = _lanes(4, seed=10)
         ov.verify_batch(pks, msgs, sigs)
         devstats.enable()
         m = NodeMetrics()
         libmetrics.push_node_metrics(m)
         try:
-            out = devstats.sample(m)
-            la = out["lane_arena"]
-            assert la["stages"] == staged_arena.stages > 0
-            assert la["buffers"] == staged_arena.buffers()
-            assert (
-                m.lane_arena_staging.labels("buffers").value()
-                == la["buffers"]
-            )
-            assert (
-                m.lane_arena_stages.labels("alloc").value()
-                + m.lane_arena_stages.labels("reuse").value()
-                == la["stages"]
-            )
+            got = devstats.sample(m)
         finally:
             libmetrics.pop_node_metrics(m)
             devstats.disable()
+        assert "lane_arena" not in got
+        assert {"pubkey_arena", "verify_dispatch"} <= set(got)
+        assert "lane_arena" not in m.registry.render()

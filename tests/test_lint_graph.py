@@ -626,22 +626,16 @@ class TestEngineWideGate:
             ]
             assert edges == [], (lock, edges)
 
-    def test_lane_arena_lock_registered_and_leaf(self, analysis):
+    def test_lane_arena_lock_is_retired(self, analysis):
         """The lane staging arena's slot mutex ('ops.verify._lane_mtx')
-        is in the shipped artifact and edge-free: stage() holds it only
-        across slot bookkeeping and the ASYNC staging-jit dispatch —
-        never a device wait, never another lock. It may be acquired
-        under caller engine mutexes (verify paths run from consensus /
-        blocksync / RPC threads), so an OUTGOING edge would splice the
-        staging arena into the engine lock hierarchy."""
+        went with the arena (PR 26): a launch takes no lock between its
+        pack and its kernel call, so nothing of the verify dispatch can
+        splice into the engine lock hierarchy there. The pubkey table
+        cache's lock is the one ops/verify lock left."""
         d = analysis.graph_dict()
-        assert "ops.verify._lane_mtx" in {lk["name"] for lk in d["locks"]}
-        edges = [
-            (e["from"], e["to"])
-            for e in d["edges"]
-            if e["from"] == "ops.verify._lane_mtx"
-        ]
-        assert edges == [], edges
+        names = {lk["name"] for lk in d["locks"]}
+        assert "ops.verify._lane_mtx" not in names
+        assert "ops.verify._lock" in names
 
     def test_health_lock_registered_and_leaf(self, analysis):
         """libs/health's bundle-rate-limit mutex carries the same
