@@ -33,6 +33,13 @@ class BatchVerifier:
     def add(self, pub_key, msg: bytes, signature: bytes) -> None:
         raise NotImplementedError
 
+    def add_many(self, pub_keys, msgs, signatures) -> None:
+        """``add`` for each triple in order. The backends check the key
+        types first and then extend their lists, so a foreign key
+        raises ``add``'s TypeError before any lane is taken."""
+        for pub_key, msg, signature in zip(pub_keys, msgs, signatures):
+            self.add(pub_key, msg, signature)
+
     def verify(self) -> tuple[bool, list[bool]]:
         raise NotImplementedError
 
@@ -261,6 +268,20 @@ def host_batch_threshold() -> int:
     return _ACCEL_HOST_BATCH_THRESHOLD if accelerator_backend_live() else base
 
 
+def _all_instances(pub_keys, key_class) -> bool:
+    """One type check over a batch's keys: by class, not by key."""
+    return all(issubclass(t, key_class) for t in set(map(type, pub_keys)))
+
+
+def _extend_lanes(bv, pub_keys, msgs, signatures) -> None:
+    """add_many's three extends, after the backend's type check."""
+    if not len(pub_keys) == len(msgs) == len(signatures):
+        raise ValueError("add_many needs a message and a signature per key")
+    bv._pubkeys.extend([pk.data for pk in pub_keys])
+    bv._msgs.extend(map(bytes, msgs))
+    bv._sigs.extend(map(bytes, signatures))
+
+
 class Ed25519BatchVerifier(BatchVerifier):
     """TPU-backed ed25519 batch verification with a host small-batch path."""
 
@@ -275,6 +296,11 @@ class Ed25519BatchVerifier(BatchVerifier):
         self._pubkeys.append(pub_key.data)
         self._msgs.append(bytes(msg))
         self._sigs.append(bytes(signature))
+
+    def add_many(self, pub_keys, msgs, signatures) -> None:
+        if not _all_instances(pub_keys, Ed25519PubKey):
+            raise TypeError("Ed25519BatchVerifier requires ed25519 keys")
+        _extend_lanes(self, pub_keys, msgs, signatures)
 
     def __len__(self) -> int:
         return len(self._pubkeys)
@@ -360,6 +386,13 @@ class Sr25519BatchVerifier(BatchVerifier):
         self._pubkeys.append(pub_key.data)
         self._msgs.append(bytes(msg))
         self._sigs.append(bytes(signature))
+
+    def add_many(self, pub_keys, msgs, signatures) -> None:
+        from .sr25519 import Sr25519PubKey
+
+        if not _all_instances(pub_keys, Sr25519PubKey):
+            raise TypeError("Sr25519BatchVerifier requires sr25519 keys")
+        _extend_lanes(self, pub_keys, msgs, signatures)
 
     def __len__(self) -> int:
         return len(self._pubkeys)
@@ -449,6 +482,14 @@ class MixedBatchVerifier(BatchVerifier):
         self._pubkeys.append(pub_key.data)
         self._msgs.append(bytes(msg))
         self._sigs.append(bytes(signature))
+
+    def add_many(self, pub_keys, msgs, signatures) -> None:
+        types = [getattr(pk, "type", None) for pk in pub_keys]
+        for t in dict.fromkeys(types):  # the first foreign type first
+            if t not in _BATCH_BACKENDS:
+                raise TypeError(f"unsupported key type for batching: {t!r}")
+        _extend_lanes(self, pub_keys, msgs, signatures)
+        self._types.extend(types)
 
     def __len__(self) -> int:
         return len(self._pubkeys)

@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import ctypes
 import os
+from array import array
 from ..libs import sync as libsync
 import secrets
 
@@ -120,6 +121,14 @@ def _bind(lib) -> None:
         ctypes.c_char_p, ctypes.c_size_t, ctypes.c_char_p,
         ctypes.c_char_p,
     ]
+    # called with the interpreter lock HELD (PYFUNCTYPE): the call takes
+    # microseconds, and a thread that lets the lock go waits whole time
+    # slices behind every other runnable thread to get it back
+    lib.edb_vote_sign_bytes = ctypes.PYFUNCTYPE(
+        None, ctypes.c_char_p, ctypes.c_size_t, ctypes.c_char_p,
+        ctypes.c_size_t, ctypes.c_void_p, ctypes.c_size_t,
+        ctypes.c_void_p, ctypes.c_void_p,
+    )(("edb_vote_sign_bytes", lib))
 
 
 def _install_sha512_constants(lib) -> None:
@@ -180,6 +189,27 @@ def pack_challenges(recs: bytes, msgs_blob: bytes, offs, n: int):
     if rc != 0:
         return None
     return out_kneg.raw, np.frombuffer(out_ok.raw, np.uint8).astype(bool)
+
+
+def vote_sign_bytes(prefix: bytes, suffix: bytes, timestamps_ns):
+    """CanonicalVote sign bytes of votes that differ in the timestamp
+    alone, for types/canonical.vote_sign_bytes_many: ``timestamps_ns`` an
+    ``array('q')``. Returns (blob, offs): lane i is
+    ``blob[offs[i]:offs[i + 1]]``, the layout ``pack_challenges`` takes;
+    None when the native engine is unavailable. The call keeps the
+    interpreter lock (it takes ~30 ns a lane)."""
+    lib = _load()
+    if lib is None:
+        return None
+    n = len(timestamps_ns)
+    out = bytearray(n * (len(prefix) + len(suffix) + 32))
+    offs = array("Q", bytes(8 * (n + 1)))
+    lib.edb_vote_sign_bytes(
+        prefix, len(prefix), suffix, len(suffix),
+        timestamps_ns.buffer_info()[0], n,
+        (ctypes.c_char * len(out)).from_buffer(out), offs.buffer_info()[0],
+    )
+    return bytes(memoryview(out)[: offs[n]]), offs
 
 
 def sr_challenge_batch(

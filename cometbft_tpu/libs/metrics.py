@@ -445,6 +445,17 @@ class NodeMetrics:
         )
         for result in ("computed", "reused"):
             self.valset_hash_total.labels(result)
+        # lanes whose sign-bytes a commit check encoded, by encoder:
+        # "batched" (Commit.vote_sign_bytes_many, one encoding a commit)
+        # or "per_lane" (canonical.vote_sign_bytes lane by lane: the
+        # single-verify walk). Both series exist from the start.
+        self.commit_sign_bytes_lanes_total = r.counter(
+            "types_commit_sign_bytes_lanes_total",
+            "Commit-check lanes by sign-bytes encoder",
+            label_names=("path",),
+        )
+        for path in ("batched", "per_lane"):
+            self.commit_sign_bytes_lanes_total.labels(path)
         # cross-caller verify coalescer (crypto/coalesce.py): window
         # shape, flush triggers, and the submit-to-verdict latency its
         # callers actually pay. Lane-count buckets are the shape
@@ -1099,6 +1110,12 @@ def observe_valset_hash(reused: bool) -> None:
     node_metrics().valset_hash_total.labels(
         "reused" if reused else "computed"
     ).inc()
+
+
+def observe_commit_sign_bytes(path: str, lanes: int) -> None:
+    """Lanes one commit check encoded sign-bytes for:
+    types_commit_sign_bytes_lanes_total{path}."""
+    node_metrics().commit_sign_bytes_lanes_total.labels(path).inc(lanes)
 
 
 def observe_hash_phase(phase: str, seconds: float, lanes: int) -> None:

@@ -1050,4 +1050,59 @@ void edb_decompress_ok(const uint8_t* points_enc, size_t m, uint8_t* out) {
         out[i] = pt_decompress(points_enc + 32 * i, tmp) ? 1 : 0;
 }
 
+static inline size_t put_uvarint(uint8_t* dst, uint64_t v) {
+    size_t k = 0;
+    while (v >= 0x80) {
+        dst[k++] = (uint8_t)(v | 0x80);
+        v >>= 7;
+    }
+    dst[k++] = (uint8_t)v;
+    return k;
+}
+
+// CanonicalVote sign bytes of n votes that differ in the timestamp alone
+// (one commit's lanes; types/canonical.py vote_sign_bytes is the per-vote
+// reference): lane i is written to out[offs[i]:offs[i+1]] as
+//   uvarint(body length) | prefix | 0x2a len | Timestamp | suffix
+// where Timestamp = [0x08 varint(seconds)] [0x10 varint(nanos)] of
+// ts_ns[i] split by floor division, a zero field omitted (proto3) and
+// negative seconds a 10-byte two's-complement varint. prefix holds
+// fields 1-4, suffix field 6. out needs n * (plen + slen + 32) bytes,
+// offs n + 1 entries: the msgs/offs layout edb_pack_challenges reads.
+void edb_vote_sign_bytes(const uint8_t* prefix, size_t plen,
+                         const uint8_t* suffix, size_t slen,
+                         const int64_t* ts_ns, size_t n, uint8_t* out,
+                         uint64_t* offs) {
+    const int64_t NS = 1000000000;
+    size_t pos = 0;
+    offs[0] = 0;
+    for (size_t i = 0; i < n; i++) {
+        int64_t seconds = ts_ns[i] / NS, nanos = ts_ns[i] % NS;
+        if (nanos < 0) {  // C truncates; Python's divmod floors
+            nanos += NS;
+            seconds -= 1;
+        }
+        uint8_t ts[17];
+        size_t tlen = 0;
+        if (seconds) {
+            ts[tlen++] = 0x08;
+            tlen += put_uvarint(ts + tlen, (uint64_t)seconds);
+        }
+        if (nanos) {
+            ts[tlen++] = 0x10;
+            tlen += put_uvarint(ts + tlen, (uint64_t)nanos);
+        }
+        pos += put_uvarint(out + pos, plen + 2 + tlen + slen);
+        memcpy(out + pos, prefix, plen);
+        pos += plen;
+        out[pos++] = 0x2a;
+        out[pos++] = (uint8_t)tlen;
+        memcpy(out + pos, ts, tlen);
+        pos += tlen;
+        memcpy(out + pos, suffix, slen);
+        pos += slen;
+        offs[i + 1] = pos;
+    }
+}
+
 }  // extern "C"

@@ -249,6 +249,42 @@ class Commit:
             cs.timestamp_ns,
         )
 
+    def vote_sign_bytes_many(
+        self, chain_id: str, idxs
+    ) -> list[bytes] | None:
+        """``[self.vote_sign_bytes(chain_id, i) for i in idxs]``, encoded
+        together (canonical.vote_sign_bytes_many): once for the lanes that
+        signed this commit's block id and once for those that signed nil.
+        None where that encoder cannot take them; the caller then goes
+        lane by lane."""
+        lanes = [self.signatures[i] for i in idxs]
+        if {cs.block_id_flag for cs in lanes} == {BLOCK_ID_FLAG_COMMIT}:
+            return self._sign_bytes_of(chain_id, self.block_id, lanes)
+        voted = [cs.block_id(self.block_id) for cs in lanes]
+        out: list = [None] * len(lanes)
+        for block_id in (self.block_id, NIL_BLOCK_ID):
+            at = [k for k, b in enumerate(voted) if b is block_id]
+            encoded = self._sign_bytes_of(
+                chain_id, block_id, [lanes[k] for k in at]
+            )
+            if encoded is None:
+                return None
+            for k, sign_bytes in zip(at, encoded):
+                out[k] = sign_bytes
+        return out
+
+    def _sign_bytes_of(
+        self, chain_id, block_id, lanes
+    ) -> list[bytes] | None:
+        return canonical.vote_sign_bytes_many(
+            chain_id,
+            canonical.PRECOMMIT_TYPE,
+            self.height,
+            self.round,
+            block_id,
+            [cs.timestamp_ns for cs in lanes],
+        )
+
     def hash(self) -> bytes:
         if self._hash is None:
             self._hash = merkle.hash_from_byte_slices(

@@ -11,6 +11,9 @@ Message types: prevote=1, precommit=2, proposal=32
 
 from __future__ import annotations
 
+from array import array
+
+from ..crypto import host_batch
 from . import proto
 
 PREVOTE_TYPE = 1
@@ -50,15 +53,10 @@ _SIGN_TEMPLATE_CACHE: dict = {}
 _SIGN_TEMPLATE_BOUND = 64
 
 
-def vote_sign_bytes(
-    chain_id: str,
-    msg_type: int,
-    height: int,
-    round_: int,
-    block_id,
-    timestamp_ns: int,
-) -> bytes:
-    """CanonicalVote sign bytes (types/vote.go:139, canonical.proto:30-37)."""
+def _vote_template(
+    chain_id: str, msg_type: int, height: int, round_: int, block_id
+) -> tuple[bytes, bytes]:
+    """(prefix, suffix) of a CanonicalVote around its timestamp field."""
     bid_key = (
         None
         if block_id is None or block_id.is_nil()
@@ -82,13 +80,62 @@ def vote_sign_bytes(
         if len(_SIGN_TEMPLATE_CACHE) >= _SIGN_TEMPLATE_BOUND:
             _SIGN_TEMPLATE_CACHE.clear()
         _SIGN_TEMPLATE_CACHE[key] = tpl
-    prefix, suffix = tpl
+    return tpl
+
+
+def vote_sign_bytes(
+    chain_id: str,
+    msg_type: int,
+    height: int,
+    round_: int,
+    block_id,
+    timestamp_ns: int,
+) -> bytes:
+    """CanonicalVote sign bytes (types/vote.go:139, canonical.proto:30-37)."""
+    prefix, suffix = _vote_template(
+        chain_id, msg_type, height, round_, block_id
+    )
     body = (
         prefix
         + proto.field_message(5, proto.timestamp(timestamp_ns), always=True)
         + suffix
     )
     return proto.delimited(body)
+
+
+def vote_sign_bytes_many(
+    chain_id: str,
+    msg_type: int,
+    height: int,
+    round_: int,
+    block_id,
+    timestamps_ns,
+) -> list[bytes] | None:
+    """``[vote_sign_bytes(..., t) for t in timestamps_ns]``, byte for byte,
+    encoded together: the votes of one commit differ in the timestamp
+    alone, so the template is looked up once and the native engine
+    (native/edbatch.cpp edb_vote_sign_bytes) writes every lane in one
+    call that keeps the interpreter lock.
+
+    None where the lanes cannot be encoded together, and the caller
+    encodes them one by one: no native engine on this machine, or a
+    timestamp beyond int64 nanoseconds (Go's zero time, the timestamp of
+    an absent CommitSig, is)."""
+    if not timestamps_ns:
+        return []
+    prefix, suffix = _vote_template(
+        chain_id, msg_type, height, round_, block_id
+    )
+    try:
+        timestamps = array("q", timestamps_ns)
+    except OverflowError:
+        return None
+    rows = host_batch.vote_sign_bytes(prefix, suffix, timestamps)
+    if rows is None:
+        return None
+    blob, offs = rows
+    offs = offs.tolist()
+    return [blob[start:end] for start, end in zip(offs, offs[1:])]
 
 
 def proposal_sign_bytes(

@@ -1,7 +1,8 @@
 """Commit verification — the engine-wide hot path (types/validation.go).
 
-All three façades tally voting power while streaming (pubkey, sign-bytes,
-signature) triples into one device batch:
+All three façades select their lanes and tally voting power in one walk
+(_select_lanes), encode the selected lanes' sign-bytes together and hand
+the (pubkey, sign-bytes, signature) triples to one device batch:
 
 * verify_commit          — full check, every signature (consensus apply path)
 * verify_commit_light    — stop at +2/3, commit-flag sigs only (light/blocksync)
@@ -95,12 +96,7 @@ def verify_commit(
     """+2/3 check over ALL signatures (incl. nil votes) — consensus path."""
     _verify_basic(vals, commit, height, block_id)
     needed = vals.total_voting_power() * 2 // 3
-    ignore = lambda cs: cs.block_id_flag == BLOCK_ID_FLAG_ABSENT  # noqa: E731
-    count = lambda cs: cs.block_id_flag == BLOCK_ID_FLAG_COMMIT  # noqa: E731
-    _verify(
-        chain_id, vals, commit, needed, ignore, count,
-        count_all=True, by_index=True,
-    )
+    _verify(chain_id, vals, commit, needed, count_all=True, by_index=True)
 
 
 def verify_commit_light(
@@ -113,12 +109,7 @@ def verify_commit_light(
     """+2/3 check, commit-flag signatures only, stops when reached."""
     _verify_basic(vals, commit, height, block_id)
     needed = vals.total_voting_power() * 2 // 3
-    ignore = lambda cs: cs.block_id_flag != BLOCK_ID_FLAG_COMMIT  # noqa: E731
-    count = lambda cs: True  # noqa: E731
-    _verify(
-        chain_id, vals, commit, needed, ignore, count,
-        count_all=False, by_index=True,
-    )
+    _verify(chain_id, vals, commit, needed, count_all=False, by_index=True)
 
 
 def verify_commit_light_trusting(
@@ -137,17 +128,10 @@ def verify_commit_light_trusting(
     needed = (
         vals.total_voting_power() * trust_level.numerator
     ) // trust_level.denominator
-    ignore = lambda cs: cs.block_id_flag != BLOCK_ID_FLAG_COMMIT  # noqa: E731
-    count = lambda cs: True  # noqa: E731
-    _verify(
-        chain_id, vals, commit, needed, ignore, count,
-        count_all=False, by_index=False,
-    )
+    _verify(chain_id, vals, commit, needed, count_all=False, by_index=False)
 
 
-def _verify(
-    chain_id, vals, commit, needed, ignore, count, count_all, by_index
-) -> None:
+def _verify(chain_id, vals, commit, needed, count_all, by_index) -> None:
     from ..libs import devledger
 
     # ledger attribution default: an untagged commit verification is
@@ -155,51 +139,88 @@ def _verify(
     # blocksync reactor, statesync restores) declared first and win
     with devledger.caller_class("commit-verify"):
         if _should_batch_verify(vals, commit):
-            _verify_batch(
-                chain_id, vals, commit, needed, ignore, count, count_all,
-                by_index,
-            )
+            _verify_batch(chain_id, vals, commit, needed, count_all, by_index)
         else:
-            _verify_single(
-                chain_id, vals, commit, needed, ignore, count, count_all,
-                by_index,
-            )
+            _verify_single(chain_id, vals, commit, needed, count_all, by_index)
+
+
+def _select_lanes(vals, commit, needed, count_all, by_index):
+    """The lanes a commit check verifies, in commit order, and their tally:
+    ``(idxs, validators, tallied, double_vote)``.
+
+    ``count_all`` (verify_commit) takes every signature that is not
+    absent, nil votes too, and counts the power of those for the block;
+    without it only signatures for the block are taken and the walk
+    stops at the first lane where the tally passes ``needed``.
+    ``by_index`` reads the validator at the signature's index; without
+    it the validator is looked up by address, unknown addresses are
+    skipped, and a validator's second signature ends the walk:
+    ``double_vote`` is then that error, held for the caller, which may
+    owe an earlier lane its own.
+    """
+    idxs: list[int] = []
+    lane_vals: list = []
+    seen: dict[int, int] = {}
+    tallied = 0
+    validators = vals.validators
+    for idx, cs in enumerate(commit.signatures):
+        flag = cs.block_id_flag
+        if flag != BLOCK_ID_FLAG_COMMIT and (
+            not count_all or flag == BLOCK_ID_FLAG_ABSENT
+        ):
+            continue
+        if by_index:
+            val = validators[idx]
+        else:
+            val_idx, val = vals.get_by_address(cs.validator_address)
+            if val is None:
+                continue
+            if val_idx in seen:
+                return idxs, lane_vals, tallied, VerificationError(
+                    f"double vote from validator {val_idx} "
+                    f"({seen[val_idx]} and {idx})"
+                )
+            seen[val_idx] = idx
+        idxs.append(idx)
+        lane_vals.append(val)
+        if flag == BLOCK_ID_FLAG_COMMIT:
+            tallied += val.voting_power
+        if not count_all and tallied > needed:
+            break
+    return idxs, lane_vals, tallied, None
 
 
 def _verify_batch(
-    chain_id, vals, commit, needed, ignore, count, count_all, by_index
+    chain_id, vals, commit, needed, count_all, by_index
 ) -> None:
     """Mirror of verifyCommitBatch (types/validation.go:153-257)."""
     bv = crypto_batch.create_commit_batch_verifier(vals)
-    seen: dict[int, int] = {}
-    batch_sig_idxs: list[int] = []
-    tallied = 0
-    # one span for the whole walk (sign-bytes, bv.add, tally), never one
-    # per lane; bv.verify() has the verify.* phases of its own
+    # one span for the whole walk (selection, one encoding of the
+    # selected lanes' sign-bytes, one bv.add_many), never one per lane;
+    # bv.verify() has the verify.* phases of its own
     with libmetrics.light_phase("sign_bytes", "commit.sign_bytes") as ph:
-        for idx, cs in enumerate(commit.signatures):
-            if ignore(cs):
-                continue
-            if by_index:
-                val = vals.validators[idx]
-            else:
-                val_idx, val = vals.get_by_address(cs.validator_address)
-                if val is None:
-                    continue
-                if val_idx in seen:
-                    raise VerificationError(
-                        f"double vote from validator {val_idx} "
-                        f"({seen[val_idx]} and {idx})"
-                    )
-                seen[val_idx] = idx
-            sign_bytes = commit.vote_sign_bytes(chain_id, idx)
-            bv.add(val.pub_key, sign_bytes, cs.signature)
-            batch_sig_idxs.append(idx)
-            if count(cs):
-                tallied += val.voting_power
-            if not count_all and tallied > needed:
-                break
-        ph.set(lanes=len(batch_sig_idxs))
+        idxs, lane_vals, tallied, double_vote = _select_lanes(
+            vals, commit, needed, count_all, by_index
+        )
+        if double_vote is not None:
+            raise double_vote
+        encoder = "batched"
+        sign_bytes = commit.vote_sign_bytes_many(chain_id, idxs)
+        if sign_bytes is None:
+            # no native engine on this machine, or a timestamp beyond
+            # int64 nanoseconds: the per-vote encoder, lane by lane
+            encoder = "per_lane"
+            sign_bytes = [
+                commit.vote_sign_bytes(chain_id, idx) for idx in idxs
+            ]
+        signatures = commit.signatures
+        bv.add_many(
+            [val.pub_key for val in lane_vals],
+            sign_bytes,
+            [signatures[idx].signature for idx in idxs],
+        )
+        libmetrics.observe_commit_sign_bytes(encoder, len(idxs))
+        ph.set(lanes=len(idxs), encoder=encoder)
     if tallied <= needed:
         raise NotEnoughVotingPowerError(got=tallied, needed=needed)
     ok, valid_sigs = bv.verify()
@@ -207,7 +228,7 @@ def _verify_batch(
         return
     for i, sig_ok in enumerate(valid_sigs):
         if not sig_ok:
-            idx = batch_sig_idxs[i]
+            idx = idxs[i]
             raise VerificationError(
                 f"wrong signature (#{idx}): "
                 f"{commit.signatures[idx].signature.hex()}"
@@ -218,7 +239,7 @@ def _verify_batch(
 
 
 def _verify_single(
-    chain_id, vals, commit, needed, ignore, count, count_all, by_index
+    chain_id, vals, commit, needed, count_all, by_index
 ) -> None:
     """Mirror of verifyCommitSingle (types/validation.go:266-330).
 
@@ -233,50 +254,29 @@ def _verify_single(
     from ..crypto import coalesce
 
     co = coalesce.active()
-    seen: dict[int, int] = {}
-    tallied = 0
     deferred: list[tuple] = []  # (idx, pubkey_data, sign_bytes, sig)
-    stopped_early = False
-    # Any raise inside the walk is HELD, not thrown: deferred ed25519
+    # Any error of the walk is HELD, not thrown: deferred ed25519
     # lanes collected earlier in the walk are still unverified, and the
     # unrouted walk raises at the earliest failing index — an invalid
     # deferred lane must surface before a later double-vote /
     # sign-bytes / wrong-signature error. All deferred lanes precede
     # the break point by construction, so resolving them first and
     # then re-raising preserves the reference error identity.
-    walk_exc: BaseException | None = None
-    for idx, cs in enumerate(commit.signatures):
-        if ignore(cs):
-            continue
-        if by_index:
-            val = vals.validators[idx]
-        else:
-            val_idx, val = vals.get_by_address(cs.validator_address)
-            if val is None:
-                continue
-            if val_idx in seen:
-                walk_exc = VerificationError(
-                    f"double vote from validator {val_idx} "
-                    f"({seen[val_idx]} and {idx})"
-                )
-                break
-            seen[val_idx] = idx
+    idxs, lane_vals, tallied, walk_exc = _select_lanes(
+        vals, commit, needed, count_all, by_index
+    )
+    libmetrics.observe_commit_sign_bytes("per_lane", len(idxs))
+    for idx, val in zip(idxs, lane_vals):
         try:
             sign_bytes = commit.vote_sign_bytes(chain_id, idx)
         except Exception as e:
             walk_exc = e
             break
+        signature = commit.signatures[idx].signature
         if co is not None and coalesce.eligible(val.pub_key):
-            deferred.append(
-                (idx, val.pub_key, sign_bytes, cs.signature)
-            )
-        elif not val.pub_key.verify_signature(sign_bytes, cs.signature):
+            deferred.append((idx, val.pub_key, sign_bytes, signature))
+        elif not val.pub_key.verify_signature(sign_bytes, signature):
             walk_exc = VerificationError(f"wrong signature (#{idx})")
-            break
-        if count(cs):
-            tallied += val.voting_power
-        if not count_all and tallied > needed:
-            stopped_early = True
             break
     if deferred:
         bits = coalesce.verify_bytes(
@@ -294,7 +294,5 @@ def _verify_single(
                 raise VerificationError(f"wrong signature (#{idx})")
     if walk_exc is not None:
         raise walk_exc
-    if stopped_early:
-        return
     if tallied <= needed:
         raise NotEnoughVotingPowerError(got=tallied, needed=needed)

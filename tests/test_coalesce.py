@@ -648,10 +648,6 @@ class TestFirstInvalidIndexIdentity:
         import dataclasses
 
         from cometbft_tpu.types import validation
-        from cometbft_tpu.types.block import (
-            BLOCK_ID_FLAG_ABSENT,
-            BLOCK_ID_FLAG_COMMIT,
-        )
         from tests.helpers import sign_commit
 
         vals, pvs = _make_valset(5)
@@ -674,8 +670,6 @@ class TestFirstInvalidIndexIdentity:
             with pytest.raises(validation.VerificationError) as ei:
                 validation._verify_single(
                     CHAIN_ID, vals, bad, needed,
-                    lambda cs: cs.block_id_flag == BLOCK_ID_FLAG_ABSENT,
-                    lambda cs: cs.block_id_flag == BLOCK_ID_FLAG_COMMIT,
                     count_all=True, by_index=True,
                 )
             return str(ei.value)
@@ -698,10 +692,6 @@ class TestFirstInvalidIndexIdentity:
         import dataclasses
 
         from cometbft_tpu.types import validation
-        from cometbft_tpu.types.block import (
-            BLOCK_ID_FLAG_ABSENT,
-            BLOCK_ID_FLAG_COMMIT,
-        )
         from tests.helpers import sign_commit
 
         vals, pvs = _make_valset(5)
@@ -717,8 +707,6 @@ class TestFirstInvalidIndexIdentity:
             with pytest.raises(validation.VerificationError) as ei:
                 validation._verify_single(
                     CHAIN_ID, vals, bad, needed,
-                    lambda cs: cs.block_id_flag == BLOCK_ID_FLAG_ABSENT,
-                    lambda cs: cs.block_id_flag == BLOCK_ID_FLAG_COMMIT,
                     count_all=True, by_index=False,
                 )
             return str(ei.value)

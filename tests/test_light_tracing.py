@@ -9,6 +9,7 @@ import glob
 import json
 import os
 import threading
+import types
 
 import pytest
 
@@ -176,6 +177,61 @@ def test_valset_hash_counter_has_both_series(one_header):
         assert (
             'cometbft_tpu_types_valset_hash_total{result="%s"} ' % result
         ) in text
+
+
+def test_sign_bytes_counter_grows_by_the_spans_lanes(one_header):
+    """What sign_bytes_batched_pct.* divides: one walk, one span, and the
+    batched encoder's series grows by that span's ``lanes``."""
+    spans, m = one_header
+    (sb,) = [s for s in spans if s["name"] == "commit.sign_bytes"]
+    assert sb["lanes"] == CUT and sb["encoder"] == "batched"
+    lanes = m.commit_sign_bytes_lanes_total
+    assert lanes.labels("batched").value() == sb["lanes"]
+    assert lanes.labels("per_lane").value() == 0
+    text = m.registry.render()
+    for path in ("batched", "per_lane"):
+        assert (
+            'cometbft_tpu_types_commit_sign_bytes_lanes_total{path="%s"} '
+            % path
+        ) in text
+
+
+def test_lanes_handed_back_count_per_lane(
+    device_route, tracer, metrics, monkeypatch
+):
+    """Without the native engine the walk encodes lane by lane, and both
+    the counter and the span say so."""
+    from cometbft_tpu.crypto import host_batch
+
+    monkeypatch.setattr(host_batch, "vote_sign_bytes", lambda *a: None)
+    blocks = helpers.make_light_chain(3, n_vals=N_VALS)
+    client = _client(blocks)
+    libtrace.reset()
+    m = NodeMetrics()
+    libmetrics.push_node_metrics(m)
+    try:
+        client.verify_light_block_at_height(2, now_after(blocks, 2))
+    finally:
+        libmetrics.pop_node_metrics(m)
+    (sb,) = [s for s in _spans() if s["name"] == "commit.sign_bytes"]
+    assert sb["lanes"] == CUT and sb["encoder"] == "per_lane"
+    assert m.commit_sign_bytes_lanes_total.labels("per_lane").value() == CUT
+    assert m.commit_sign_bytes_lanes_total.labels("batched").value() == 0
+
+
+def test_single_verify_walk_counts_its_lanes_per_lane(metrics):
+    """A commit of one signature is below the batch threshold: its lane
+    goes through canonical.vote_sign_bytes and is counted so."""
+    from cometbft_tpu.types import validation
+
+    blocks = helpers.make_light_chain(2, n_vals=1)
+    lb = blocks[2]
+    commit = lb.signed_header.commit
+    validation.verify_commit_light(
+        helpers.CHAIN_ID, lb.validator_set, commit.block_id, 2, commit)
+    lanes = metrics.commit_sign_bytes_lanes_total
+    assert lanes.labels("per_lane").value() == 1
+    assert lanes.labels("batched").value() == 0
 
 
 class _FreshSetProvider(DictProvider):
@@ -417,7 +473,8 @@ NEW_METRICS = (
     "provider_fetch_ms_per_header", "valset_hash_ms_per_header",
     "header_basic_ms_per_header", "sign_bytes_ms_per_header",
     "kernel_wait_ms_per_header", "light_span_coverage_pct.replay",
-    "valset_hash_reuse_pct.replay",
+    "valset_hash_reuse_pct.replay", "sign_bytes_batched_pct.replay",
+    "sign_bytes_batched_pct.backfill",
 )
 
 
@@ -449,4 +506,31 @@ def test_benchmark_metric_reads_a_series_that_exists(
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         (entry,) = [m for m in json.load(f)["per_layer"] if m["name"] == name]
     assert entry["moves"] == "sigs_per_s"
-    assert entry["workloads"] == ["light10k-replay"]
+    assert entry["workloads"] == [
+        "qa175-relayers-backfill" if name.endswith(".backfill")
+        else "light10k-replay"
+    ]
+
+
+@pytest.mark.parametrize("cell", ["replay", "backfill"])
+def test_sign_bytes_batched_pct_resolves_from_a_windows_counters(
+    device_route, cell
+):
+    """The benchmark's own snapshot, delta and reader over a window of
+    one header: every lane went through the batched encoder."""
+    from benchmark.harness import counters
+    from benchmark.readers import counter_ratio
+
+    name = f"sign_bytes_batched_pct.{cell}"
+    with open(os.path.join(REPO, "benchmark", "metrics", name + ".json")) as f:
+        metric = json.load(f)
+    blocks = helpers.make_light_chain(3, n_vals=N_VALS)
+    client = _client(blocks)
+    before = counters.snapshot()
+    client.verify_light_block_at_height(2, now_after(blocks, 2))
+    after = counters.snapshot()
+    window = types.SimpleNamespace(counters=counters.delta(before, after))
+    assert counter_ratio.read(metric, window) == 100.0
+    # a window in which no commit was checked has nothing to read
+    idle = types.SimpleNamespace(counters=counters.delta(after, after))
+    assert counter_ratio.read(metric, idle) is None
