@@ -40,7 +40,7 @@ import numpy as np
 _DETAILS: list = []
 
 # COMETBFT_BENCH_TINY=1 shrinks every config so the FULL capture path —
-# 5-config table, extras, kernel A/B — executes end to end in minutes.
+# 5-config table, extras — executes end to end in minutes.
 # With JAX_PLATFORMS=cpu it is the only run bench.py makes off the chip
 # (tests/test_bench_capture.py); its provenance row says ``cpu``.
 _TINY = os.environ.get("COMETBFT_BENCH_TINY") == "1"
@@ -416,14 +416,10 @@ _VPU_INT32_PEAK = 3.3e12
 # (29 dbl-chain + 8 niels + 7 affine) + tail ~31.
 _LADDER_MULS_CACHED = 265 + 64 * 44 + 31
 _LADDER_MULS_UNCACHED = _LADDER_MULS_CACHED + 265 + 121  # + A decomp/table
-# 8-bit fixed-base windows: -32 affine B-adds (-224 muls) + 1 complete
-# add (+9) = -215 muls/sig vs the joint ladder (docs/tpu-kernel.md);
-# the window selects move to the MXU and leave the VPU ledger.
+# both programs run the same joint ladder
 _MULS_UNCACHED_BY_KERNEL = {
     "xla": _LADDER_MULS_UNCACHED,
     "pallas": _LADDER_MULS_UNCACHED,
-    "xla8": _LADDER_MULS_UNCACHED - 215,
-    "pallas8": _LADDER_MULS_UNCACHED - 215,
 }
 
 
@@ -444,7 +440,7 @@ def bench_device_floor():
     goes — host packing, dispatch (includes transfer under jit's async
     dispatch), readback sync, and pure device COMPUTE on device-resident
     donated inputs — at realistic commit sizes, for both the uncached
-    kernel and the expanded-pubkey cached path, plus the RLC MSM kernel,
+    kernel and the expanded-pubkey cached path,
     and reports the measured crossover against the native host batch
     verifier. est_vpu_util = static op ledger / measured compute vs the
     documented v5e VPU int32 peak estimate (round-4 verdict task 2).
@@ -453,8 +449,8 @@ def bench_device_floor():
     # compiles to their own column: a first timed rep that "dispatches
     # for 10 s" is one compile silently folded into it.
     # Enabled for the sweep only and ALWAYS restored — a mid-sweep
-    # failure must not leave the later configs (kernel A/B, the
-    # headline) running with per-launch telemetry on.
+    # failure must not leave the later configs (the headline) running
+    # with per-launch telemetry on.
     from cometbft_tpu.libs import devstats as libdevstats
 
     devstats_was_on = libdevstats.enabled()
@@ -468,7 +464,6 @@ def bench_device_floor():
 
 def _bench_device_floor_measured(libdevstats):
     from cometbft_tpu.crypto import host_batch
-    from cometbft_tpu.ops import rlc as orlc
     from cometbft_tpu.ops import verify as ov
 
     rows = []
@@ -554,27 +549,22 @@ def _bench_device_floor_measured(libdevstats):
             if size != n and n <= ov._CHUNK:
                 bufp = np.pad(buf, [(0, 0), (0, size - n)])
             # Time the kernel production would actually pick for this
-            # bucket (auto: the measured-A/B pallas flavor on chip; XLA
-            # otherwise) so compute_ms/utilization describe the real
-            # path — falling back through the remaining candidates to
-            # XLA so one broken pallas flavor can't erase the whole
-            # decomposition this probe exists to capture. The live
-            # path's jit IDENTITY matters too: small buckets launch
-            # their dedicated small-grid jits — probe the exact
-            # (flavor, grid) pair live windows launch, or the n<=256
-            # rows (the crossover's home) would time a kernel the
-            # production path never runs.
+            # bucket (ops/verify's one rule: Pallas on the chip from
+            # one block up, XLA otherwise) so compute_ms/utilization
+            # describe the real path — falling back to XLA so a broken
+            # Pallas can't erase the whole decomposition this probe
+            # exists to capture. The live path's jit IDENTITY matters
+            # too: small buckets launch their dedicated small-grid
+            # jits — probe the exact (program, grid) pair live windows
+            # launch, or the n<=256 rows (the crossover's home) would
+            # time a kernel the production path never runs.
             probe_grid = ov._small_grid(min(size, ov._CHUNK))
-            cands = (
-                ov._pallas_candidates()
-                if ov._pallas_wanted() and size >= ov._PALLAS_MIN_LANES
-                else []
-            )
+            cands = ["pallas", "xla"] if ov._pallas_wanted(size) else ["xla"]
             fn = None
-            for probe_try in [*cands, ov._xla_which()]:
+            for probe_try in cands:
                 try:
                     fn = ov._jitted_kernel(
-                        probe_try, probe_grid
+                        "verify", probe_try, probe_grid
                     )
                     # fresh device buffer per attempt: the kernels jit
                     # with input donation on TPU, so a faulting
@@ -638,22 +628,6 @@ def _bench_device_floor_measured(libdevstats):
         except Exception:
             pass
 
-        # RLC MSM kernel end-to-end (the voi batch equation on device)
-        t_rlc = None
-        try:
-            if _TINY:
-                raise RuntimeError("skip rlc probe in tiny mode")
-            t_r = []
-            ok_r, _bm = orlc.verify_batch_rlc(pubkeys, msgs, sigs)  # warm
-            if ok_r:
-                for _ in range(reps):
-                    t0 = time.perf_counter()
-                    orlc.verify_batch_rlc(pubkeys, msgs, sigs)
-                    t_r.append(time.perf_counter() - t0)
-                t_rlc = min(t_r)
-        except Exception:
-            pass
-
         t0 = time.perf_counter()
         host_batch.verify_many(pubkeys, msgs, sigs)
         t_host = time.perf_counter() - t0
@@ -676,20 +650,13 @@ def _bench_device_floor_measured(libdevstats):
             est_basis = "compute_probe"
         else:
             lanes = ov.bucket_size(n) if n <= ov._CHUNK else n
-            if not (
-                ov._pallas_wanted() and lanes >= ov._PALLAS_MIN_LANES
-            ):
+            if not ov._pallas_wanted(lanes):
                 est_util = _est_vpu_util(
-                    _MULS_UNCACHED_BY_KERNEL[ov._xla_which()],
+                    _MULS_UNCACHED_BY_KERNEL["xla"],
                     lanes,
                     d_unc + r_unc,
                 )
                 est_basis = "dispatch_readback"
-        # PRODUCTION paths only: the rlc lowering is reachable only via
-        # the separate ops/rlc entry, never ov.verify_batch — letting it
-        # win here would derive a HOST_BATCH_THRESHOLD that routes
-        # deployments onto a slower default path. Its time is still
-        # recorded per-row (rlc_total_ms) for the A/B trend.
         dev_total = t_pack + min(candidates)
         rows.append(
             {
@@ -746,7 +713,6 @@ def _bench_device_floor_measured(libdevstats):
                 # (both lowerings of a scheme run the same algorithm).
                 "est_vpu_util_uncached": est_util,
                 "est_vpu_util_basis": est_basis,
-                "rlc_total_ms": round(t_rlc * 1e3, 2) if t_rlc else None,
                 "device_total_ms": round(dev_total * 1e3, 2),
                 "host_rlc_ms": round(t_host * 1e3, 2),
                 "device_wins": bool(dev_total < t_host),
@@ -792,67 +758,6 @@ def _bench_device_floor_measured(libdevstats):
         "window_fixed_cost_ms": fixed,
         "current_HOST_BATCH_THRESHOLD": cbatch.HOST_BATCH_THRESHOLD,
     }
-
-
-def bench_kernel_ab():
-    """One-window lowering A/B: XLA vs 8-bit-window vs Pallas, each on
-    the uncached and cached-arena paths, same batch, same process —
-    the process that holds the chip (a child could not open it).
-    Pallas runs only on accelerator backends (interpret mode on CPU
-    takes minutes per trace). A flavor that fails to lower reports its
-    error in its own column; the others still measure.
-    """
-    import jax
-
-    from cometbft_tpu.ops import verify as ov
-
-    n = _sz(4096, 256)
-    pubkeys, msgs, sigs = _make_ed_batch(n, seed=7)
-    buf, _host_ok = ov.pack_bytes(pubkeys, msgs, sigs)
-    size = ov.bucket_size(n) if n <= ov._CHUNK else n
-    if size != n:
-        buf = np.pad(buf, [(0, 0), (0, size - n)])
-    from cometbft_tpu.libs.accel import ACCELERATOR_BACKENDS
-
-    flavors = ["xla", "xla8"]
-    if jax.default_backend() in ACCELERATOR_BACKENDS:
-        flavors += ["pallas", "pallas8"]
-    out = {"lanes": n}
-    for which in flavors:
-        try:
-            fn = ov._jitted_kernel(which)
-            np.asarray(fn(buf))  # compile + warm
-            dt = _steady(lambda: np.asarray(fn(buf)))
-            out[f"{which}_uncached_sigs_per_sec"] = round(n / dt, 1)
-        except Exception as e:
-            out[f"{which}_uncached_error"] = repr(e)[:160]
-    # RLC MSM lowering through its public entry, same batch
-    try:
-        from cometbft_tpu.ops import rlc as orlc
-
-        ok_r, _ = orlc.verify_batch_rlc(pubkeys, msgs, sigs)  # warm
-        assert ok_r
-        dt = _steady(lambda: orlc.verify_batch_rlc(pubkeys, msgs, sigs))
-        out["rlc_sigs_per_sec"] = round(n / dt, 1)
-    except Exception as e:
-        out["rlc_error"] = repr(e)[:160]
-    hit = ov._PUBKEY_CACHE.lookup(pubkeys)
-    if hit is not None:
-        idxs, arena, arena_ok = hit
-        if size != n:
-            idxs = np.pad(idxs, (0, size - n))
-        rsk = buf[32:]
-        for which in flavors:
-            try:
-                fn = ov._jitted_cached_kernel(which)
-                np.asarray(fn(arena, arena_ok, idxs, rsk))
-                dt = _steady(
-                    lambda: np.asarray(fn(arena, arena_ok, idxs, rsk))
-                )
-                out[f"{which}_cached_sigs_per_sec"] = round(n / dt, 1)
-            except Exception as e:
-                out[f"{which}_cached_error"] = repr(e)[:160]
-    return out
 
 
 def bench_wal_decode():
@@ -3366,7 +3271,6 @@ def main() -> None:
         ("7_mempool", bench_mempool),
         ("8_valset_update", bench_valset_update),
         ("9_device_floor", bench_device_floor),
-        ("10_kernel_ab", bench_kernel_ab),
     ):
         try:
             row = fn()

@@ -19,10 +19,6 @@ _MS = 1_000_000  # ns per ms
 # catalog — adding an env read and documenting it are one change. Keys
 # are knob names, values are one-line operator docs.
 ENV_KNOBS: dict[str, str] = {
-    "COMETBFT_TPU_KERNEL": (
-        "verify-kernel lowering: auto (default) | pallas | pallas8 | "
-        "xla | xla8; pins a flavor for benchmarking (ops/verify.py)"
-    ),
     "COMETBFT_TPU_PUBKEY_CACHE": (
         "expanded-pubkey device arena: 1 (default) | 0 to disable "
         "(ops/verify.py)"

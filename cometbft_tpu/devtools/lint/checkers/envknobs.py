@@ -1,8 +1,8 @@
 """CLNT007 env-knob registry: every ``COMETBFT_*`` environment variable
 read anywhere must be declared in ``config.py``'s ``ENV_KNOBS``.
 
-Undocumented knobs are how the round-5 backend-gate bug happened: a
-``COMETBFT_TPU_KERNEL=pallas`` pin changed dispatch behavior that no
+Undocumented knobs are how the round-5 backend-gate bug happened: an
+environment pin of the verify kernel changed dispatch behavior that no
 config surface admitted existed. The registry is the single catalog an
 operator (and the docs) can trust; reading a knob that isn't in it is a
 lint failure, so adding the env read and documenting it become one

@@ -329,170 +329,6 @@ def verify_kernel_cached(
     return is_identity(acc) & ok_r
 
 
-# ---------------------------------------------------------------- 8-bit
-# fixed-base windows for [S]B (gated prototype: COMETBFT_TPU_KERNEL=xla8).
-#
-# S is the one scalar whose base point is CONSTANT across every lane and
-# every launch, so its window tables can be precomputed per WINDOW rather
-# than per lane: with T_j[v] = [v * 2^(8j)]B in affine-Niels form,
-# [S]B = sum_j T_j[S_j] needs 32 table adds and ZERO doublings — the
-# ladder's doublings remain driven by the per-lane A part alone. vs the
-# joint 4-bit ladder this removes 32 of 64 B-adds (~215 field muls/sig,
-# ~11% of the cached total, docs/tpu-kernel.md ledger).
-#
-# The 256-entry selects are expressed as ONE batched one-hot matmul
-# (32, 60, 256) @ (32, 256, N) so the MXU (systolic array) does the
-# gather work instead of the VPU: a 16-entry select was affordable as a
-# one-hot multiply-reduce, a 256-entry one is not. f32 accumulation is
-# EXACT here: limbs are < 2^13, the one-hot has a single nonzero per
-# column, and Precision.HIGHEST keeps full f32 fidelity through the
-# bf16 decomposition on TPU.
-
-
-def _base_table8_host() -> np.ndarray:
-    """(32, 256, 3, 20) int32: [v * 2^(8j)]B affine-Niels entries.
-
-    One Montgomery batch inversion turns 8192 per-point affine
-    conversions into one modexp; table build is ~0.3 s once per process
-    (and only when the 8-bit path is actually used).
-    """
-
-    def ext_add(p, q):
-        x1, y1, z1, t1 = p
-        x2, y2, z2, t2 = q
-        a = (y1 - x1) * (y2 - x2) % P
-        b = (y1 + x1) * (y2 + x2) % P
-        c = t1 * D2_INT % P * t2 % P
-        d = 2 * z1 * z2 % P
-        e, f, g, h = b - a, d - c, d + c, b + a
-        return (e * f % P, g * h % P, f * g % P, e * h % P)
-
-    pts = []
-    g = BASE_INT
-    for _j in range(32):
-        row = IDENTITY_INT
-        for _v in range(256):
-            pts.append(row)
-            row = ext_add(row, g)
-        for _ in range(8):  # g <- [2^8] g for the next window
-            g = ext_add(g, g)
-
-    # Montgomery batch inversion of all Z coordinates.
-    prefix = [1]
-    for p in pts:
-        prefix.append(prefix[-1] * p[2] % P)
-    inv_acc = pow(prefix[-1], P - 2, P)
-    zinvs = [0] * len(pts)
-    for i in range(len(pts) - 1, -1, -1):
-        zinvs[i] = prefix[i] * inv_acc % P
-        inv_acc = inv_acc * pts[i][2] % P
-
-    out = np.empty((32 * 256, 3, NLIMB), np.int32)
-    for i, (p, zi) in enumerate(zip(pts, zinvs)):
-        xa, ya = p[0] * zi % P, p[1] * zi % P
-        out[i, 0] = field.to_limbs((ya + xa) % P)
-        out[i, 1] = field.to_limbs((ya - xa) % P)
-        out[i, 2] = field.to_limbs(2 * D_INT * xa % P * ya % P)
-    return out.reshape(32, 256, 3, NLIMB)
-
-
-NLIMB = field.NLIMB
-_TABLE8_CACHE: list = []
-
-
-def _base_table8_f32() -> np.ndarray:
-    """(32, 60, 256) float32, transposed for the select matmul."""
-    if not _TABLE8_CACHE:
-        t8 = _base_table8_host().reshape(32, 256, 3 * NLIMB)
-        _TABLE8_CACHE.append(
-            np.ascontiguousarray(t8.transpose(0, 2, 1)).astype(np.float32)
-        )
-    return _TABLE8_CACHE[0]
-
-
-def fixed_base_sum8(s_bytes: jnp.ndarray) -> jnp.ndarray:
-    """[S]B from little-endian S bytes via per-window constant tables.
-
-    s_bytes: (32, *B) int32 in [0, 256). Returns an extended point
-    (4, 20, *B). 32 affine-Niels adds, no doublings; selection rides the
-    MXU as a batched one-hot matmul.
-    """
-    batch = s_bytes.shape[1:]
-    nb = 1
-    for d in batch:
-        nb *= d
-    flat = s_bytes.reshape(32, 1, nb)
-    iota = jnp.arange(256, dtype=jnp.int32).reshape(1, 256, 1)
-    onehot = (flat == iota).astype(jnp.float32)  # (32, 256, NB)
-    tabs = jnp.asarray(_base_table8_f32())  # (32, 60, 256)
-    sel = jax.lax.dot_general(
-        tabs,
-        onehot,
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32,
-    )  # (32, 60, NB)
-    sel = sel.astype(jnp.int32).reshape((32, 3, NLIMB) + batch)
-    acc = broadcast_point(const_point(IDENTITY_INT), batch)
-
-    def body(j, acc):
-        return affine_niels_add(acc, sel[j])
-
-    return jax.lax.fori_loop(0, 32, body, acc)
-
-
-def _ladder_a_only(table_a, kneg_nibs, batch):
-    """The joint ladder minus the B part: [(-k mod L)]A."""
-    ident = broadcast_point(const_point(IDENTITY_INT), batch)
-
-    def body(j, acc):
-        acc = point_double_n(acc, WBITS)
-        return niels_add(acc, _select(table_a, kneg_nibs[j]))
-
-    return jax.lax.fori_loop(0, WINDOWS, body, ident)
-
-
-def verify_kernel8(
-    y_a: jnp.ndarray,
-    sign_a: jnp.ndarray,
-    y_r: jnp.ndarray,
-    sign_r: jnp.ndarray,
-    s_bytes: jnp.ndarray,
-    kneg_nibs: jnp.ndarray,
-) -> jnp.ndarray:
-    """verify_kernel with the [S]B part on 8-bit fixed-base windows."""
-    y2 = jnp.stack([y_a, y_r], axis=1)
-    s2 = jnp.stack([sign_a, sign_r], axis=0)
-    pts, oks = decompress(y2, s2)
-    a_pt, r_pt = pts[:, :, 0], pts[:, :, 1]
-    batch = y_a.shape[1:]
-    table_a = _build_a_table(a_pt)
-    acc = point_add(
-        _ladder_a_only(table_a, kneg_nibs, batch), fixed_base_sum8(s_bytes)
-    )
-    acc = affine_niels_add(acc, to_affine_niels(point_neg(r_pt)))
-    acc = point_double(point_double(point_double(acc)))
-    return is_identity(acc) & oks[0] & oks[1]
-
-
-def verify_kernel8_cached(
-    table_a: jnp.ndarray,
-    y_r: jnp.ndarray,
-    sign_r: jnp.ndarray,
-    s_bytes: jnp.ndarray,
-    kneg_nibs: jnp.ndarray,
-) -> jnp.ndarray:
-    """verify_kernel_cached with 8-bit fixed-base [S]B windows."""
-    batch = y_r.shape[1:]
-    r_pt, ok_r = decompress(y_r, sign_r)
-    acc = point_add(
-        _ladder_a_only(table_a, kneg_nibs, batch), fixed_base_sum8(s_bytes)
-    )
-    acc = affine_niels_add(acc, to_affine_niels(point_neg(r_pt)))
-    acc = point_double(point_double(point_double(acc)))
-    return is_identity(acc) & ok_r
-
-
 def verify_kernel(
     y_a: jnp.ndarray,
     sign_a: jnp.ndarray,

@@ -31,8 +31,6 @@ from __future__ import annotations
 import threading
 from functools import lru_cache
 
-import numpy as np
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -519,329 +517,6 @@ def _verify_block_kernel_cached(
 
     is_id = _is_zero(acc[0]) & _eq(acc[1], acc[2])
     out_ref[:] = (is_id & ok).astype(jnp.int32)
-
-
-def _point_add_full(p, q, batch):
-    """Complete extended + extended addition (9 muls) — used once per
-    block to join the A-ladder result with the fixed-base B sum."""
-    x1, y1, z1, t1 = p
-    x2, y2, z2, t2 = q
-    a = _mul(_sub(y1, x1), _sub(y2, x2))
-    b = _mul(_add(y1, x1), _add(y2, x2))
-    c = _mul(_mul(t1, _TC.d2(batch)), t2)
-    d = _dbl2(_mul(z1, z2))
-    e = _sub(b, a)
-    f = _sub(d, c)
-    g = _add(d, c)
-    h = _add(b, a)
-    return (_mul(e, f), _mul(g, h), _mul(f, g), _mul(e, h))
-
-
-def _fixed_base_sum8_pl(tab8_ref, s_ref, batch):
-    """[S]B from 8-bit windows: 32 MXU one-hot dots + 32 affine adds.
-
-    ``tab8_ref``: (32*64, 256) f32 — per-window constant affine-Niels
-    tables T_j[v] = [v*2^(8j)]B, coordinate rows j*64 + c*20 + limb
-    (rows 60-63 of each window zero-padded: Mosaic requires the dynamic
-    window offset to be provably 8-aligned, and 60 is not), entry axis
-    on lanes so each window's select is one (64, 256) @ (256, B) matmul
-    (exact in f32: limbs < 2^13, one-hot has a single nonzero per
-    column). ``s_ref``: (32, B) S bytes, little-endian — byte j IS the
-    window of weight 2^(8j).
-
-    vs the joint ladder's per-window select_b: the 64 affine B-adds
-    drop to 32 and the select work leaves the VPU entirely
-    (curve.fixed_base_sum8 is the XLA twin; docs/tpu-kernel.md).
-    """
-    one_l = jnp.concatenate(
-        [jnp.ones((1, batch), jnp.int32),
-         jnp.zeros((NLIMB - 1, batch), jnp.int32)],
-        axis=0,
-    )
-    zero_l = jnp.zeros((NLIMB, batch), jnp.int32)
-    ident = (zero_l, one_l, one_l, zero_l)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (256, batch), 0)
-
-    def body(j, acc):
-        sj = s_ref[pl.ds(j, 1), :]  # (1, B)
-        oh = (iota == sj).astype(jnp.float32)  # (256, B)
-        tj = tab8_ref[pl.ds(j * 64, 64), :]  # (64, 256), 8-aligned start
-        sel = jax.lax.dot_general(
-            tj,
-            oh,
-            (((1,), (0,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32,
-        ).astype(jnp.int32)  # (64, B); rows 60+ are the zero padding
-        n3 = (
-            sel[0:NLIMB],
-            sel[NLIMB : 2 * NLIMB],
-            sel[2 * NLIMB : 3 * NLIMB],
-        )
-        return _affine_niels_add(acc, n3)
-
-    return jax.lax.fori_loop(0, 32, body, ident)
-
-
-def _verify_block_kernel8(
-    tab8_ref, y_a_ref, sign_a_ref, y_r_ref, sign_r_ref, s_ref, kneg_ref,
-    out_ref,
-):
-    """verify kernel with the [S]B part on 8-bit fixed-base windows."""
-    _TC.reset()
-    batch = y_a_ref.shape[-1]
-
-    y2 = jnp.concatenate([y_a_ref[:], y_r_ref[:]], axis=-1)
-    s2 = jnp.concatenate([sign_a_ref[:], sign_r_ref[:]], axis=-1)
-    pt2, ok2 = _decompress(y2, s2)
-    a_pt = tuple(c[:, :batch] for c in pt2)
-    r_pt = tuple(c[:, batch:] for c in pt2)
-    ok = ok2[:, :batch] & ok2[:, batch:]
-
-    entries = [a_pt, _point_double(a_pt)]
-    a_niels3 = (
-        _add(a_pt[1], a_pt[0]),
-        _sub(a_pt[1], a_pt[0]),
-        _mul(a_pt[3], _TC.d2(batch)),
-    )
-    for _ in range(2, TSIZE - 1):
-        entries.append(_affine_niels_add(entries[-1], a_niels3))
-    one_l = jnp.concatenate(
-        [jnp.ones((1, batch), jnp.int32),
-         jnp.zeros((NLIMB - 1, batch), jnp.int32)],
-        axis=0,
-    )
-    two_l = jnp.concatenate(
-        [jnp.full((1, batch), 2, jnp.int32),
-         jnp.zeros((NLIMB - 1, batch), jnp.int32)],
-        axis=0,
-    )
-    zero_l = jnp.zeros((NLIMB, batch), jnp.int32)
-    niels_entries = [(one_l, one_l, two_l, zero_l)]
-    for e in entries:
-        x, yv, z, t = e
-        niels_entries.append(
-            (_add(yv, x), _sub(yv, x), _dbl2(z), _mul(t, _TC.d2(batch)))
-        )
-    tab = [
-        jnp.concatenate([niels_entries[k][c] for k in range(TSIZE)], axis=0)
-        for c in range(4)
-    ]
-
-    def select_a(oh):
-        out = []
-        for c in range(4):
-            acc = tab[c][0:NLIMB] * oh[0:1]
-            for k in range(1, TSIZE):
-                acc = acc + tab[c][k * NLIMB : (k + 1) * NLIMB] * oh[k : k + 1]
-            out.append(acc)
-        return tuple(out)
-
-    ident = (zero_l, one_l, one_l, zero_l)
-
-    def body(j, acc):
-        for _ in range(WBITS):
-            acc = _point_double(acc)
-        kn = kneg_ref[pl.ds(j, 1), :]
-        return _niels_add(acc, select_a(_onehot(kn, batch)))
-
-    acc = jax.lax.fori_loop(0, WINDOWS, body, ident)
-    acc = _point_add_full(
-        acc, _fixed_base_sum8_pl(tab8_ref, s_ref, batch), batch
-    )
-
-    rx, ry, _, rt = r_pt
-    nrx = _neg(rx)
-    r_niels = (_add(ry, nrx), _sub(ry, nrx), _mul(_neg(rt), _TC.d2(batch)))
-    acc = _affine_niels_add(acc, r_niels)
-    for _ in range(3):
-        acc = _point_double(acc)
-
-    is_id = _is_zero(acc[0]) & _eq(acc[1], acc[2])
-    out_ref[:] = (is_id & ok).astype(jnp.int32)
-
-
-def _verify_block_kernel8_cached(
-    tab8_ref, tab0_ref, tab1_ref, tab2_ref, tab3_ref, ok_a_ref,
-    y_r_ref, sign_r_ref, s_ref, kneg_ref, out_ref,
-):
-    _TC.reset()
-    batch = y_r_ref.shape[-1]
-
-    r_pt, ok = _decompress(y_r_ref[:], sign_r_ref[:])
-    ok = ok & (ok_a_ref[:] != 0)
-
-    tab = [tab0_ref[:], tab1_ref[:], tab2_ref[:], tab3_ref[:]]
-
-    def select_a(oh):
-        out = []
-        for c in range(4):
-            acc = tab[c][0:NLIMB] * oh[0:1]
-            for k in range(1, TSIZE):
-                acc = acc + tab[c][k * NLIMB : (k + 1) * NLIMB] * oh[k : k + 1]
-            out.append(acc)
-        return tuple(out)
-
-    one_l = jnp.concatenate(
-        [jnp.ones((1, batch), jnp.int32),
-         jnp.zeros((NLIMB - 1, batch), jnp.int32)],
-        axis=0,
-    )
-    zero_l = jnp.zeros((NLIMB, batch), jnp.int32)
-    ident = (zero_l, one_l, one_l, zero_l)
-
-    def body(j, acc):
-        for _ in range(WBITS):
-            acc = _point_double(acc)
-        kn = kneg_ref[pl.ds(j, 1), :]
-        return _niels_add(acc, select_a(_onehot(kn, batch)))
-
-    acc = jax.lax.fori_loop(0, WINDOWS, body, ident)
-    acc = _point_add_full(
-        acc, _fixed_base_sum8_pl(tab8_ref, s_ref, batch), batch
-    )
-
-    rx, ry, _, rt = r_pt
-    nrx = _neg(rx)
-    r_niels = (_add(ry, nrx), _sub(ry, nrx), _mul(_neg(rt), _TC.d2(batch)))
-    acc = _affine_niels_add(acc, r_niels)
-    for _ in range(3):
-        acc = _point_double(acc)
-
-    is_id = _is_zero(acc[0]) & _eq(acc[1], acc[2])
-    out_ref[:] = (is_id & ok).astype(jnp.int32)
-
-
-_TAB8_PL_CACHE: list = []
-
-
-def _tab8_pl() -> np.ndarray:
-    """(32*64, 256) f32 layout of curve's per-window base tables.
-
-    Each window's 60 coordinate rows are padded to a 64-row block so
-    the kernel's dynamic window offset (j*64) is provably 8-aligned
-    (Mosaic rejects j*60)."""
-    if not _TAB8_PL_CACHE:
-        t8 = curve._base_table8_host()  # (32, 256, 3, 20)
-        rows = t8.transpose(0, 2, 3, 1).reshape(32, 60, 256)
-        padded = np.zeros((32, 64, 256), np.float32)
-        padded[:, :60] = rows
-        _TAB8_PL_CACHE.append(
-            np.ascontiguousarray(padded.reshape(32 * 64, 256))
-        )
-    return _TAB8_PL_CACHE[0]
-
-
-@lru_cache(maxsize=None)
-def _compiled8(n: int, block: int, interpret: bool):
-    grid = n // block
-    spec2 = lambda rows: pl.BlockSpec(  # noqa: E731
-        (rows, block), lambda i: (0, i), memory_space=pltpu.VMEM
-    )
-    tab_spec = pl.BlockSpec(
-        (32 * 64, 256), lambda i: (0, 0), memory_space=pltpu.VMEM
-    )
-    call = pl.pallas_call(
-        _verify_block_kernel8,
-        grid=(grid,),
-        in_specs=[
-            tab_spec,
-            spec2(NLIMB),
-            spec2(1),
-            spec2(NLIMB),
-            spec2(1),
-            spec2(32),
-            spec2(WINDOWS),
-        ],
-        out_specs=spec2(1),
-        out_shape=jax.ShapeDtypeStruct((1, n), jnp.int32),
-        interpret=interpret,
-    )
-
-    def fn(y_a, sign_a, y_r, sign_r, s_bytes, kneg_nibs):
-        return call(
-            jnp.asarray(_tab8_pl()),
-            y_a,
-            sign_a.reshape(1, n),
-            y_r,
-            sign_r.reshape(1, n),
-            s_bytes,
-            kneg_nibs,
-        )[0].astype(bool)
-
-    return fn
-
-
-def verify_kernel8(y_a, sign_a, y_r, sign_r, s_bytes, kneg_nibs, *,
-                   interpret=False):
-    """8-bit fixed-base-window Pallas lowering
-    (COMETBFT_TPU_KERNEL=pallas8); same contract as
-    curve.verify_kernel8."""
-    n = y_a.shape[-1]
-    block = _block_for(n)
-    if n % block:
-        raise ValueError(f"batch {n} not a multiple of block {block}")
-    return _compiled8(n, block, interpret)(
-        y_a, sign_a, y_r, sign_r, s_bytes, kneg_nibs
-    )
-
-
-@lru_cache(maxsize=None)
-def _compiled8_cached(n: int, block: int, interpret: bool):
-    grid = n // block
-    spec2 = lambda rows: pl.BlockSpec(  # noqa: E731
-        (rows, block), lambda i: (0, i), memory_space=pltpu.VMEM
-    )
-    tab_spec = pl.BlockSpec(
-        (32 * 64, 256), lambda i: (0, 0), memory_space=pltpu.VMEM
-    )
-    call = pl.pallas_call(
-        _verify_block_kernel8_cached,
-        grid=(grid,),
-        in_specs=[
-            tab_spec,
-            spec2(TSIZE * NLIMB),
-            spec2(TSIZE * NLIMB),
-            spec2(TSIZE * NLIMB),
-            spec2(TSIZE * NLIMB),
-            spec2(1),
-            spec2(NLIMB),
-            spec2(1),
-            spec2(32),
-            spec2(WINDOWS),
-        ],
-        out_specs=spec2(1),
-        out_shape=jax.ShapeDtypeStruct((1, n), jnp.int32),
-        interpret=interpret,
-    )
-
-    def fn(table, ok_a, y_r, sign_r, s_bytes, kneg_nibs):
-        planes = [
-            table[:, c].reshape(TSIZE * NLIMB, n) for c in range(4)
-        ]
-        return call(
-            jnp.asarray(_tab8_pl()),
-            *planes,
-            ok_a.astype(jnp.int32).reshape(1, n),
-            y_r,
-            sign_r.reshape(1, n),
-            s_bytes,
-            kneg_nibs,
-        )[0].astype(bool)
-
-    return fn
-
-
-def verify_kernel8_cached(table, ok_a, y_r, sign_r, s_bytes, kneg_nibs, *,
-                          interpret=False):
-    """Cached-table 8-bit-window Pallas lowering."""
-    n = y_r.shape[-1]
-    block = _block_for(n)
-    if n % block:
-        raise ValueError(f"batch {n} not a multiple of block {block}")
-    return _compiled8_cached(n, block, interpret)(
-        table, ok_a, y_r, sign_r, s_bytes, kneg_nibs
-    )
 
 
 @lru_cache(maxsize=None)

@@ -25,6 +25,7 @@ from __future__ import annotations
 import os
 import time
 
+from ..libs import accel as libaccel
 from ..libs import devstats as libdevstats
 from ..libs.accel import ACCELERATOR_BACKENDS
 from ..libs import metrics as libmetrics
@@ -347,26 +348,6 @@ def _kernel_from_bytes(buf):
     return _pack_ok_bits(curve.verify_kernel(**unpack_on_device(buf)))
 
 
-def _kernel_from_bytes8(buf):
-    """8-bit fixed-base-window lowering (COMETBFT_TPU_KERNEL=xla8).
-
-    S rides as raw little-endian bytes: byte j IS the 8-bit window of
-    weight 2^(8j), so the wire format needs no new rows."""
-    import jax.numpy as jnp
-
-    b = buf.astype(jnp.int32)
-    pk_bits = _dev_le_bits(b[0:32])
-    rr_bits = _dev_le_bits(b[32:64])
-    return _pack_ok_bits(curve.verify_kernel8(
-        y_a=_dev_y_limbs(pk_bits),
-        sign_a=pk_bits[255],
-        y_r=_dev_y_limbs(rr_bits),
-        sign_r=rr_bits[255],
-        s_bytes=b[64:96],
-        kneg_nibs=_dev_msb_nibbles(b[96:128]),
-    ))
-
-
 # ------------------------------------------------------------------ cache
 # HBM-resident expanded-pubkey cache. The reference keeps a 4096-entry
 # LRU of expanded pubkeys because validators recur every round
@@ -398,22 +379,6 @@ def _cached_kernel(arena, arena_ok, idxs, buf):
     return _pack_ok_bits(ok & arena_ok[idxs])
 
 
-def _cached_kernel8(arena, arena_ok, idxs, buf):
-    import jax.numpy as jnp
-
-    b = buf.astype(jnp.int32)
-    rr_bits = _dev_le_bits(b[0:32])
-    table = arena[:, :, :, idxs]
-    ok = curve.verify_kernel8_cached(
-        table,
-        y_r=_dev_y_limbs(rr_bits),
-        sign_r=rr_bits[255],
-        s_bytes=b[32:64],
-        kneg_nibs=_dev_msb_nibbles(b[64:96]),
-    )
-    return _pack_ok_bits(ok & arena_ok[idxs])
-
-
 # The routed Pallas launches compile for the chip (Mosaic). Interpret
 # mode exists for tests and chip_smoke.py's CPU dry run, which set this
 # before the first trace; nothing infers it from the backend.
@@ -427,25 +392,6 @@ def _cached_kernel_pallas(arena, arena_ok, idxs, buf):
     table = arena[:, :, :, idxs]
     return _pack_ok_bits(pallas_verify.verify_kernel_cached(
         table, arena_ok[idxs], **arrays, interpret=_PALLAS_INTERPRET
-    ))
-
-
-def _cached_kernel_pallas8(arena, arena_ok, idxs, buf):
-    import jax.numpy as jnp
-
-    from . import pallas_verify
-
-    b = buf.astype(jnp.int32)
-    rr_bits = _dev_le_bits(b[0:32])
-    table = arena[:, :, :, idxs]
-    return _pack_ok_bits(pallas_verify.verify_kernel8_cached(
-        table,
-        arena_ok[idxs],
-        y_r=_dev_y_limbs(rr_bits),
-        sign_r=rr_bits[255],
-        s_bytes=b[32:64],
-        kneg_nibs=_dev_msb_nibbles(b[64:96]),
-        interpret=_PALLAS_INTERPRET,
     ))
 
 
@@ -498,12 +444,13 @@ def _served(kernel: str) -> None:
 
 
 def dispatch_counters() -> dict:
-    """Launches served per kernel, absorbed faults per kind, and the
-    Pallas flavors retired in this process."""
+    """Launches served per kernel, absorbed faults per kind, and
+    whether Pallas has been retired in this process (``["pallas"]``
+    after a fault, else empty)."""
     return {
         "launches": dict(_LAUNCHES),
         "faults": dict(_FAULTS),
-        "pallas_broken": sorted(_PALLAS_BROKEN),
+        "pallas_broken": ["pallas"] if _PALLAS_BROKEN else [],
     }
 
 
@@ -528,7 +475,7 @@ def _cached_jits():
     # and dispatch against it after the update; donation would invalidate
     # that buffer under it. Updates are rare (new validator keys), the
     # ~21 MB copy is cheap. (The verify-side jits live in
-    # _jitted_cached_kernel, keyed by lowering.)
+    # _jitted_kernel.)
     # devstats.track wraps each jit for compile accounting (axis = the
     # positional arg whose last dim is the lane bucket): every XLA
     # compile lands in xla_compile_total{kernel,bucket} and the
@@ -539,62 +486,6 @@ def _cached_jits():
             "arena.scatter", jax.jit(_scatter_kernel), axis=3
         ),
     )
-
-
-@lru_cache(maxsize=None)
-def _jitted_cached_kernel(which: str, grid=None):
-    """The cached-table jit for one (flavor, grid) pair.
-
-    ``grid`` pins a dedicated small-bucket jit (see _SMALL_GRID_MAX):
-    its own executable cache and its own devstats kernel name, so
-    small-window compiles and launches are attributable per bucket.
-    """
-    _enable_compilation_cache()
-    flavors = {
-        "pallas": _cached_kernel_pallas,
-        "pallas8": _cached_kernel_pallas8,
-        "xla8": _cached_kernel8,
-    }
-    fn = flavors.get(which, _cached_kernel)
-    label = which if which in flavors else "xla"
-    if grid is not None:
-        label = f"{label}.g{grid}"
-    # donate the per-launch R|S|kneg wire rows (arg 3) — NEVER the arena
-    return libdevstats.track(
-        "verify_cached." + label,
-        jax.jit(fn, donate_argnums=_donatable((3,))),
-        axis=3,
-    )
-
-
-def _run_cached_kernel(arena, arena_ok, idxs, buf):
-    """Cached-table launch with the same Pallas/XLA selection and Mosaic
-    fallback discipline as :func:`_run_kernel`. Wire rows and slot
-    indices are launched from host memory, the rows donated: every
-    launch owns its inputs, so any number of threads may launch one
-    shape at once, with one jit dispatch each (a staging step before
-    the launch costs the coalescer's executor two more waits for the
-    GIL and the chip showed no gain from it: PERF.md section 6, PR 26).
-    Small buckets launch their dedicated small-grid jits."""
-    grid = _small_grid(buf.shape[1])
-    if buf.shape[1] >= _PALLAS_MIN_LANES and _pallas_wanted():
-        for which in _pallas_candidates():
-            kernel = _jitted_cached_kernel(which, grid)
-            try:
-                out = kernel(arena, arena_ok, idxs, buf)
-            except Exception as e:
-                _note_pallas_broken(which, e)
-            else:
-                # the arena stays HBM-resident; only the wire rows and
-                # the slot indices cross the host->device edge
-                libdevstats.record_h2d(buf.nbytes + idxs.nbytes)
-                _served(kernel.kernel)
-                return out, which
-    kernel = _jitted_cached_kernel(_xla_which(), grid)
-    out = kernel(arena, arena_ok, idxs, buf)
-    libdevstats.record_h2d(buf.nbytes + idxs.nbytes)
-    _served(kernel.kernel)
-    return out, None
 
 
 def _builder_bucket(m: int) -> int:
@@ -809,25 +700,6 @@ def _kernel_from_bytes_pallas(buf):
     ))
 
 
-def _kernel_from_bytes_pallas8(buf):
-    import jax.numpy as jnp
-
-    from . import pallas_verify
-
-    b = buf.astype(jnp.int32)
-    pk_bits = _dev_le_bits(b[0:32])
-    rr_bits = _dev_le_bits(b[32:64])
-    return _pack_ok_bits(pallas_verify.verify_kernel8(
-        y_a=_dev_y_limbs(pk_bits),
-        sign_a=pk_bits[255],
-        y_r=_dev_y_limbs(rr_bits),
-        sign_r=rr_bits[255],
-        s_bytes=b[64:96],
-        kneg_nibs=_dev_msb_nibbles(b[96:128]),
-        interpret=_PALLAS_INTERPRET,
-    ))
-
-
 _CHECKOUT_CACHE_DIR = os.path.join(
     os.path.dirname(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -857,113 +729,74 @@ def _enable_compilation_cache() -> str:
     return cache_dir
 
 
-@lru_cache(maxsize=None)
-def _jitted_kernel(which: str = "xla", grid=None):
-    """The uncached-path jit for one (flavor, grid) pair — same contract
-    as :func:`_jitted_cached_kernel`: the wire buffer is donated,
-    ``grid`` pins a dedicated small-bucket jit with its own devstats
-    identity."""
-    _enable_compilation_cache()
-    flavors = {
+# The two routes of a verify launch, each with its two programs. A
+# route's wire arguments (host arrays, the rows last) follow whatever
+# stays resident on the device; ``rows_arg`` is the rows' position: the
+# argument whose last dimension is the lane bucket, and the one
+# donated — NEVER the arena.
+_ROUTES = {
+    # (128, N) wire rows A|R|S|kneg
+    "verify": (0, {
+        "xla": _kernel_from_bytes,
         "pallas": _kernel_from_bytes_pallas,
-        "pallas8": _kernel_from_bytes_pallas8,
-        "xla8": _kernel_from_bytes8,
-    }
-    fn = flavors.get(which, _kernel_from_bytes)
-    label = which if which in flavors else "xla"
-    if grid is not None:
-        label = f"{label}.g{grid}"
+    }),
+    # arena, arena_ok resident; (N,) slot indices and (96, N) R|S|kneg
+    "verify_cached": (3, {
+        "xla": _cached_kernel,
+        "pallas": _cached_kernel_pallas,
+    }),
+}
+
+
+@lru_cache(maxsize=None)
+def _jitted_kernel(route: str, which: str, grid=None):
+    """The jit of one (route, program, grid) triple, tracked by
+    devstats as ``<route>.<program>[.g<grid>]``.
+
+    ``grid`` pins a dedicated small-bucket jit (see _SMALL_GRID_MAX):
+    its own executable cache and its own devstats kernel name, so
+    small-window compiles and launches are attributable per bucket.
+    """
+    _enable_compilation_cache()
+    rows_arg, programs = _ROUTES[route]
+    label = which if grid is None else f"{which}.g{grid}"
     return libdevstats.track(
-        "verify." + label,
-        jax.jit(fn, donate_argnums=_donatable((0,))),
-        axis=0,
+        f"{route}.{label}",
+        jax.jit(programs[which], donate_argnums=_donatable((rows_arg,))),
+        axis=rows_arg,
     )
 
 
-# Kernel selection: "auto" routes single-chip batches through the Pallas
-# kernel on TPU backends (VMEM-resident ladder, ~2x the XLA lowering) and
-# the XLA kernel elsewhere (CPU tests, virtual-device meshes — Pallas
-# interpret mode is far slower than the XLA program there). Overridable
-# for benchmarking via COMETBFT_TPU_KERNEL=pallas|xla|xla8 ("xla8" is
-# the 8-bit fixed-base-window prototype: MXU one-hot selects, -11%
-# field muls — see curve.fixed_base_sum8).
-_KERNEL_MODE = None
-_PALLAS_BROKEN: set = set()  # flavors that faulted in this process
-
-
-def _kernel_mode() -> str:
-    global _KERNEL_MODE
-    if _KERNEL_MODE is None:
-        _KERNEL_MODE = os.environ.get("COMETBFT_TPU_KERNEL", "auto")
-    return _KERNEL_MODE
-
-
-def _xla_which() -> str:
-    """The non-Pallas lowering to use: the gated 8-bit prototype or the
-    default joint 4-bit ladder. pallas8 falls back to xla8 (same window
-    scheme) when Mosaic balks."""
-    return "xla8" if _kernel_mode() in ("xla8", "pallas8") else "xla"
-
-
-def _pallas_candidates() -> list[str]:
-    """Pallas flavors to try, best first, faulted flavors excluded.
-
-    Explicit COMETBFT_TPU_KERNEL=pallas|pallas8 pins a single flavor
-    (benchmarking wants THAT kernel, its XLA twin is the only
-    fallback); auto tries ``pallas`` first, then the sibling. No
-    recorded measurement steers the order: a benchmark cell that shows
-    ``pallas8`` winning changes this line, not a file read at import."""
-    mode = _kernel_mode()
-    order = [mode] if mode in ("pallas", "pallas8") else ["pallas", "pallas8"]
-    return [f for f in order if f not in _PALLAS_BROKEN]
-
-
-def _pallas_wanted() -> bool:
-    mode = _kernel_mode()
-    if mode in ("pallas", "pallas8"):
-        return True
-    if mode in ("xla", "xla8"):
-        return False
-    return jax.default_backend() in ACCELERATOR_BACKENDS
-
-
-# Buckets below this stay on the XLA kernel even when Pallas is wanted:
+# Which program a launch runs is decided here and nowhere else: a bucket
+# of at least _PALLAS_MIN_LANES on an accelerator backend launches the
+# Pallas program (VMEM-resident ladder) unless Pallas has faulted in
+# this process; everything else launches the XLA program, which tier-1
+# holds to the oracle on every run (CPU tests, virtual-device meshes —
+# interpret-mode Pallas is far slower than the XLA program there — and
+# small buckets, on their dedicated small-grid jits).
+#
+# Below _PALLAS_MIN_LANES the XLA program serves even on the chip:
 # small-lane Mosaic layouts compile pathologically slowly and the launch
-# is latency-bound there anyway (the host path owns batches < 768).
+# is latency-bound there anyway. (Whether a batch reaches the device at
+# all is crypto/batch's question: its static cut is 96 lanes with an
+# accelerator, 768 on a CPU backend.) The floor is one Pallas block,
+# pinned to pallas_verify._BLOCK by tests/test_sr25519_secp.py.
 _PALLAS_MIN_LANES = 512
+_PALLAS_BROKEN = False  # a Pallas launch has faulted in this process
 
 
-def _note_pallas_broken(which: str, e: Exception) -> None:
-    _PALLAS_BROKEN.add(which)
-    _note_fault("pallas", e, flavor=which)
+def _pallas_wanted(lanes: int) -> bool:
+    return (
+        lanes >= _PALLAS_MIN_LANES
+        and not _PALLAS_BROKEN
+        and libaccel.accelerator_backend()
+    )
 
 
-def _run_kernel(buf):
-    """Dispatch one bucket launch, falling back through the remaining
-    pallas flavor and then XLA if Mosaic balks.
-
-    Returns (device_array, flavor-or-None). jit dispatch is
-    asynchronous, so a Mosaic *runtime* fault only surfaces when the
-    result materializes — callers resolve through :func:`_materialize`,
-    which marks the flavor broken and re-dispatches.
-    """
-    grid = _small_grid(buf.shape[1])
-    if buf.shape[1] >= _PALLAS_MIN_LANES and _pallas_wanted():
-        for which in _pallas_candidates():
-            kernel = _jitted_kernel(which, grid)
-            try:
-                out = kernel(buf)
-            except Exception as e:  # synchronous trace/compile failure
-                _note_pallas_broken(which, e)
-            else:
-                libdevstats.record_h2d(buf.nbytes)
-                _served(kernel.kernel)
-                return out, which
-    kernel = _jitted_kernel(_xla_which(), grid)
-    out = kernel(buf)
-    libdevstats.record_h2d(buf.nbytes)
-    _served(kernel.kernel)
-    return out, None
+def _note_pallas_fault(e: Exception) -> None:
+    global _PALLAS_BROKEN
+    _PALLAS_BROKEN = True
+    _note_fault("pallas", e)
 
 
 def _start_readback(out) -> None:
@@ -974,6 +807,45 @@ def _start_readback(out) -> None:
     with two light clients under one GIL that read 3% of sigs_per_s
     (PERF.md, PR 24)."""
     out.copy_to_host_async()
+
+
+def _launch(route: str, resident: tuple, wire: tuple):
+    """Launch one bucket of ``route`` without blocking: the Pallas
+    program where :func:`_pallas_wanted`, falling to the XLA program if
+    Mosaic balks at trace or compile time. An XLA fault propagates.
+
+    ``resident`` are the device-resident arguments (the arena), ``wire``
+    the host arrays this launch ships, rows last. They are launched from
+    host memory, the rows donated: every launch owns its inputs, so any
+    number of threads may launch one shape at once, with one jit
+    dispatch each (a staging step before the launch costs the
+    coalescer's executor two more waits for the GIL and the chip showed
+    no gain from it: PERF.md section 6, PR 26). The caller keeps
+    ``wire``: a donated device copy is gone, the host arrays are what a
+    retry launches again.
+
+    Returns (device ok-mask words, program). jit dispatch is
+    asynchronous, so a Mosaic *runtime* fault only surfaces when the
+    result materializes: resolve through :func:`_materialize`.
+    """
+    lanes = wire[-1].shape[1]
+    grid = _small_grid(lanes)
+    which = "pallas" if _pallas_wanted(lanes) else "xla"
+    while True:
+        kernel = _jitted_kernel(route, which, grid)
+        try:
+            out = kernel(*resident, *wire)
+        except Exception as e:
+            if which == "xla":
+                raise
+            _note_pallas_fault(e)
+            which = "xla"
+        else:
+            # only the wire arguments cross the host->device edge
+            libdevstats.record_h2d(sum(a.nbytes for a in wire))
+            _served(kernel.kernel)
+            _start_readback(out)
+            return out, which
 
 
 def _kernel_wait(out, backend: str, lanes: int) -> None:
@@ -993,42 +865,51 @@ def _kernel_wait(out, backend: str, lanes: int) -> None:
         out.block_until_ready()
 
 
-def _materialize(out, used_pallas, buf, backend: str, lanes: int):
-    """np.asarray(out) with device-side pallas faults rerouted: the
-    faulting flavor is retired and the launch retried through
-    :func:`_run_kernel` (sibling flavor, then XLA). Bounded — each
-    retry removes a flavor; the XLA launch (used_pallas None) raises.
+def _launch_async(route: str, resident: tuple, wire: tuple, n: int,
+                  backend: str):
+    """:func:`_launch` now; the returned closure is
+    :func:`_materialize` on that launch, the (n,) validity bitmap."""
+    out, which = _launch(route, resident, wire)
+    return lambda: _materialize(
+        out, which, route, resident, wire, n, backend
+    )
+
+
+def _materialize(out, which: str, route: str, resident: tuple,
+                 wire: tuple, n: int, backend: str) -> np.ndarray:
+    """The launch's first ``n`` verdicts on the host, with device-side
+    Pallas faults rerouted: Pallas is retired and the launch repeated
+    through :func:`_launch`, which then serves it by XLA, from the host
+    arrays the caller kept. Bounded: an XLA fault raises.
 
     The wire value is the bit-packed ok mask (:func:`_pack_ok_bits` —
-    bucket/8 uint8 words, what record_d2h counts); the return value is
-    the unpacked (bucket,) bool bitmap callers slice."""
-    try:
-        _kernel_wait(out, backend, lanes)
-        # cometlint: disable=CLNT002 -- THE sanctioned per-launch readback:
-        # every async dispatch materializes exactly once, here
-        arr = np.asarray(out)
-    except Exception as e:
-        if used_pallas is None:
-            raise
-        _note_pallas_broken(used_pallas, e)
-        out2, which2 = _run_kernel(buf)
-        return _materialize(out2, which2, buf, backend, lanes)
-    libdevstats.record_d2h(arr.nbytes)
-    return unpack_ok_bits(arr, 8 * arr.shape[0])
+    bucket/8 uint8 words, what record_d2h counts), unpacked here to
+    per-lane bools."""
+    while True:
+        try:
+            _kernel_wait(out, backend, n)
+            # cometlint: disable=CLNT002 -- THE sanctioned per-launch
+            # readback: every async dispatch materializes exactly once,
+            # here
+            arr = np.asarray(out)
+        except Exception as e:
+            if which == "xla":
+                raise
+            _note_pallas_fault(e)
+            out, which = _launch(route, resident, wire)
+        else:
+            libdevstats.record_d2h(arr.nbytes)
+            return unpack_ok_bits(arr, 8 * arr.shape[0])[:n]
 
 
-# Measured on a v5e (round 5, Pallas kernel): the launch has a ~40-50 ms
-# floor nearly independent of lane count up to 4096, then scales gently —
-# 4096 lanes 40 ms, 8192 66 ms, 16384 120 ms (137k sigs/s). Chunking at
-# 2048 therefore DOUBLED 4096-lane cost (two floor payments); one big
-# launch wins everywhere measured. Batches past _CHUNK still split so a
-# single dispatch stays bounded (compile shape, VMEM head-room).
+# One launch per batch up to _CHUNK lanes: on this chip a window of
+# buckets 64-1024 reads 4.5-7.8 ms and bucket 8192 reads 17.2 ms
+# (PERF.md sections 5-6, ledger), so the launch's fixed cost is paid
+# once and splitting finer only pays it again. Batches past _CHUNK
+# still split, so a single dispatch stays bounded (compile shape, VMEM
+# head-room); verify_batch packs and dispatches at the same grain, so
+# the host packing of chunk i+1 overlaps the kernel of chunk i.
 _CHUNK = 16384
-
-# verify_batch pipelines pack->dispatch at this granularity. Device time
-# dominates host packing ~10:1, so the pipeline grain equals _CHUNK:
-# splitting finer pays the launch floor again without hiding anything.
-_PIPE_CHUNK = 16384
 
 
 def verify_bytes_async(buf: np.ndarray, n: int, backend: str = _BACKEND):
@@ -1040,29 +921,19 @@ def verify_bytes_async(buf: np.ndarray, n: int, backend: str = _BACKEND):
     Batches beyond the per-launch sweet spot are auto-chunked and
     pipelined. ``backend`` labels the closure's kernel-wait phase.
     """
-    if n > _CHUNK:
-        outs = []
-        for lo in range(0, n, _CHUNK):
-            hi = min(lo + _CHUNK, n)
-            piece = buf[:, lo:hi]
-            # The tail chunk pads to its own pow-2 bucket, not a full
-            # _CHUNK: a 64-lane remainder costs the ~40 ms launch floor
-            # instead of a full 16384-lane launch (~120 ms).
-            size = bucket_size(hi - lo)
-            if hi - lo < size:
-                piece = np.pad(piece, [(0, 0), (0, size - (hi - lo))])
-            out, used_pallas = _run_kernel(piece)
-            _start_readback(out)
-            outs.append((out, used_pallas, piece, hi - lo))
-        return lambda: np.concatenate(
-            [_materialize(o, up, p, backend, m)[:m] for o, up, p, m in outs]
-        )
-    size = bucket_size(n)
-    if size != n:
-        buf = np.pad(buf, [(0, 0), (0, size - n)])
-    out, used_pallas = _run_kernel(buf)
-    _start_readback(out)
-    return lambda: _materialize(out, used_pallas, buf, backend, n)[:n]
+    finals = []
+    for lo in range(0, max(n, 1), _CHUNK):
+        m = min(_CHUNK, n - lo)
+        piece = buf[:, lo:lo + m]
+        # each chunk pads to its own bucket: a 64-lane remainder
+        # launches 64 lanes, not a full _CHUNK
+        size = bucket_size(m)
+        if m < size:
+            piece = np.pad(piece, [(0, 0), (0, size - m)])
+        finals.append(_launch_async("verify", (), (piece,), m, backend))
+    if len(finals) == 1:
+        return finals[0]
+    return lambda: np.concatenate([f() for f in finals])
 
 
 def _cache_enabled() -> bool:
@@ -1141,31 +1012,9 @@ def verify_rsk_async(buf: np.ndarray, idxs: np.ndarray, arena, arena_ok,
     if size != n:
         buf = np.pad(buf, [(0, 0), (0, size - n)])
         idxs = np.pad(idxs, (0, size - n))  # slot 0 gather: harmless
-    out, used_pallas = _run_cached_kernel(arena, arena_ok, idxs, buf)
-    _start_readback(out)
-
-    def materialize():
-        o, which = out, used_pallas
-        while True:
-            try:
-                _kernel_wait(o, backend, n)
-                # cometlint: disable=CLNT002 -- sanctioned readback of the
-                # cached-table launch (the _materialize analog)
-                arr = np.asarray(o)
-            except Exception as e:
-                if which is None:
-                    raise
-                # retire the faulting flavor; _run_cached_kernel then
-                # tries the sibling, bottoming out at XLA (which=None)
-                _note_pallas_broken(which, e)
-                o, which = _run_cached_kernel(arena, arena_ok, idxs, buf)
-            else:
-                # arr is the bit-packed ok mask — bucket/8 uint8 words
-                # on the wire, unpacked to per-lane bools here
-                libdevstats.record_d2h(arr.nbytes)
-                return unpack_ok_bits(arr, 8 * arr.shape[0])[:n]
-
-    return materialize
+    return _launch_async(
+        "verify_cached", (arena, arena_ok), (idxs, buf), n, backend
+    )
 
 
 def verify_prepacked(buf: np.ndarray, keys, n: int, backend: str):
@@ -1291,9 +1140,8 @@ def verify_batch(pubkeys, msgs, sigs) -> tuple[bool, np.ndarray]:
     # into ONE crypto_verify_phase_seconds observation per batch, so the
     # three phases tile the crypto_verify_batch_seconds interval.
     pack_ns = disp_ns = read_ns = 0
-    step = min(_PIPE_CHUNK, _CHUNK)
-    for lo in range(0, n, step):
-        hi = min(lo + step, n)
+    for lo in range(0, n, _CHUNK):
+        hi = min(lo + _CHUNK, n)
         # Pipeline host packing with device execution: each chunk is
         # dispatched as soon as it is packed, so the per-lane SHA-512 /
         # packing cost of chunk i+1 overlaps chunk i's kernel time.

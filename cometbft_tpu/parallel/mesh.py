@@ -132,33 +132,18 @@ def _sharded_verify_pallas(mesh: Mesh):
 
 
 def _dispatch_sharded(mesh: Mesh, args, lanes_per_shard: int):
-    """Pallas-per-shard on accelerator backends, the portable XLA
-    program otherwise (CPU virtual meshes: interpret mode is far too
-    slow). Returns MATERIALIZED (ok, verdict) ndarrays: jit dispatch is
-    asynchronous, so a Mosaic runtime fault only surfaces at
-    np.asarray — materializing inside the try is what lets it retire
-    the path and fall back (the multi-chip analog of
-    ops/verify._materialize).
-
-    Knob semantics here: COMETBFT_TPU_KERNEL=xla|xla8 disables the
-    pallas branch (via _pallas_wanted); a pallas/pallas8 pin or auto
-    runs the 4-bit pallas LADDER per shard — the 8-bit-window kernels
-    take a different wire layout (s_bytes) than pack_inputs ships
-    (s_nibs), so flavor pins to them apply to the single-chip path
-    only. The backend gate is explicit: an off-accelerator pallas pin
-    must route to XLA, not attempt a Mosaic compile that retires the
-    path."""
+    """Pallas-per-shard where ops/verify's one rule wants Pallas for a
+    shard's lanes (an accelerator backend, a full block, no Pallas
+    fault in this process), the portable XLA program otherwise (CPU
+    virtual meshes: interpret mode is far too slow). Returns
+    MATERIALIZED (ok, verdict) ndarrays: jit dispatch is asynchronous,
+    so a Mosaic runtime fault only surfaces at np.asarray —
+    materializing inside the try is what lets it retire the path and
+    fall back (the multi-chip analog of ops/verify._materialize)."""
     global _SHARDED_PALLAS_BROKEN
     from ..ops import verify as ov
 
-    from ..libs.accel import ACCELERATOR_BACKENDS
-
-    if (
-        jax.default_backend() in ACCELERATOR_BACKENDS
-        and lanes_per_shard >= ov._PALLAS_MIN_LANES
-        and ov._pallas_wanted()
-        and not _SHARDED_PALLAS_BROKEN
-    ):
+    if ov._pallas_wanted(lanes_per_shard) and not _SHARDED_PALLAS_BROKEN:
         try:
             ok, verdict = _sharded_verify_pallas(mesh)(*args)
             # cometlint: disable=CLNT002 -- sanctioned sharded readback:

@@ -163,12 +163,12 @@ def test_verify_zip215_small_order():
 
 
 def test_verify_batch_pipelined_chunks():
-    """verify_batch's pipelined pack->dispatch path (n > _PIPE_CHUNK)
+    """verify_batch's pipelined pack->dispatch path (n > _CHUNK)
     maps lanes to the right outputs across chunk boundaries."""
     from cometbft_tpu.ops import verify as ov
 
-    old = ov._PIPE_CHUNK
-    ov._PIPE_CHUNK = 8
+    old = ov._CHUNK
+    ov._CHUNK = 8
     try:
         pks, msgs, sigs = make_batch(20)  # 3 chunks: 8 + 8 + 4
         bad = {3, 9, 17}  # one per chunk
@@ -178,7 +178,7 @@ def test_verify_batch_pipelined_chunks():
         assert not ok
         assert [bool(m) for m in mask] == [i not in bad for i in range(20)]
     finally:
-        ov._PIPE_CHUNK = old
+        ov._CHUNK = old
 
 
 def test_verify_agrees_with_oracle_fuzz():

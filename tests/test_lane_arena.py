@@ -244,20 +244,24 @@ class TestSmallGridSplit:
         calls: list[tuple] = []
         real = ov._jitted_kernel
 
-        def spy(which="xla", grid=None):
-            calls.append((which, grid))
-            return real(which, grid)
+        def spy(route, which, grid=None):
+            calls.append((route, which, grid))
+            return real(route, which, grid)
 
         monkeypatch.setattr(ov, "_jitted_kernel", spy)
         pks, msgs, sigs = _lanes(4, seed=8)
         buf, host_ok = ov.pack_bytes(pks, msgs, sigs)
         bits = ov.verify_bytes_async(buf, 4)()
         assert (bits & host_ok).all()
-        assert calls and calls[-1][1] == 8, calls
+        assert calls == [("verify", "xla", 8)], calls
         # the dedicated jit carries its own devstats kernel identity,
         # so small-window compiles/launches attribute per bucket
-        assert real("xla", 8).kernel == "verify.xla.g8"
-        assert real("xla", None).kernel == "verify.xla"
+        assert real("verify", "xla", 8).kernel == "verify.xla.g8"
+        assert real("verify", "xla", None).kernel == "verify.xla"
+        assert (
+            real("verify_cached", "pallas", None).kernel
+            == "verify_cached.pallas"
+        )
 
 
 

@@ -314,19 +314,19 @@ def test_failed_header_closes_every_span(
             "light.verify_header", "light.fetch"}
 
 
-@pytest.mark.parametrize("pipe_chunk, n, chunks", [
-    (ov._PIPE_CHUNK, 20, 1),
+@pytest.mark.parametrize("chunk, n, chunks", [
+    (ov._CHUNK, 20, 1),
     (8, 20, 3),
 ])
 def test_dispatch_lanes_add_up_once(
-    device_route, tracer, metrics, monkeypatch, pipe_chunk, n, chunks
+    device_route, tracer, metrics, monkeypatch, chunk, n, chunks
 ):
     """What verify_roofline_pct.* divides by: the lanes of a batch's
     verify.dispatch records are the batch's lanes, exactly once, and the
     histogram still gets one observation per batch."""
     from cometbft_tpu.crypto.keys import Ed25519PrivKey
 
-    monkeypatch.setattr(ov, "_PIPE_CHUNK", pipe_chunk)
+    monkeypatch.setattr(ov, "_CHUNK", chunk)
     pvs = [Ed25519PrivKey.from_seed(bytes([7, i]) * 16) for i in range(n)]
     msgs = [b"lanes-%d" % i for i in range(n)]
     sigs = [pv.sign(m) for pv, m in zip(pvs, msgs)]

@@ -1696,13 +1696,30 @@ class TestConsensusTraceBurst:
         # pin single-device dispatch (the sharded route merges phases).
         monkeypatch.setattr(cbatch, "HOST_BATCH_THRESHOLD", 2)
         monkeypatch.setenv("COMETBFT_TPU_SHARD", "0")
+        genesis, pvs = helpers.make_genesis(4)
+        # Compile what the burst launches (the 8-lane cached kernel,
+        # the arena builder and scatter) BEFORE the nodes start, on
+        # lanes that are not the validators' (the burst still builds
+        # their tables itself). Cold, those compiles ran inside the
+        # first round on the FSM's thread, and with six xdist workers
+        # sharing the host they alone outlasted the 120 s the burst is
+        # given to reach height 2 (tier-1, PR 27 and the take-up run).
+        from cometbft_tpu.crypto.keys import Ed25519PrivKey
+        from cometbft_tpu.ops import verify as ov
+
+        warm = [Ed25519PrivKey.from_seed(bytes([9, i]) * 16) for i in range(4)]
+        ok, _bits = ov.verify_batch(
+            [pv.pub_key().data for pv in warm],
+            [b"warm"] * 4,
+            [pv.sign(b"warm") for pv in warm],
+        )
+        assert ok
         m = NodeMetrics()
         libmetrics.push_node_metrics(m)
         libtrace.reset()
         # a burst-sized ring: the phase/total tiling check below needs
         # EVERY verify event of the run, not the last N
         libtrace.enable(ring=1 << 16)
-        genesis, pvs = helpers.make_genesis(4)
         nodes = [
             helpers.make_consensus_node(genesis, pv) for pv in pvs
         ]
@@ -1752,12 +1769,29 @@ class TestConsensusTraceBurst:
             and e.get("backend") == "ed25519-tpu"
         ]
         phases = {e["name"].split(".", 1)[1] for e in phase_evs}
-        assert {"pack", "dispatch", "readback"} <= phases, phases
+        assert phases == {"pack", "dispatch", "readback"}, phases
+        batches = m.verify_batch_seconds.labels("ed25519-tpu")
+        assert batches._n > 0
+        for phase in sorted(phases):
+            mine = [e for e in phase_evs if e["name"] == "verify." + phase]
+            hist = m.verify_phase_seconds.labels(phase, "ed25519-tpu")
+            # every batch has each phase exactly once, as a span and as
+            # one histogram observation (a burst's batches are far
+            # under one chunk) ...
+            assert len(mine) == hist._n == batches._n, (phase, len(mine))
+            # ... of the SAME two clock readings: equal whatever the
+            # load (the histogram takes seconds, the span nanoseconds)
+            assert hist._sum == pytest.approx(
+                sum(e["dur_ns"] for e in mine) / 1e9, rel=1e-6
+            ), phase
+        # and the three lie inside the batch's interval, which starts
+        # before the first and ends after the last. (That they also
+        # fill most of it was asserted here as phase_s >= 0.3 * total_s:
+        # a ratio of wall-clock sums, in which the gaps between the
+        # phases grow with every wait for the GIL.)
         phase_s = sum(e["dur_ns"] for e in phase_evs) / 1e9
-        total_s = m.verify_batch_seconds.labels("ed25519-tpu")._sum
-        assert total_s > 0
+        total_s = batches._sum
         assert 0 < phase_s <= total_s * 1.01, (phase_s, total_s)
-        assert phase_s >= total_s * 0.3, (phase_s, total_s)
 
 
 class TestProfilePlane:

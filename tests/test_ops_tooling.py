@@ -261,21 +261,58 @@ def test_bucket_size_grid():
         assert got < 512 or got % 512 == 0
 
 
-def test_pallas_flavor_selection(monkeypatch):
-    """Auto kernel mode tries ``pallas`` first (no recorded measurement
-    steers it); explicit modes pin one flavor; faulted flavors drop out
-    of the candidate order (per-flavor isolation — a pallas8 fault must
-    not retire pallas)."""
+@pytest.mark.parametrize(
+    "accelerator,lanes,faulted,want",
+    [
+        (True, 512, False, "pallas"),  # one Pallas block: the floor
+        (True, 8192, False, "pallas"),
+        (True, 256, False, "xla"),  # under a block: the small-grid jit
+        (True, 8192, True, "xla"),  # Pallas has faulted in this process
+        (False, 8192, False, "xla"),  # a CPU backend
+        (False, 64, False, "xla"),
+    ],
+)
+@pytest.mark.parametrize("route", ["verify", "verify_cached"])
+def test_pallas_flavor_selection(
+    monkeypatch, route, accelerator, lanes, faulted, want
+):
+    """The one rule of ops/verify: accelerator backend x bucket x
+    "Pallas has faulted" -> the program a launch runs, the same on both
+    routes, on the bucket's grid."""
+    import numpy as np
+
+    from cometbft_tpu.libs import accel as libaccel
     from cometbft_tpu.ops import verify as ov
 
-    monkeypatch.setattr(ov, "_KERNEL_MODE", "auto")
-    monkeypatch.setattr(ov, "_PALLAS_BROKEN", set())
-    assert ov._pallas_candidates() == ["pallas", "pallas8"]
-    # a faulted first choice falls back to the sibling, not to nothing
-    monkeypatch.setattr(ov, "_PALLAS_BROKEN", {"pallas"})
-    assert ov._pallas_candidates() == ["pallas8"]
-    assert ov.dispatch_counters()["pallas_broken"] == ["pallas"]
-    # explicit mode pins a single flavor
-    monkeypatch.setattr(ov, "_PALLAS_BROKEN", set())
-    monkeypatch.setattr(ov, "_KERNEL_MODE", "pallas8")
-    assert ov._pallas_candidates() == ["pallas8"]
+    monkeypatch.setattr(
+        libaccel, "accelerator_backend", lambda required=False: accelerator
+    )
+    monkeypatch.setattr(ov, "_PALLAS_BROKEN", faulted)
+    monkeypatch.setattr(ov, "_LAUNCHES", {})
+    assert ov._pallas_wanted(lanes) is (want == "pallas")
+    assert ov.dispatch_counters()["pallas_broken"] == (
+        ["pallas"] if faulted else []
+    )
+
+    class Out:
+        def copy_to_host_async(self):
+            pass
+
+    class Program:
+        kernel = "fetched"
+
+        def __call__(self, *args):
+            return Out()
+
+    fetched = []
+
+    def getter(*key):
+        fetched.append(key)
+        return Program()
+
+    monkeypatch.setattr(ov, "_jitted_kernel", getter)
+    rows = np.zeros((96, lanes), np.uint8)
+    _out, which = ov._launch(route, (), (rows,))
+    assert which == want
+    assert fetched == [(route, want, lanes if lanes <= 256 else None)]
+    assert ov.dispatch_counters()["launches"] == {"fetched": 1}

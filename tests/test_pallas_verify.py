@@ -22,7 +22,7 @@ from test_curve import _order8_point, make_batch
 
 # Interpret-mode execution of the full ladder is tens of minutes per
 # invocation on small CPU hosts — slow tier (the XLA-lowering parity
-# tests in test_curve/test_kernel8 stay tier-1).
+# tests in test_curve/test_verify_routes stay tier-1).
 pytestmark = pytest.mark.slow
 
 rng = random.Random(77)

@@ -132,13 +132,15 @@ def test_graft_entry_dryrun():
 
 
 def test_sharded_dispatch_backend_selection(monkeypatch):
-    """_dispatch_sharded routes accelerators to the pallas-per-shard
-    path and everything else (CPU virtual meshes, COMETBFT_TPU_KERNEL
-    overrides, sub-512-lane shards) to the portable XLA program; a
-    pallas failure — including one surfacing at materialization —
-    retires the path and falls back instead of sinking the verify."""
+    """_dispatch_sharded asks ops/verify's one rule: accelerators go to
+    the pallas-per-shard path and everything else (CPU virtual meshes, a
+    process in which Pallas has faulted, sub-512-lane shards) to the
+    portable XLA program; a pallas failure — including one surfacing at
+    materialization — retires the path and falls back instead of
+    sinking the verify."""
     import numpy as np
 
+    from cometbft_tpu.libs import accel as libaccel
     from cometbft_tpu.ops import verify as ov
     from cometbft_tpu.parallel import mesh as pmesh
 
@@ -155,10 +157,13 @@ def test_sharded_dispatch_backend_selection(monkeypatch):
                 raise RuntimeError("mosaic balked")
             return pair
 
-    def reset(pallas_wanted, fail=False, backend="tpu"):
+    def reset(accelerator=True, faulted=False, fail=False):
         calls.clear()
-        monkeypatch.setattr(ov, "_pallas_wanted", lambda: pallas_wanted)
-        monkeypatch.setattr(pmesh.jax, "default_backend", lambda: backend)
+        monkeypatch.setattr(
+            libaccel, "accelerator_backend",
+            lambda required=False: accelerator,
+        )
+        monkeypatch.setattr(ov, "_PALLAS_BROKEN", faulted)
         monkeypatch.setattr(
             pmesh, "_sharded_verify", lambda m: FakeCallable("xla")
         )
@@ -169,31 +174,31 @@ def test_sharded_dispatch_backend_selection(monkeypatch):
         )
         monkeypatch.setattr(pmesh, "_SHARDED_PALLAS_BROKEN", False)
 
-    # kernel-knob override (xla/xla8): straight to XLA
-    reset(pallas_wanted=False)
+    # Pallas has faulted on the single-chip path: straight to XLA
+    reset(faulted=True)
     pmesh._dispatch_sharded("mesh", (), lanes_per_shard=2048)
     assert calls == ["xla"]
 
-    # off-accelerator pallas pin: no Mosaic attempt, no retirement
-    reset(pallas_wanted=True, backend="cpu")
+    # off-accelerator: no Mosaic attempt, no retirement
+    reset(accelerator=False)
     pmesh._dispatch_sharded("mesh", (), lanes_per_shard=2048)
     assert calls == ["xla"] and not pmesh._SHARDED_PALLAS_BROKEN
 
     # accelerator: pallas first
-    reset(pallas_wanted=True)
+    reset()
     pmesh._dispatch_sharded("mesh", (), lanes_per_shard=2048)
     assert calls == ["pallas"]
 
     # tiny per-shard lane counts stay off Mosaic (512-lane floor)
-    reset(pallas_wanted=True)
+    reset()
     pmesh._dispatch_sharded("mesh", (), lanes_per_shard=8)
     assert calls == ["xla"]
 
     # pallas failure: falls back to XLA and retires the path
-    reset(pallas_wanted=True, fail=True)
+    reset(fail=True)
     pmesh._dispatch_sharded("mesh", (), lanes_per_shard=2048)
     assert calls == ["pallas", "xla"]
-    assert pmesh._SHARDED_PALLAS_BROKEN
+    assert pmesh._SHARDED_PALLAS_BROKEN and not ov._PALLAS_BROKEN
     calls.clear()
     pmesh._dispatch_sharded("mesh", (), lanes_per_shard=2048)
     assert calls == ["xla"]  # retired: no pallas retry

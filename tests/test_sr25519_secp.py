@@ -442,7 +442,7 @@ def test_mixed_row_assembly_matches_pack_part_row():
 def test_bucket_midpoints_match_pallas_block():
     """bucket_size's midpoint admission hard-codes the Pallas block
     width; if _BLOCK is ever retuned, a mid-bucket launch would raise
-    inside _run_kernel and permanently pin the process to the XLA
+    inside _launch and permanently pin the process to the XLA
     kernel (_PALLAS_BROKEN) — this pins the two constants together."""
     from cometbft_tpu.ops import pallas_verify
     from cometbft_tpu.ops import verify as ov

@@ -296,10 +296,15 @@ def test_pallas_kernel_matches_corpus():
     assert not bad, f"Pallas kernel diverges: {bad}"
 
 
-def test_verify_batch_production_path_matches_corpus():
+@pytest.mark.parametrize("pubkey_cache", ["1", "0"])
+def test_verify_batch_production_path_matches_corpus(
+    monkeypatch, pubkey_cache
+):
     """The production dispatch (ops.verify.verify_batch — what VoteSet
     and commit verification actually call) returns the same per-lane
-    bitmap as the analytic verdicts."""
+    bitmap as the analytic verdicts, on the cached-arena route and on
+    the uncached one."""
+    monkeypatch.setenv("COMETBFT_TPU_PUBKEY_CACHE", pubkey_cache)
     pks, msgs, sigs, expect = _split(CORPUS)
     ok, bitmap = verify.verify_batch(pks, msgs, sigs)
     assert ok == all(expect) or not all(expect)
