@@ -19,6 +19,7 @@ import numpy as np
 from ..libs import metrics as libmetrics
 from ..libs import sync as libsync
 from . import keys
+from .host_batch import MsgColumn
 from .keys import Ed25519PubKey
 
 
@@ -273,34 +274,64 @@ def _all_instances(pub_keys, key_class) -> bool:
     return all(issubclass(t, key_class) for t in set(map(type, pub_keys)))
 
 
-def _extend_lanes(bv, pub_keys, msgs, signatures) -> None:
-    """add_many's three extends, after the backend's type check."""
+def _check_lane_counts(pub_keys, msgs, signatures) -> None:
     if not len(pub_keys) == len(msgs) == len(signatures):
         raise ValueError("add_many needs a message and a signature per key")
+
+
+def _extend_lanes(bv, pub_keys, msgs, signatures) -> None:
+    """add_many's three extends, after the backend's type check."""
+    _check_lane_counts(pub_keys, msgs, signatures)
     bv._pubkeys.extend([pk.data for pk in pub_keys])
     bv._msgs.extend(map(bytes, msgs))
     bv._sigs.extend(map(bytes, signatures))
 
 
 class Ed25519BatchVerifier(BatchVerifier):
-    """TPU-backed ed25519 batch verification with a host small-batch path."""
+    """TPU-backed ed25519 batch verification with a host small-batch path.
+
+    The lanes are kept as three columns, keys, messages and signatures,
+    and handed on as such: to ``ops/verify.verify_batch``, to the
+    coalescer and to ``host_batch.verify_many``, each of which takes
+    any sequence of bytes-likes in each slot. ``add_many`` on an empty
+    verifier (a commit check's one call) keeps the message column it is
+    given, a ``host_batch.MsgColumn`` from the sign-bytes encoder above
+    all, which the device packer then reads in place: nothing between
+    the encoder and the wire buffer cuts it into lanes."""
 
     def __init__(self) -> None:
         self._pubkeys: list[bytes] = []
-        self._msgs: list[bytes] = []
+        self._msgs = []  # a list, or the MsgColumn add_many was given
         self._sigs: list[bytes] = []
+
+    def _msg_list(self) -> list:
+        """The messages as a list that can grow."""
+        if not isinstance(self._msgs, list):
+            self._msgs = list(self._msgs)
+        return self._msgs
 
     def add(self, pub_key, msg: bytes, signature: bytes) -> None:
         if not isinstance(pub_key, Ed25519PubKey):
             raise TypeError("Ed25519BatchVerifier requires ed25519 keys")
         self._pubkeys.append(pub_key.data)
-        self._msgs.append(bytes(msg))
+        self._msg_list().append(bytes(msg))
         self._sigs.append(bytes(signature))
 
     def add_many(self, pub_keys, msgs, signatures) -> None:
         if not _all_instances(pub_keys, Ed25519PubKey):
             raise TypeError("Ed25519BatchVerifier requires ed25519 keys")
-        _extend_lanes(self, pub_keys, msgs, signatures)
+        _check_lane_counts(pub_keys, msgs, signatures)
+        keys = [pk.data for pk in pub_keys]
+        if self._pubkeys:
+            self._pubkeys.extend(keys)
+            self._msg_list().extend(map(bytes, msgs))
+            self._sigs.extend(signatures)
+            return
+        self._pubkeys = keys
+        self._msgs = (
+            msgs if isinstance(msgs, MsgColumn) else list(map(bytes, msgs))
+        )
+        self._sigs = list(signatures)
 
     def __len__(self) -> int:
         return len(self._pubkeys)

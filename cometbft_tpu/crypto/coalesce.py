@@ -953,9 +953,12 @@ class VerifyCoalescer(BaseService):
                 from ..ops import verify as ov
 
                 with _window_phase("pack", win, n, route="device") as ph:
-                    buf, host_ok = ov.pack_bytes(pubkeys, msgs, sigs)
+                    # rows and slots at the launch's width: no padding
+                    # copy between the pack and the launch
+                    width = ov.bucket_size(n)
+                    buf, host_ok = ov.pack_bytes(pubkeys, msgs, sigs, width)
                     hit = (
-                        ov._PUBKEY_CACHE.lookup(pubkeys)
+                        ov._PUBKEY_CACHE.lookup(pubkeys, width)
                         if ov._cache_enabled()
                         else None
                     )

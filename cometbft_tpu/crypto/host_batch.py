@@ -32,6 +32,9 @@ from __future__ import annotations
 import ctypes
 import os
 from array import array
+from collections.abc import Sequence
+from itertools import accumulate
+from operator import eq as _eq
 from ..libs import sync as libsync
 import secrets
 
@@ -106,6 +109,12 @@ def _bind(lib) -> None:
         ctypes.c_char_p, ctypes.c_char_p, ctypes.c_void_p,
         ctypes.c_size_t, ctypes.c_char_p, ctypes.c_char_p,
     ]
+    lib.edb_pack_wire.restype = ctypes.c_long
+    lib.edb_pack_wire.argtypes = [
+        ctypes.c_char_p, ctypes.c_char_p, ctypes.c_char_p,
+        ctypes.c_void_p, ctypes.c_size_t, ctypes.c_void_p,
+        ctypes.c_size_t, ctypes.c_void_p,
+    ]
     lib.edb_verify_batch.restype = ctypes.c_long
     lib.edb_verify_batch.argtypes = [
         ctypes.c_char_p, ctypes.c_char_p, ctypes.c_void_p,
@@ -170,8 +179,86 @@ def available() -> bool:
     return _load() is not None
 
 
+class MsgColumn(Sequence):
+    """A batch's messages as one column: ``blob`` (bytes) and ``offs``
+    (``array('Q')``, one entry more than lanes), lane i being
+    ``blob[offs[i]:offs[i + 1]]`` — what the native encoder writes
+    (:func:`vote_sign_bytes`) and what the native packers read in place
+    (:func:`pack_wire`, :func:`pack_challenges`).
+
+    Immutable, and a ``list[bytes]`` to whoever indexes, iterates,
+    slices or compares it: lanes are cut on demand. A contiguous slice
+    is again a column over the same blob (``offs`` is absolute, its
+    first entry need not be 0); a strided one is a list."""
+
+    __slots__ = ("_blob", "_offs")
+
+    def __init__(self, blob: bytes, offs: array):
+        self._blob = blob
+        self._offs = offs
+
+    @classmethod
+    def joined(cls, msgs) -> "MsgColumn":
+        """The column of a plain sequence of bytes-likes."""
+        return cls(
+            b"".join(msgs),
+            array("Q", accumulate(map(len, msgs), initial=0)),
+        )
+
+    @property
+    def blob(self) -> bytes:
+        return self._blob
+
+    @property
+    def offs(self) -> array:
+        return self._offs
+
+    def __len__(self) -> int:
+        return len(self._offs) - 1
+
+    def __getitem__(self, i):
+        offs = self._offs
+        if isinstance(i, slice):
+            start, stop, step = i.indices(len(offs) - 1)
+            if step != 1:
+                return [self[k] for k in range(start, stop, step)]
+            stop = max(start, stop)
+            return MsgColumn(self._blob, offs[start : stop + 1])
+        if i < 0:
+            i += len(offs) - 1
+        if not 0 <= i < len(offs) - 1:
+            raise IndexError("MsgColumn index out of range")
+        return self._blob[offs[i] : offs[i + 1]]
+
+    def __iter__(self):
+        offs = self._offs.tolist()
+        return map(self._blob.__getitem__, map(slice, offs, offs[1:]))
+
+    def __eq__(self, other):
+        if not isinstance(other, (MsgColumn, list, tuple)):
+            return NotImplemented
+        return len(self) == len(other) and all(map(_eq, self, other))
+
+    __hash__ = None
+
+    def __repr__(self) -> str:
+        return f"MsgColumn({len(self)} lanes, {len(self._blob)} bytes)"
+
+
+def _offs_pointer(offs, n: int):
+    """(address, keep-alive) of n + 1 ``uint64`` offsets: an
+    ``array('Q')`` is read where it lies, anything else is copied into
+    one first."""
+    if not (isinstance(offs, array) and offs.typecode == "Q"):
+        offs = array("Q", offs)
+    if len(offs) != n + 1:
+        raise ValueError(f"{n} lanes need {n + 1} offsets, not {len(offs)}")
+    return offs.buffer_info()[0], offs
+
+
 def pack_challenges(recs: bytes, msgs_blob: bytes, offs, n: int):
-    """Native per-lane challenge packing for ops/verify.pack_bytes.
+    """Native per-lane challenges for callers that assemble their own
+    rows (the mixed verifier's ed25519 lanes).
 
     ``recs``: n x 96 bytes (A|R|S); ``msgs_blob`` + ``offs`` (n+1 u64):
     concatenated sign bytes. Returns (kneg_rows 32n bytes, s_ok (n,)
@@ -182,22 +269,53 @@ def pack_challenges(recs: bytes, msgs_blob: bytes, offs, n: int):
         return None
     out_kneg = ctypes.create_string_buffer(32 * n)
     out_ok = ctypes.create_string_buffer(n)
-    offs_arr = (ctypes.c_uint64 * (n + 1))(*offs)
+    offs_at, offs = _offs_pointer(offs, n)
     rc = lib.edb_pack_challenges(
-        recs, msgs_blob, offs_arr, n, out_kneg, out_ok
+        recs, msgs_blob, offs_at, n, out_kneg, out_ok
     )
     if rc != 0:
         return None
     return out_kneg.raw, np.frombuffer(out_ok.raw, np.uint8).astype(bool)
 
 
+def pack_wire(
+    keys: bytes, sigs: bytes, msgs: MsgColumn, out: np.ndarray,
+    out_ok: np.ndarray,
+) -> bool:
+    """The device wire buffer of ops/verify.pack_bytes in one native
+    call, from columns read in place: ``keys`` n x 32 bytes, ``sigs``
+    n x 64, ``msgs`` the sign bytes. Lane i becomes column i of ``out``
+    (C-contiguous (128, width) uint8, width >= n; columns past n are
+    left as they are), ``out_ok[i]`` (bool, (n,)) is S < L, and a lane
+    with S >= L gets a zero column. The interpreter lock is released
+    for the call. False when the native engine is unavailable."""
+    lib = _load()
+    if lib is None:
+        return False
+    n = len(msgs)
+    if len(keys) != 32 * n or len(sigs) != 64 * n or len(out_ok) != n:
+        raise ValueError("pack_wire: columns of unequal lane counts")
+    if (
+        out.shape[0] != 128 or out.shape[1] < n
+        or not out.flags.c_contiguous
+    ):
+        raise ValueError(
+            "pack_wire: out must be C-contiguous (128, width >= lanes)"
+        )
+    rc = lib.edb_pack_wire(
+        keys, sigs, msgs.blob, msgs.offs.buffer_info()[0], n,
+        out.ctypes.data, out.shape[1], out_ok.ctypes.data,
+    )
+    return rc == 0
+
+
 def vote_sign_bytes(prefix: bytes, suffix: bytes, timestamps_ns):
     """CanonicalVote sign bytes of votes that differ in the timestamp
     alone, for types/canonical.vote_sign_bytes_many: ``timestamps_ns`` an
-    ``array('q')``. Returns (blob, offs): lane i is
-    ``blob[offs[i]:offs[i + 1]]``, the layout ``pack_challenges`` takes;
-    None when the native engine is unavailable. The call keeps the
-    interpreter lock (it takes ~30 ns a lane)."""
+    ``array('q')``. Returns the lanes as a :class:`MsgColumn`, the
+    layout ``pack_wire`` takes; None when the native engine is
+    unavailable. The call keeps the interpreter lock (it takes ~30 ns a
+    lane)."""
     lib = _load()
     if lib is None:
         return None
@@ -209,7 +327,7 @@ def vote_sign_bytes(prefix: bytes, suffix: bytes, timestamps_ns):
         timestamps_ns.buffer_info()[0], n,
         (ctypes.c_char * len(out)).from_buffer(out), offs.buffer_info()[0],
     )
-    return bytes(memoryview(out)[: offs[n]]), offs
+    return MsgColumn(bytes(memoryview(out)[: offs[n]]), offs)
 
 
 def sr_challenge_batch(
@@ -229,9 +347,9 @@ def sr_challenge_batch(
     if lib is None:
         return None
     out_k = ctypes.create_string_buffer(32 * n)
-    offs_arr = (ctypes.c_uint64 * (n + 1))(*offs)
+    offs_at, offs = _offs_pointer(offs, n)
     rc = lib.edb_sr_challenge_batch(
-        ctx_state, recs, msgs_blob, offs_arr, n, out_k
+        ctx_state, recs, msgs_blob, offs_at, n, out_k
     )
     if rc != 0:
         return None

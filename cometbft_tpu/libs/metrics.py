@@ -456,6 +456,28 @@ class NodeMetrics:
         )
         for path in ("batched", "per_lane"):
             self.commit_sign_bytes_lanes_total.labels(path)
+        # how ops/verify.pack_bytes built a wire buffer: "columnar" (key,
+        # signature and message columns joined by C-level calls and one
+        # native call that writes the transposed rows) or "per_lane" (a
+        # malformed lane sent the packer through its per-lane pass, or
+        # there is no native engine and the pure-Python loop ran)
+        self.verify_pack_lanes_total = r.counter(
+            "crypto_verify_pack_lanes_total",
+            "Lanes packed for the device verifier by packer path",
+            label_names=("path",),
+        )
+        for path in ("columnar", "per_lane"):
+            self.verify_pack_lanes_total.labels(path)
+        # how the device key arena answered a lookup: "memo" (the whole
+        # key column was the one a kept slot vector was computed for) or
+        # "walked" (key by key through the LRU)
+        self.pubkey_lookup_lanes_total = r.counter(
+            "ops_pubkey_lookup_lanes_total",
+            "Key-arena lookup lanes by how their slots were found",
+            label_names=("result",),
+        )
+        for result in ("memo", "walked"):
+            self.pubkey_lookup_lanes_total.labels(result)
         # cross-caller verify coalescer (crypto/coalesce.py): window
         # shape, flush triggers, and the submit-to-verdict latency its
         # callers actually pay. Lane-count buckets are the shape
@@ -1116,6 +1138,28 @@ def observe_commit_sign_bytes(path: str, lanes: int) -> None:
     """Lanes one commit check encoded sign-bytes for:
     types_commit_sign_bytes_lanes_total{path}."""
     node_metrics().commit_sign_bytes_lanes_total.labels(path).inc(lanes)
+
+
+def _set_on_pack_span(**fields) -> None:
+    """Fields for the ``verify.pack`` span the caller runs under, if it
+    runs under one (verify_batch's and the coalescer's both do)."""
+    sp = libtrace.current()
+    if sp is not None and sp.name == "verify.pack":
+        sp.set(**fields)
+
+
+def observe_pack_lanes(path: str, lanes: int) -> None:
+    """One ops/verify.pack_bytes call: crypto_verify_pack_lanes_total
+    {path}, and ``path`` on the enclosing ``verify.pack`` span."""
+    node_metrics().verify_pack_lanes_total.labels(path).inc(lanes)
+    _set_on_pack_span(path=path)
+
+
+def observe_pubkey_lookup(result: str, lanes: int) -> None:
+    """One key-arena lookup: ops_pubkey_lookup_lanes_total{result}, and
+    ``slots`` on the enclosing ``verify.pack`` span."""
+    node_metrics().pubkey_lookup_lanes_total.labels(result).inc(lanes)
+    _set_on_pack_span(slots=result)
 
 
 def observe_hash_phase(phase: str, seconds: float, lanes: int) -> None:

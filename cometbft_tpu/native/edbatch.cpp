@@ -787,6 +787,63 @@ long edb_pack_challenges(const uint8_t* recs, const uint8_t* msgs,
     return 0;
 }
 
+// edb_pack_challenges from columns, straight into the device wire buffer:
+// keys n x 32 (A), sigs n x 64 (R | S), msgs[offs[i]:offs[i+1]] the sign
+// bytes, all read in place. out is a caller-owned row-major
+// (128, width) uint8 buffer, width >= n: lane i is COLUMN i, rows 0-31
+// A, 32-63 R, 64-95 S, 96-127 (L - k) mod L (the layout of
+// ops/verify.pack_bytes). Columns n..width are not touched. A lane with
+// S >= L gets out_ok[i] = 0 and a zero column. Lanes are gathered 64 at
+// a time in a tile and written out as 64-byte row pieces: a byte store
+// per row per lane would walk 128 cache lines a power of two apart.
+long edb_pack_wire(const uint8_t* keys, const uint8_t* sigs,
+                   const uint8_t* msgs, const uint64_t* offs, size_t n,
+                   uint8_t* out, size_t width, uint8_t* out_ok) {
+    if (!g_sha_ready) return -1;
+    if (width < n) return -2;
+    ensure_init();
+    const size_t TILE = 64;
+    uint8_t tile[TILE][128];
+    for (size_t base = 0; base < n; base += TILE) {
+        size_t m = n - base < TILE ? n - base : TILE;
+        for (size_t j = 0; j < m; j++) {
+            size_t i = base + j;
+            const uint8_t* a = keys + 32 * i;
+            const uint8_t* r = sigs + 64 * i;
+            u64 sv[4];
+            memcpy(sv, r + 32, 32);
+            if (sc_geq(sv, L_LIMBS)) {
+                out_ok[i] = 0;
+                memset(tile[j], 0, 128);
+                continue;
+            }
+            out_ok[i] = 1;
+            Sha512Ctx c;
+            sha_init_ctx(c);
+            sha_update(c, r, 32);
+            sha_update(c, a, 32);
+            sha_update(c, msgs + offs[i], (size_t)(offs[i + 1] - offs[i]));
+            uint8_t digest[64];
+            sha_final(c, digest);
+            u64 k[4];
+            sc_reduce512(digest, k);
+            u64 kneg[4] = {0, 0, 0, 0};
+            if (k[0] | k[1] | k[2] | k[3]) {
+                memcpy(kneg, L_LIMBS, 32);
+                sc_sub_inplace(kneg, k);
+            }
+            memcpy(tile[j], a, 32);
+            memcpy(tile[j] + 32, r, 64);
+            memcpy(tile[j] + 96, kneg, 32);
+        }
+        for (size_t row = 0; row < 128; row++) {
+            uint8_t* dst = out + row * width + base;
+            for (size_t j = 0; j < m; j++) dst[j] = tile[j][row];
+        }
+    }
+    return 0;
+}
+
 // Fused happy-path batch verification: per lane i, recs holds
 // A(32) | R(32) | S(32), msgs[offs[i]:offs[i+1]] the sign bytes, and
 // zs 16 random bytes (the RLC coefficient, drawn by the caller from a

@@ -30,7 +30,7 @@ from ..libs import devstats as libdevstats
 from ..libs.accel import ACCELERATOR_BACKENDS
 from ..libs import metrics as libmetrics
 from ..libs import sync as libsync
-from collections import OrderedDict
+from collections import OrderedDict, deque
 from functools import lru_cache
 
 import numpy as np
@@ -140,20 +140,36 @@ def pack_parts(parts) -> tuple[np.ndarray, np.ndarray]:
     return buf, host_ok
 
 
-def pack_bytes(pubkeys, msgs, sigs) -> tuple[np.ndarray, np.ndarray]:
+def pack_bytes(
+    pubkeys, msgs, sigs, width: int | None = None
+) -> tuple[np.ndarray, np.ndarray]:
     """Host-side packing to the compact device wire format.
 
-    Returns (buf (128, n) uint8, host_ok (n,) bool). Rows 0-31 pubkey,
-    32-63 R, 64-95 S, 96-127 (-k mod L), all little-endian bytes; the
-    device unpacks bits/limbs/nibbles itself (:func:`unpack_on_device`).
-    Shipping 128 B/sig instead of ~680 B of pre-unpacked int32 limbs cuts
-    the host->HBM transfer ~5x. Malformed inputs (wrong
-    lengths, non-canonical S >= L) get host_ok=False and dummy lanes.
+    Returns (buf (128, width) uint8, host_ok (n,) bool); ``width``
+    defaults to n and may be the launch bucket, the columns past n then
+    being zero as the launch's padding would have made them. Rows 0-31
+    pubkey, 32-63 R, 64-95 S, 96-127 (-k mod L), all little-endian
+    bytes; the device unpacks bits/limbs/nibbles itself
+    (:func:`unpack_on_device`). Shipping 128 B/sig instead of ~680 B of
+    pre-unpacked int32 limbs cuts the host->HBM transfer ~5x. Malformed
+    inputs (wrong lengths, non-canonical S >= L) get host_ok=False and
+    zeroed lanes.
+
+    ``msgs`` is a ``host_batch.MsgColumn`` or any sequence of
+    bytes-likes, as are the lanes of ``pubkeys`` and ``sigs``. With the
+    native engine the lanes travel as columns (:func:`_pack_bytes_native`)
+    and no Python statement runs once per lane; without it, the loop
+    below.
     """
     n = len(pubkeys)
-    native = _pack_bytes_native(pubkeys, msgs, sigs, n)
+    if not n == len(msgs) == len(sigs):
+        raise ValueError("pack_bytes needs a message and a signature per key")
+    if width is None:
+        width = n
+    native = _pack_bytes_native(pubkeys, msgs, sigs, n, width)
     if native is not None:
         return native
+    libmetrics.observe_pack_lanes("per_lane", n)
     host_ok = np.ones(n, bool)
     pk_buf = bytearray(32 * n)
     rr_buf = bytearray(32 * n)
@@ -182,62 +198,56 @@ def pack_bytes(pubkeys, msgs, sigs) -> tuple[np.ndarray, np.ndarray]:
         np.frombuffer(bytes(b), np.uint8).reshape(n, 32).T
         for b in (pk_buf, rr_buf, ss_buf, kneg_buf)
     ]
-    return np.ascontiguousarray(np.concatenate(rows, axis=0)), host_ok
+    buf = np.zeros((128, width), np.uint8)
+    buf[:, :n] = np.concatenate(rows, axis=0)
+    return buf, host_ok
 
 
 _Z32 = bytes(32)
-_Z96 = bytes(96)
+_Z64 = bytes(64)
 
 
-def _pack_bytes_native(pubkeys, msgs, sigs, n: int):
-    """pack_bytes via the native challenge engine; None to fall back.
+def _pack_bytes_native(pubkeys, msgs, sigs, n: int, width: int):
+    """pack_bytes through the native engine; None to fall back.
 
-    The Python loop above costs ~9 us/lane (SHA-512 + bigint mod +
-    per-lane buffer writes); the C path (native/edbatch.cpp
-    edb_pack_challenges) does the per-lane work in ~1.5 us, leaving
-    only bulk joins here. Malformed lanes keep the same semantics:
-    host_ok False, zeroed rows.
+    The lanes travel as columns: the keys joined to n x 32 bytes, the
+    signatures to n x 64, the messages one blob with its offsets (a
+    ``MsgColumn`` is taken as it is, a plain sequence joined), and ONE
+    native call (native/edbatch.cpp edb_pack_wire, interpreter lock
+    released) hashes every lane's challenge and writes the rows already
+    transposed into the (128, width) buffer. Nothing here runs once per
+    lane in Python, and no numpy call walks the lanes, while every key
+    is 32 bytes and every signature 64. A lane that is not gets
+    host_ok False and a zero column as ever: zero records stand in for
+    it in the columns (the one per-lane pass, counted as
+    ``crypto_verify_pack_lanes_total{path="per_lane"}``).
     """
     from ..crypto import host_batch
 
     if not host_batch.available():
         return None
-    host_ok = np.ones(n, bool)
-    recs = []
-    msg_parts = []
-    lens = np.zeros(n, np.uint64)  # host-staging: message byte lengths
-    # for the C packer's offset table; never shipped to the device
-    for i in range(n):
-        p_i, s_i = pubkeys[i], sigs[i]
-        if len(p_i) != 32 or len(s_i) != 64:
-            host_ok[i] = False
-            recs.append(_Z96)
-            msg_parts.append(b"")
-            continue
-        recs.append(bytes(p_i) + bytes(s_i))
-        m = bytes(msgs[i])
-        msg_parts.append(m)
-        lens[i] = len(m)
-    recs_blob = b"".join(recs)
-    msgs_blob = b"".join(msg_parts)
-    offs = np.zeros(n + 1, np.uint64)  # host-staging: byte offsets into
-    # msgs_blob for native/edbatch.cpp (size_t ABI); never device-bound
-    np.cumsum(lens, out=offs[1:])
-    out = host_batch.pack_challenges(recs_blob, msgs_blob, offs, n)
-    if out is None:
+    path, bad = "columnar", ()
+    if n and (set(map(len, pubkeys)) != {32} or set(map(len, sigs)) != {64}):
+        path = "per_lane"
+        pubkeys, sigs = list(pubkeys), list(sigs)
+        bad = [
+            i for i in range(n)
+            if len(pubkeys[i]) != 32 or len(sigs[i]) != 64
+        ]
+        for i in bad:
+            pubkeys[i], sigs[i] = _Z32, _Z64
+    if not isinstance(msgs, host_batch.MsgColumn):
+        msgs = host_batch.MsgColumn.joined(msgs)
+    buf = np.zeros((128, width), np.uint8)
+    host_ok = np.empty(n, bool)
+    if not host_batch.pack_wire(
+        b"".join(pubkeys), b"".join(sigs), msgs, buf, host_ok
+    ):
         return None
-    kneg_blob, s_ok = out
-    rec_arr = np.frombuffer(recs_blob, np.uint8).reshape(n, 96)
-    kneg_arr = np.frombuffer(kneg_blob, np.uint8).reshape(n, 32)
-    buf = np.ascontiguousarray(
-        np.concatenate([rec_arr, kneg_arr], axis=1).T
-    )
-    host_ok &= s_ok
-    # zero the rows of malformed/non-canonical lanes (legacy semantics:
-    # the kernel sees dummy data there; host_ok masks the verdict)
-    bad = ~host_ok
-    if bad.any():
+    if bad:
+        host_ok[bad] = False
         buf[:, bad] = 0
+    libmetrics.observe_pack_lanes(path, n)
     return buf, host_ok
 
 
@@ -496,6 +506,12 @@ def _builder_bucket(m: int) -> int:
     return size
 
 
+# Kept slot vectors of a PubkeyTableCache: the validator sets a process
+# checks commits of at one time are a handful (two light clients on one
+# chain share one), and a coalescer window's key sequence rarely repeats.
+_MEMO_ENTRIES = 4
+
+
 class PubkeyTableCache:
     """LRU arena of expanded pubkey tables resident on device.
 
@@ -528,6 +544,12 @@ class PubkeyTableCache:
         self._slots: OrderedDict[bytes, int] = OrderedDict()
         self._arena = None
         self._arena_ok = None
+        # the slot vectors of the last few key columns answered, newest
+        # first: (column, _gen it was computed under, width, idxs). _gen
+        # counts every slot assigned or evicted, so an entry is served
+        # only while the slots are what its vector was read from
+        self._memo: list[tuple[bytes, int, int, np.ndarray]] = []
+        self._gen = 0
         self.hits = 0
         self.misses = 0
         self.builds = 0  # builder launches (device round trips)
@@ -552,14 +574,48 @@ class PubkeyTableCache:
         with self._lock:
             return sum(1 for pk in keys if pk not in self._slots)
 
-    def lookup(self, pubkeys):
+    def _key_column(self, pubkeys) -> bytes | None:
+        """The keys joined into one n x 32 column, the memo's key; None
+        when a lane is not a 32-byte key (such a batch is walked)."""
+        try:
+            if set(map(len, pubkeys)) == {32}:
+                return b"".join(pubkeys)
+        except TypeError:
+            pass
+        return None
+
+    def _retire_memo(self) -> None:
+        """The slots are about to change (caller holds the lock): every
+        kept slot vector dies with them. Their keys were served without
+        a touch of the LRU, so they are touched now, least lately served
+        first, before an eviction chooses its victim: a set answered
+        from the memo is never the oldest by accident."""
+        slots = self._slots
+        for column, _gen, _width, _idxs in reversed(self._memo):
+            for at in range(0, len(column), 32):
+                pk = column[at : at + 32]
+                if pk in slots:
+                    slots.move_to_end(pk)
+        self._memo.clear()
+
+    def lookup(self, pubkeys, width: int | None = None):
         """Per-pubkey slot indices into the arena, building misses.
 
-        Returns (idxs (N,) int32, arena, arena_ok), or None when the
-        call's UNIQUE keys exceed the arena (every lane of one gather
-        needs a live slot — callers fall back to the uncached kernel).
-        Keys used by the current call are pinned: eviction never frees a
-        slot this call's gather will read.
+        Returns (idxs (width,) ``idx_dtype``, arena, arena_ok), or None
+        when the call's UNIQUE keys exceed the arena (every lane of one
+        gather needs a live slot — callers fall back to the uncached
+        kernel). ``width`` defaults to the number of keys and may be the
+        launch bucket: the entries past the keys then read slot 0, as
+        the launch's padding would. Keys used by the current call are
+        pinned: eviction never frees a slot this call's gather will
+        read.
+
+        A batch whose keys are all resident and whose key column is one
+        this cache has answered before, with no slot assigned or
+        evicted since (``_gen``), gets the kept vector back (read-only):
+        one join, one comparison of bytes, no statement per lane. A
+        validator set's commit checks repeat their column header after
+        header.
 
         Locking: the builder launch (a full device round trip for new
         keys) runs OUTSIDE the lock, so a cache miss on one path
@@ -570,8 +626,43 @@ class PubkeyTableCache:
         the pairing; tables are a pure function of the key, so two
         threads racing to build the same key scatter identical values.
         """
+        n = len(pubkeys)
+        if width is None:
+            width = n
+        column = self._key_column(pubkeys)
+        if column is not None:
+            with self._lock:
+                idxs = self._memo_get(column, width)
+                if idxs is not None:
+                    self.hits += n
+                    arena, arena_ok = self._arena, self._arena_ok
+            if idxs is not None:
+                libmetrics.observe_pubkey_lookup("memo", n)
+                return idxs, arena, arena_ok
+        hit = self._walk(pubkeys, column, width)
+        if hit is not None:
+            libmetrics.observe_pubkey_lookup("walked", n)
+        return hit
+
+    def _memo_get(self, column: bytes, width: int):
+        """The kept slot vector of ``column`` (caller holds the lock);
+        an entry from before the last change of the slots is dropped."""
+        memo = self._memo
+        for at, (kept, gen, kept_width, idxs) in enumerate(memo):
+            if kept_width == width and kept == column:
+                if gen != self._gen:
+                    del memo[at]
+                    return None
+                if at:
+                    memo.insert(0, memo.pop(at))
+                return idxs
+        return None
+
+    def _walk(self, pubkeys, column, width: int):
+        """:meth:`lookup` key by key through the LRU, building misses;
+        an answer for a well-formed ``column`` is kept for the next."""
         builder, scatter = _cached_jits()
-        keys = [bytes(pk) for pk in pubkeys]
+        keys = list(map(bytes, pubkeys))
         in_use = set(keys)
         if len(in_use) > self.capacity:
             return None
@@ -599,6 +690,8 @@ class PubkeyTableCache:
                         for j, pk in enumerate(batch_keys):
                             slot = self._slots.get(pk)
                             if slot is None:
+                                if self._memo:
+                                    self._retire_memo()
                                 if len(self._slots) >= self.capacity:
                                     # evict the oldest key NOT referenced
                                     # by this call (an in-use eviction
@@ -617,18 +710,27 @@ class PubkeyTableCache:
                                 else:
                                     slot = len(self._slots)
                                 self._slots[pk] = slot
+                                self._gen += 1
                             slots[j] = slot
                         self._arena, self._arena_ok = scatter(
                             self._arena, self._arena_ok, slots, tables, oks
                         )
-                    idxs = np.empty(len(keys), self.idx_dtype)
-                    for i, pk in enumerate(keys):
-                        idxs[i] = self._slots[pk]
-                        self._slots.move_to_end(pk)
-                        if pk in built_keys:
-                            self.misses += 1
-                        else:
-                            self.hits += 1
+                    idxs = np.zeros(width, self.idx_dtype)
+                    idxs[:len(keys)] = list(
+                        map(self._slots.__getitem__, keys)
+                    )
+                    # the LRU touch and the tallies, lane order kept,
+                    # without a statement per lane
+                    deque(map(self._slots.move_to_end, keys), maxlen=0)
+                    missed = sum(map(built_keys.__contains__, keys))
+                    self.misses += missed
+                    self.hits += len(keys) - missed
+                    if column is not None:
+                        idxs.flags.writeable = False
+                        self._memo.insert(
+                            0, (column, self._gen, width, idxs)
+                        )
+                        del self._memo[_MEMO_ENTRIES:]
                     return idxs, self._arena, self._arena_ok
             if _attempt == 3:
                 break  # 3 builds done and keys STILL missing: stop
@@ -924,12 +1026,15 @@ def verify_bytes_async(buf: np.ndarray, n: int, backend: str = _BACKEND):
     finals = []
     for lo in range(0, max(n, 1), _CHUNK):
         m = min(_CHUNK, n - lo)
-        piece = buf[:, lo:lo + m]
         # each chunk pads to its own bucket: a 64-lane remainder
         # launches 64 lanes, not a full _CHUNK
         size = bucket_size(m)
-        if m < size:
-            piece = np.pad(piece, [(0, 0), (0, size - m)])
+        if buf.shape[1] == size:
+            piece = buf  # one chunk, packed at the bucket's width
+        else:
+            piece = buf[:, lo:lo + m]
+            if m < size:
+                piece = np.pad(piece, [(0, 0), (0, size - m)])
         finals.append(_launch_async("verify", (), (piece,), m, backend))
     if len(finals) == 1:
         return finals[0]
@@ -1007,10 +1112,13 @@ def verify_rsk_async(buf: np.ndarray, idxs: np.ndarray, arena, arena_ok,
     """Dispatch a cached-table launch: (96, n) R|S|kneg rows + arena slots.
 
     Same async contract as :func:`verify_bytes_async`. ``n`` must be
-    <= _CHUNK (callers chunk above that)."""
+    <= _CHUNK (callers chunk above that). Rows and slots that already
+    have the bucket's width (``pack_bytes`` and ``lookup`` given it)
+    launch as they are."""
     size = bucket_size(n)
-    if size != n:
+    if buf.shape[1] != size:
         buf = np.pad(buf, [(0, 0), (0, size - n)])
+    if idxs.shape[0] != size:
         idxs = np.pad(idxs, (0, size - n))  # slot 0 gather: harmless
     return _launch_async(
         "verify_cached", (arena, arena_ok), (idxs, buf), n, backend
@@ -1142,13 +1250,21 @@ def verify_batch(pubkeys, msgs, sigs) -> tuple[bool, np.ndarray]:
     pack_ns = disp_ns = read_ns = 0
     for lo in range(0, n, _CHUNK):
         hi = min(lo + _CHUNK, n)
+        lanes = pubkeys, msgs, sigs
+        if hi - lo < n:
+            lanes = pubkeys[lo:hi], msgs[lo:hi], sigs[lo:hi]
+        # rows and slots are made at the launch's width, so the launch
+        # has nothing to pad
+        width = bucket_size(hi - lo)
         # Pipeline host packing with device execution: each chunk is
         # dispatched as soon as it is packed, so the per-lane SHA-512 /
         # packing cost of chunk i+1 overlaps chunk i's kernel time.
         builds_before = _PUBKEY_CACHE.builds
         with _chunk_phase("pack", hi - lo) as ph:
-            buf, hok = pack_bytes(pubkeys[lo:hi], msgs[lo:hi], sigs[lo:hi])
-            hit = _PUBKEY_CACHE.lookup(pubkeys[lo:hi]) if use_cache else None
+            buf, hok = pack_bytes(*lanes, width=width)
+            hit = (
+                _PUBKEY_CACHE.lookup(lanes[0], width) if use_cache else None
+            )
             if not use_cache:
                 arena_state = "off"
             elif hit is None:

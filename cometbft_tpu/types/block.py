@@ -249,12 +249,13 @@ class Commit:
             cs.timestamp_ns,
         )
 
-    def vote_sign_bytes_many(
-        self, chain_id: str, idxs
-    ) -> list[bytes] | None:
+    def vote_sign_bytes_many(self, chain_id: str, idxs):
         """``[self.vote_sign_bytes(chain_id, i) for i in idxs]``, encoded
         together (canonical.vote_sign_bytes_many): once for the lanes that
         signed this commit's block id and once for those that signed nil.
+        Lanes that all signed the block id come back as the encoder's
+        column (``host_batch.MsgColumn``, a ``list[bytes]`` to its
+        readers); two encodings merged by index are a plain list.
         None where that encoder cannot take them; the caller then goes
         lane by lane."""
         lanes = [self.signatures[i] for i in idxs]
@@ -273,9 +274,7 @@ class Commit:
                 out[k] = sign_bytes
         return out
 
-    def _sign_bytes_of(
-        self, chain_id, block_id, lanes
-    ) -> list[bytes] | None:
+    def _sign_bytes_of(self, chain_id, block_id, lanes):
         return canonical.vote_sign_bytes_many(
             chain_id,
             canonical.PRECOMMIT_TYPE,

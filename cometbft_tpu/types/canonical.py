@@ -110,12 +110,16 @@ def vote_sign_bytes_many(
     round_: int,
     block_id,
     timestamps_ns,
-) -> list[bytes] | None:
+):
     """``[vote_sign_bytes(..., t) for t in timestamps_ns]``, byte for byte,
     encoded together: the votes of one commit differ in the timestamp
     alone, so the template is looked up once and the native engine
     (native/edbatch.cpp edb_vote_sign_bytes) writes every lane in one
-    call that keeps the interpreter lock.
+    call that keeps the interpreter lock. The lanes come back as the
+    engine wrote them, a ``host_batch.MsgColumn`` (one blob and its
+    offsets): a ``list[bytes]`` to whoever indexes, iterates, slices or
+    compares it, and never cut into lanes on the ed25519 device path,
+    whose packer reads the blob in place.
 
     None where the lanes cannot be encoded together, and the caller
     encodes them one by one: no native engine on this machine, or a
@@ -130,12 +134,7 @@ def vote_sign_bytes_many(
         timestamps = array("q", timestamps_ns)
     except OverflowError:
         return None
-    rows = host_batch.vote_sign_bytes(prefix, suffix, timestamps)
-    if rows is None:
-        return None
-    blob, offs = rows
-    offs = offs.tolist()
-    return [blob[start:end] for start, end in zip(offs, offs[1:])]
+    return host_batch.vote_sign_bytes(prefix, suffix, timestamps)
 
 
 def proposal_sign_bytes(

@@ -148,7 +148,8 @@ class TestNativePackChallenges:
             pks.append(ref.pubkey_from_seed(seed))
             msgs.append(m)
             sigs.append(ref.sign(seed, m))
-        native = ov._pack_bytes_native(pks, msgs, sigs, len(pks))
+        native = ov._pack_bytes_native(
+            pks, msgs, sigs, len(pks), len(pks))
         assert native is not None
         buf_n, ok_n = native
         # Python path, forced
@@ -181,7 +182,7 @@ class TestNativePackChallenges:
         # non-canonical S >= L
         s_big = (ov.L + 5).to_bytes(32, "little")
         sigs[7] = sigs[7][:32] + s_big
-        native = ov._pack_bytes_native(pks, msgs, sigs, 24)
+        native = ov._pack_bytes_native(pks, msgs, sigs, 24, 24)
         assert native is not None
         buf_n, ok_n = native
         lib, host_batch._lib = host_batch._lib, None

@@ -474,7 +474,8 @@ NEW_METRICS = (
     "header_basic_ms_per_header", "sign_bytes_ms_per_header",
     "kernel_wait_ms_per_header", "light_span_coverage_pct.replay",
     "valset_hash_reuse_pct.replay", "sign_bytes_batched_pct.replay",
-    "sign_bytes_batched_pct.backfill",
+    "sign_bytes_batched_pct.backfill", "pack_columnar_pct.replay",
+    "pack_columnar_pct.backfill", "arena_slot_memo_pct.replay",
 )
 
 
@@ -534,3 +535,52 @@ def test_sign_bytes_batched_pct_resolves_from_a_windows_counters(
     # a window in which no commit was checked has nothing to read
     idle = types.SimpleNamespace(counters=counters.delta(after, after))
     assert counter_ratio.read(metric, idle) is None
+
+
+def _metric_file(name):
+    with open(os.path.join(REPO, "benchmark", "metrics", name + ".json")) as f:
+        return json.load(f)
+
+
+def test_pack_and_memo_metrics_resolve_from_a_windows_counters(
+    device_route, monkeypatch
+):
+    """The benchmark's own snapshot, delta and reader: a header's lanes
+    are packed as columns, and the second commit check of one validator
+    set finds its slots in the memo (the first one, the client's check
+    of its root of trust, walked)."""
+    from benchmark.harness import counters, spec
+    from benchmark.readers import counter_ratio
+
+    monkeypatch.setattr(ov, "_PUBKEY_CACHE", ov.PubkeyTableCache(capacity=64))
+    blocks = helpers.make_light_chain(3, n_vals=N_VALS)
+    snaps = [counters.snapshot()]
+    client = _client(blocks)
+    snaps.append(counters.snapshot())
+    client.verify_light_block_at_height(2, now_after(blocks, 2))
+    snaps.append(counters.snapshot())
+    first, second = (
+        types.SimpleNamespace(counters=counters.delta(a, b))
+        for a, b in zip(snaps, snaps[1:])
+    )
+    idle = types.SimpleNamespace(counters=counters.delta(snaps[2], snaps[2]))
+    memo = _metric_file("arena_slot_memo_pct.replay")
+    assert counter_ratio.read(memo, first) == 0.0
+    assert counter_ratio.read(memo, second) == 100.0
+    assert counter_ratio.read(memo, idle) is None
+    for cell in ("replay", "backfill"):
+        packed = _metric_file(f"pack_columnar_pct.{cell}")
+        assert counter_ratio.read(packed, first) == 100.0
+        assert counter_ratio.read(packed, second) == 100.0
+        assert counter_ratio.read(packed, idle) is None
+    # and a cell loaded as the harness loads it carries them, files and all
+    for cell, names in (
+        ("light10k-replay",
+         {"pack_columnar_pct.replay", "arena_slot_memo_pct.replay"}),
+        ("qa175-relayers-backfill", {"pack_columnar_pct.backfill"}),
+    ):
+        loaded = {m["name"]: m for m in spec.load_cell(cell).per_layer}
+        assert names <= set(loaded)
+        for name in names:
+            assert loaded[name]["reader"] == "counter_ratio"
+            assert loaded[name]["layer"] == "host pack"

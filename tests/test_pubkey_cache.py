@@ -194,3 +194,181 @@ def test_eviction_churn_with_out_of_lock_builds(monkeypatch):
     # arena never exceeds capacity (evictions kept up under churn)
     assert len(cache._slots) <= cache.capacity
     del expect
+
+
+# --- the slot-vector memo ---------------------------------------------------
+# A batch whose key column repeats (a validator set's commit checks,
+# header after header) is answered from the slot vector kept for that
+# column, as long as no slot has been assigned or evicted since.
+
+
+def _tables_of(pks):
+    """The arena's content for each key, built apart from any cache."""
+    buf = np.zeros((32, verify._builder_bucket(len(pks))), np.uint8)
+    for j, pk in enumerate(pks):
+        buf[:, j] = np.frombuffer(pk, np.uint8)
+    tables, oks = verify._cached_jits()[0](buf)
+    assert np.asarray(oks)[: len(pks)].all()
+    return np.asarray(tables)
+
+
+def _assert_slots_hold(hit, want_tables, at):
+    """Every slot of ``hit`` holds, in the arena handed out WITH it, the
+    table of its own key (``want_tables[..., at[i]]``)."""
+    idxs, arena, arena_ok = hit
+    n = len(at)
+    got = np.asarray(arena[:, :, :, np.asarray(idxs[:n], np.int32)])
+    assert np.array_equal(got, want_tables[:, :, :, at])
+    assert np.asarray(arena_ok)[np.asarray(idxs[:n], np.int32)].all()
+
+
+def test_memo_answers_as_the_walk_does(fresh_cache):
+    pks, _, _ = make_batch(12)
+    pks[7] = pks[2]  # a key twice in one column
+    walked = fresh_cache.lookup(pks)
+    assert fresh_cache.hits == 0 and fresh_cache.misses == 12
+    again = fresh_cache.lookup(pks)
+    assert fresh_cache.hits == 12 and fresh_cache.builds == 1
+    assert again[0] is walked[0]  # the kept vector itself, read-only
+    assert not again[0].flags.writeable
+    assert again[1] is walked[1] and again[2] is walked[2]
+    assert list(again[0]) == [fresh_cache._slots[pk] for pk in pks]
+    assert again[0].dtype == fresh_cache.idx_dtype
+    # the same keys as bytearrays and memoryviews: the same column
+    other = [(bytes, bytearray, memoryview)[i % 3](pk)
+             for i, pk in enumerate(pks)]
+    assert fresh_cache.lookup(other)[0] is walked[0]
+    # another order, a prefix, a longer batch: other columns, walked
+    for cut in (pks[::-1], pks[:5], pks + pks[:1]):
+        got = fresh_cache.lookup(cut)
+        assert got[0] is not walked[0]
+        assert list(got[0]) == [fresh_cache._slots[pk] for pk in cut]
+    assert fresh_cache.builds == 1
+
+
+def test_memo_keeps_a_vector_per_width(fresh_cache):
+    """``width`` is the launch bucket: the slots past the keys read slot
+    0, and a column asked for at two widths is kept at both."""
+    pks, _, _ = make_batch(12)
+    wide = fresh_cache.lookup(pks, 16)
+    assert wide[0].shape == (16,) and not wide[0][12:].any()
+    narrow = fresh_cache.lookup(pks)
+    assert narrow[0].shape == (12,)
+    assert list(wide[0][:12]) == list(narrow[0])
+    assert fresh_cache.lookup(pks, 16)[0] is wide[0]
+    assert fresh_cache.lookup(pks)[0] is narrow[0]
+
+
+def test_memo_keeps_a_handful_of_columns(fresh_cache):
+    pks, _, _ = make_batch(verify._MEMO_ENTRIES + 3)
+    fresh_cache.lookup(pks)
+    columns = [pks[i:] for i in range(verify._MEMO_ENTRIES + 1)]
+    for cut in columns:
+        fresh_cache.lookup(cut)
+    assert len(fresh_cache._memo) == verify._MEMO_ENTRIES
+    hits = fresh_cache.hits
+    fresh_cache.lookup(columns[-1])  # the newest is kept
+    assert fresh_cache.hits == hits + len(columns[-1])
+
+
+@pytest.mark.parametrize("change", ["build", "eviction"])
+def test_memo_entry_dies_with_any_change_of_the_slots(monkeypatch, change):
+    """A build (a slot assigned) or an eviction between two lookups of
+    one column: the kept vector is dropped, never served, and the second
+    answer is right against the NEW arena."""
+    cache = verify.PubkeyTableCache(capacity=8)
+    monkeypatch.setattr(verify, "_PUBKEY_CACHE", cache)
+    pks, msgs, sigs = make_batch(14)
+    want = _tables_of(pks)
+    column = pks[:6] if change == "build" else pks[:8]
+    at = list(range(len(column)))
+    first = cache.lookup(column)
+    assert cache.lookup(column)[0] is first[0]
+    gen = cache._gen
+    if change == "build":
+        cache.lookup(pks[6:8])  # two free slots taken: no eviction
+        assert cache.evictions == 0
+    else:
+        cache.lookup(pks[8:14])  # six of the column's keys evicted
+        assert cache.evictions == 6
+    assert cache._gen > gen and not any(
+        kept == b"".join(column) for kept, *_ in cache._memo)
+    second = cache.lookup(column)
+    assert second[0] is not first[0]
+    assert list(second[0]) == [cache._slots[pk] for pk in column]
+    _assert_slots_hold(second, want, at)
+    if change == "build":
+        assert list(second[0]) == list(first[0])  # nothing moved
+        assert second[1] is not first[1]  # a later arena all the same
+    else:
+        assert cache.builds == 3  # the evicted keys were built again
+    # and the launch against them verifies
+    n = len(column)
+    ok, bits = verify.verify_batch(column, msgs[:n], sigs[:n])
+    assert ok and bits.all()
+
+
+def test_set_served_from_the_memo_is_not_the_oldest_by_accident(monkeypatch):
+    """Lookups answered from the memo touch no LRU entry, so the kept
+    columns' keys are touched before an eviction picks its victim: the
+    set checked last stays, the one not seen since goes."""
+    cache = verify.PubkeyTableCache(capacity=8)
+    monkeypatch.setattr(verify, "_PUBKEY_CACHE", cache)
+    pks, _, _ = make_batch(12)
+    served, idle = pks[:4], pks[4:8]
+    cache.lookup(served)
+    cache.lookup(idle)  # by the LRU alone, ``served`` is now the older
+    for _ in range(3):
+        cache.lookup(served)  # from the memo
+    assert cache.hits == 12
+    cache.lookup(pks[8:12])  # four new keys: four victims
+    assert cache.evictions == 4
+    assert all(pk in cache._slots for pk in served)
+    assert not any(pk in cache._slots for pk in idle)
+
+
+def test_memo_under_churn_never_hands_out_a_foreign_slot(monkeypatch):
+    """8 threads alternating two columns while a third party churns the
+    arena (capacity 16 against 42 keys): every answer's slots hold, in
+    the arena handed out with them, the tables of the keys asked for."""
+    cache = verify.PubkeyTableCache(capacity=16)
+    monkeypatch.setattr(verify, "_PUBKEY_CACHE", cache)
+    pks, _, _ = make_batch(42)
+    want = _tables_of(pks)
+    columns = [list(range(0, 6)), list(range(3, 9))]
+    errs, stop = [], threading.Event()
+
+    def worker(k):
+        try:
+            for turn in range(12):
+                at = columns[(k + turn) % 2]
+                hit = cache.lookup([pks[i] for i in at], 8)
+                assert hit is not None
+                _assert_slots_hold(hit, want, at)
+        except Exception as e:  # pragma: no cover
+            errs.append(e)
+
+    def churn():
+        try:
+            turn = 0
+            while not stop.is_set():
+                at = [9 + (turn * 5 + j) % 33 for j in range(6)]
+                hit = cache.lookup([pks[i] for i in at])
+                if hit is not None:
+                    _assert_slots_hold(hit, want, at)
+                turn += 1
+        except Exception as e:  # pragma: no cover
+            errs.append(e)
+
+    churner = threading.Thread(target=churn)
+    churner.start()
+    ts = [threading.Thread(target=worker, args=(k,)) for k in range(8)]
+    for t in ts:
+        t.start()
+    for t in ts:
+        t.join()
+    stop.set()
+    churner.join()
+    assert not errs, errs
+    assert cache.evictions > 0 and cache.hits > 0
+    assert len(cache._slots) <= cache.capacity

@@ -189,13 +189,17 @@ def test_unknown_flag_raises_as_the_per_lane_encoder_does():
 
 
 def test_native_rows_come_in_the_packers_layout():
-    """host_batch.vote_sign_bytes: one blob and n + 1 offsets, the
-    msgs/offs pair host_batch.pack_challenges takes."""
+    """host_batch.vote_sign_bytes: a MsgColumn, one blob and n + 1
+    offsets, the msgs/offs pair host_batch.pack_wire and
+    pack_challenges read in place."""
     timestamps = TIMESTAMP_CASES["nanos_of_every_length"] + [0, -1]
     prefix, suffix = canonical._vote_template(
         CHAIN_ID, canonical.PRECOMMIT_TYPE, 77, 1, BLOCK_ID)
-    blob, offs = host_batch.vote_sign_bytes(
+    column = host_batch.vote_sign_bytes(
         prefix, suffix, array("q", timestamps))
+    assert isinstance(column, host_batch.MsgColumn)
+    blob, offs = column.blob, column.offs
+    assert type(blob) is bytes and offs.typecode == "Q"
     assert len(offs) == len(timestamps) + 1
     assert offs[0] == 0 and offs[-1] == len(blob)
     assert [blob[a:b] for a, b in zip(offs, offs[1:])] == [
