@@ -16,6 +16,7 @@ from ..libs import sync as libsync
 import time
 
 from ..libs import log as _log
+from ..libs import metrics as libmetrics
 from ..libs import netstats as libnetstats
 from ..libs.bits import BitArray
 from ..p2p.base_reactor import ChannelDescriptor, Reactor
@@ -49,6 +50,9 @@ STATE_CHANNEL = 0x20
 DATA_CHANNEL = 0x21
 VOTE_CHANNEL = 0x22
 VOTE_SET_BITS_CHANNEL = 0x23
+
+# consensus_vote_phase_seconds{phase} of a received message, by channel
+_RECEIVE_PHASE = {DATA_CHANNEL: "receive_data", VOTE_CHANNEL: "receive_vote"}
 
 
 class PeerState:
@@ -368,6 +372,15 @@ class ConsensusReactor(Reactor):
     # -- receive dispatch (reactor.go Receive:233) -------------------------
 
     def receive(self, ch_id: int, peer, msg_bytes: bytes) -> None:
+        # decode -> enqueue on the peer's thread, by channel: a blocked
+        # put (the FSM's inbox is full) is inside it
+        with libmetrics.consensus_phase(
+            _RECEIVE_PHASE.get(ch_id, "receive_state"), "reactor.receive",
+            ch=ch_id,
+        ):
+            self._receive(ch_id, peer, msg_bytes)
+
+    def _receive(self, ch_id: int, peer, msg_bytes: bytes) -> None:
         msg = ser.loads(msg_bytes)
         ps: PeerState = peer.get("consensus_peer_state")
         if ps is None:

@@ -14,6 +14,7 @@ own-vote signing ``sign_vote:2355``/``sign_add_vote:2426``.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import os
@@ -40,7 +41,7 @@ from ..types.event_bus import (
     NopEventBus,
 )
 from ..types.part_set import PartSetError
-from ..types.vote import Proposal, Vote
+from ..types.vote import Proposal, Vote, votes_sign_bytes
 from ..types.vote_set import ConflictingVoteError, VoteSet
 from ..types import serialization as ser
 from .height_vote_set import HeightVoteSet
@@ -200,6 +201,17 @@ class ConsensusState(BaseService):
         # merged inbox: ("peer"|"internal"|"timeout", payload)
         self._queue: queue.Queue = queue.Queue(maxsize=1000)
         self._preverify_warned_types: set[str] = set()
+        # when each queued peer vote was handed over (time_ns), oldest
+        # first: appended on the peer's thread before the put, popped by
+        # the receive routine for as many votes as a drain holds, so the
+        # drain reads its votes' enqueue -> drain waits without a field
+        # on the WAL-logged MsgInfo
+        self._vote_enqueued_ns: collections.deque = collections.deque()
+        # phase -> [ns, n] over the drain being processed (None outside
+        # one): the phases that recur per item (libmetrics.
+        # observe_consensus_phase_sum)
+        # lockfree: receive-routine-only — installed and cleared by _process_batch on the FSM-owner thread, which is also the only thread that adds to it
+        self._drain_phases: dict | None = None
         self.ticker = TimeoutTicker()
         self._n_started = 0
         # lockfree: True only during the single-threaded startup replay, before any routine exists; steady-state constant False
@@ -276,6 +288,7 @@ class ConsensusState(BaseService):
     # -- message entry points (thread-safe) --------------------------------
 
     def add_vote_from_peer(self, vote: Vote, peer_id: str) -> None:
+        self._vote_enqueued_ns.append(time.time_ns())
         self._queue.put(("peer", MsgInfo(VoteMessage(vote), peer_id)))
 
     def set_proposal_from_peer(self, proposal: Proposal, peer_id: str) -> None:
@@ -458,9 +471,74 @@ class ConsensusState(BaseService):
         """WAL-log + dispatch one drained batch (the single-writer body
         shared by the receive thread and the simnet pump).  Returns True
         on the quit sentinel."""
-        memo = None
+        votes = [
+            payload.msg.vote for kind, payload in items
+            if kind == "peer" and isinstance(payload.msg, VoteMessage)
+        ]
+        items_total = libmetrics.node_metrics().consensus_drain_items_total
+        items_total.labels("vote").inc(len(votes))
+        items_total.labels("other").inc(len(items) - len(votes))
+        with libmetrics.consensus_phase(
+            "drain", "consensus.drain", items=len(items), votes=len(votes)
+        ):
+            self._note_queue_wait(len(votes))
+            self._drain_phases = {}
+            try:
+                return self._process_drained(items, votes)
+            finally:
+                phases, self._drain_phases = self._drain_phases, None
+                for phase, (ns, n) in phases.items():
+                    libmetrics.observe_consensus_phase_sum(
+                        phase, "consensus." + phase, ns, n,
+                        event=phase != "finalize",  # that one is a span
+                    )
+
+    def _note_queue_wait(self, n_votes: int) -> None:
+        """The drained votes' waits, enqueue (the peer's thread) -> this
+        drain, as one sum: ``consensus_vote_phase_seconds{queue_wait}``
+        over ``consensus_drain_items_total{vote}`` is the wait a vote,
+        and ``consensus.queue_wait`` one span a drain from the oldest
+        vote's enqueue to now. A vote queued by another way than
+        add_vote_from_peer has no stamp and is left out."""
+        now = time.time_ns()
+        stamps = self._vote_enqueued_ns
+        waited = oldest = n = 0
         try:
-            memo = self._preverify_queued_votes(items)
+            for _ in range(n_votes):
+                t = stamps.popleft()
+                oldest = oldest or t
+                waited += max(0, now - t)
+                n += 1
+        except IndexError:
+            pass
+        if not n:
+            return
+        libmetrics.node_metrics().consensus_vote_phase_seconds.labels(
+            "queue_wait"
+        ).observe(waited / 1e9)
+        if libtrace.enabled():
+            sp = libtrace.begin(
+                "consensus.queue_wait", parent=libtrace.current(),
+                votes=n, sum_ns=waited,
+            )
+            sp.start_ns = min(oldest, now)
+            sp.end()
+
+    def _phase_add(self, phase: str, ns: int) -> None:
+        """``ns`` more of ``phase`` in the drain being processed."""
+        phases = self._drain_phases
+        if phases is not None:
+            cell = phases.get(phase)
+            if cell is None:
+                phases[phase] = [ns, 1]
+            else:
+                cell[0] += ns
+                cell[1] += 1
+
+    def _process_drained(self, items: list, votes: list) -> bool:
+        memos = None
+        try:
+            memos = self._preverify_queued_votes(votes)
         except Exception as e:
             # Preverification is an optimization only — votes fall back
             # to per-signature host verification — but a persistent
@@ -473,17 +551,23 @@ class ConsensusState(BaseService):
 
                 traceback.print_exc()
         try:
-            for kind, payload in items:
+            logged = 0  # items[:logged] are in the WAL already
+            for i, (kind, payload) in enumerate(items):
                 if kind == "quit":
                     return True
                 try:
-                    if kind == "peer":
-                        self.wal.write(payload)
+                    t0 = time.perf_counter_ns()
+                    if i < logged:
+                        pass
+                    elif kind == "peer":
+                        logged = i + self._current_vote_run(items, i)
+                        self.wal.write_many([p for _, p in items[i:logged]])
                     elif kind == "internal":
                         self.wal.write_sync(payload)
                     elif kind == "timeout":
                         self.wal.write(payload)
-                    self._locked_dispatch(kind, payload)
+                    self._phase_add("wal_write", time.perf_counter_ns() - t0)
+                    self._timed_dispatch(kind, payload)
                 except FatalConsensusError as e:
                     # Fail-stop (state.go finalizeCommit panics): the
                     # node must not keep running on a half-applied
@@ -505,13 +589,67 @@ class ConsensusState(BaseService):
 
                     traceback.print_exc()
         finally:
-            if memo:
-                # Memo entries are scoped to THIS drain window: votes
-                # dropped before reaching signature verification (bad
-                # rounds, failed pre-checks) must not let peer-
-                # controlled entries accumulate for the height.
+            # Memo entries are scoped to THIS drain window: votes
+            # dropped before reaching signature verification (bad
+            # rounds, failed pre-checks) must not let peer-
+            # controlled entries accumulate for the height.
+            for memo in memos or ():
                 memo.clear()
         return False
+
+    def _current_vote_run(self, items: list, i: int) -> int:
+        """How many items from ``items[i]`` (a peer message) on are logged
+        with it in one WAL write: the unbroken run of peer votes of the
+        height the FSM is at, or the one message alone. Each is still in
+        the WAL before it is handled, in arrival order, and nothing of
+        another kind or height is written ahead of its turn. If one of
+        the run commits the height, the rest of the run (votes of that
+        same height: late precommits, stale prevotes) stands before the
+        #ENDHEIGHT marker and a crash replay leaves them out, as it may:
+        ``last_commit`` is rebuilt from the stored seen commit, and they
+        were extras to it."""
+        height = self.rs.height
+        n = 0
+        for kind, payload in items[i:]:
+            if not (
+                kind == "peer"
+                and isinstance(payload.msg, VoteMessage)
+                and payload.msg.vote.height == height
+            ):
+                break
+            n += 1
+        return max(n, 1)
+
+    def _timed_dispatch(self, kind: str, payload) -> None:
+        """_locked_dispatch, its time booked to the phase of the item's
+        kind (vote_step / block_part / timeout) less what the phases
+        inside it booked for themselves (add_vote, finalize, publish)."""
+        if kind == "timeout":
+            phase = "timeout"
+        elif kind == "txs_available":
+            phase = "block_part"
+        elif isinstance(payload.msg, VoteMessage):
+            phase = "vote_step"
+        else:
+            phase = "block_part"
+        phases = self._drain_phases
+        inner0 = self._inner_ns(phases)
+        t0 = time.perf_counter_ns()
+        try:
+            self._locked_dispatch(kind, payload)
+        finally:
+            took = time.perf_counter_ns() - t0
+            took -= self._inner_ns(phases) - inner0
+            self._phase_add(phase, max(0, took))
+
+    @staticmethod
+    def _inner_ns(phases: dict | None) -> int:
+        if not phases:
+            return 0
+        return sum(
+            phases[p][0] for p in ("add_vote", "finalize", "publish")
+            if p in phases
+        )
 
     @contextlib.contextmanager
     def _deferred_events(self):
@@ -533,6 +671,7 @@ class ConsensusState(BaseService):
         finally:
             # lockfree: same FSM-owner plane as the install above; the reset runs on the same thread that installed the buffer
             self._pending_events = None
+            t0 = time.perf_counter_ns()
             for fn, args in pending:
                 try:
                     fn(*args)
@@ -542,6 +681,8 @@ class ConsensusState(BaseService):
                     import traceback
 
                     traceback.print_exc()
+            if pending:
+                self._phase_add("publish", time.perf_counter_ns() - t0)
 
     def _locked_dispatch(self, kind: str, payload) -> None:
         """One FSM step under the state mutex, with event delivery
@@ -574,21 +715,21 @@ class ConsensusState(BaseService):
         else:
             fn(*args)
 
-    def _preverify_queued_votes(self, items) -> dict | None:
-        """One batched signature launch for all drained current-height votes.
+    def _preverify_queued_votes(self, votes: list):
+        """One batched signature launch for all drained votes of the
+        current height, and for the late precommits of the height before
+        it that complete ``rs.last_commit``.
 
-        Results land in the HeightVoteSet's signature memo keyed by the
-        exact (pubkey, sign bytes, signature) triple; admission later pops
-        them. Mirrors vote_set.go:216-231's per-vote verify with the
-        device-batched layout of SURVEY §7(d). Never changes consensus
-        state — a memo miss just falls back to the per-vote host verify.
+        Results land in the signature memo of the VoteSets the votes will
+        be admitted to, keyed by the exact (pubkey, sign bytes, signature)
+        triple; admission later pops them. Mirrors vote_set.go:216-231's
+        per-vote verify with the device-batched layout of SURVEY §7(d).
+        Never changes consensus state — a memo miss just falls back to
+        the per-vote host verify. Returns the memos it filled (the
+        drain clears them when it ends), or None.
         """
         from ..crypto import coalesce as crypto_coalesce
 
-        votes: list[Vote] = []
-        for kind, payload in items:
-            if kind == "peer" and isinstance(payload.msg, VoteMessage):
-                votes.append(payload.msg.vote)
         # A lone drained vote is worth pre-verifying only when a
         # coalescer is routed: the batch verifier then submits it as a
         # coalescer lane that merges with concurrent callers' windows
@@ -603,58 +744,84 @@ class ConsensusState(BaseService):
             height = rs.height
             val_set = rs.validators
             memo = rs.votes.sig_memo
+            extensions_enabled = rs.votes.extensions_enabled
+            last_commit = rs.last_commit
             chain_id = self.state.chain_id
-        triples: list[tuple] = []
-        for vote in votes:
-            if vote.height != height:
-                continue
-            val = val_set.get_by_index(vote.validator_index)
-            if val is None:
-                continue
-            triples.append(
-                (val.pub_key, vote.sign_bytes(chain_id), vote.signature)
-            )
-            if (
-                rs.votes.extensions_enabled
-                and vote.msg_type == canonical.PRECOMMIT_TYPE
-                and not vote.block_id.is_nil()
-                and vote.extension_signature
-            ):
-                triples.append(
-                    (
-                        val.pub_key,
-                        vote.extension_sign_bytes(chain_id),
-                        vote.extension_signature,
-                    )
+        # late precommits go to last_commit, whose memo is the one the
+        # height before shared (None for a reconstructed commit: those
+        # votes verify at admission as before)
+        late_memo = getattr(last_commit, "sig_memo", None)
+        with libmetrics.consensus_phase(
+            "preverify", "consensus.preverify", height=height
+        ) as phase:
+            # lanes: (memo, pub_key, sign bytes, signature)
+            lanes: list[tuple] = []
+            with libmetrics.consensus_phase(
+                "sign_bytes", "consensus.sign_bytes"
+            ) as enc:
+                wanted = []
+                for vote in votes:
+                    if vote.height == height:
+                        val = val_set.get_by_index(vote.validator_index)
+                        if val is not None:
+                            wanted.append((memo, vote, val.pub_key))
+                    elif (
+                        late_memo is not None
+                        and vote.height + 1 == height
+                        and vote.msg_type == canonical.PRECOMMIT_TYPE
+                    ):
+                        val = last_commit.val_set.get_by_index(
+                            vote.validator_index
+                        )
+                        if val is not None:
+                            wanted.append((late_memo, vote, val.pub_key))
+                encoded = votes_sign_bytes(
+                    chain_id, [vote for _, vote, _ in wanted]
                 )
-        if len(triples) < min_lanes:
-            return None
-        try:
-            # Keyed off the SET: a heterogeneous ed25519+sr25519 valset
-            # pre-verifies through MixedBatchVerifier (one launch)
-            # instead of losing batching to a foreign-key TypeError.
-            from ..libs import devledger
+                for (to, vote, pub_key), sign_bytes in zip(wanted, encoded):
+                    lanes.append((to, pub_key, sign_bytes, vote.signature))
+                    if (
+                        extensions_enabled
+                        and vote.msg_type == canonical.PRECOMMIT_TYPE
+                        and not vote.block_id.is_nil()
+                        and vote.extension_signature
+                    ):
+                        lanes.append((
+                            to, pub_key,
+                            vote.extension_sign_bytes(chain_id),
+                            vote.extension_signature,
+                        ))
+                enc.set(lanes=len(lanes))
+            phase.set(lanes=len(lanes), route="none")
+            if len(lanes) < min_lanes:
+                return None
+            try:
+                # Keyed off the SET: a heterogeneous ed25519+sr25519 valset
+                # pre-verifies through MixedBatchVerifier (one launch)
+                # instead of losing batching to a foreign-key TypeError.
+                from ..libs import devledger
 
-            verifier = crypto_batch.create_commit_batch_verifier(val_set)
-            for pub_key, sign_bytes, sig in triples:
-                verifier.add(pub_key, sign_bytes, sig)
-            with devledger.caller_class("consensus-vote"):
-                _, bits = verifier.verify()
-        except (ValueError, TypeError):
-            # no batch backend for some key type (e.g. secp256k1):
-            # skip pre-verification — admission falls back to per-vote
-            # verify, never crashes the receive loop
-            return None
-        for (pub_key, sign_bytes, sig), ok in zip(triples, bits):
-            memo[(pub_key.bytes(), sign_bytes, sig)] = bool(ok)
-        if libtrace.enabled():
-            libtrace.event(
-                "consensus.preverify",
-                height=height,
-                lanes=len(triples),
-                ok=sum(1 for b in bits if b),
-            )
-        return memo
+                verifier = crypto_batch.create_commit_batch_verifier(val_set)
+                verifier.add_many(
+                    [lane[1] for lane in lanes],
+                    [lane[2] for lane in lanes],
+                    [lane[3] for lane in lanes],
+                )
+                with devledger.caller_class("consensus-vote"):
+                    _, bits = verifier.verify()
+            except (ValueError, TypeError):
+                # no batch backend for some key type (e.g. secp256k1):
+                # skip pre-verification — admission falls back to per-vote
+                # verify, never crashes the receive loop
+                return None
+            for (to, pub_key, sign_bytes, sig), ok in zip(lanes, bits):
+                to[(pub_key.bytes(), sign_bytes, sig)] = bool(ok)
+            route = verifier.route or "host"
+            libmetrics.node_metrics().consensus_preverify_lanes_total.labels(
+                route
+            ).inc(len(lanes))
+            phase.set(route=route, ok=sum(1 for b in bits if b))
+        return (memo,) if late_memo is None else (memo, late_memo)
 
     def _handle_msg(self, mi: MsgInfo) -> None:
         msg, peer_id = mi.msg, mi.peer_id
@@ -675,10 +842,13 @@ class ConsensusState(BaseService):
 
     def _handle_timeout(self, ti: TimeoutInfo) -> None:
         rs = self.rs
+        timeouts = libmetrics.node_metrics().consensus_timeouts_total
         if ti.height != rs.height or ti.round < rs.round or (
             ti.round == rs.round and ti.step < int(rs.step)
         ):
+            timeouts.labels("stale").inc()
             return  # stale
+        timeouts.labels("acted").inc()
         step = RoundStep(ti.step)
         if step == RoundStep.NEW_HEIGHT:
             self._enter_new_round(ti.height, 0)
@@ -1448,14 +1618,25 @@ class ConsensusState(BaseService):
         rs = self.rs
         if rs.height != height or rs.step != RoundStep.COMMIT:
             return
+        t0 = time.perf_counter_ns()
+        started = getattr(self, "_height_started", None)
         try:
-            self._finalize_commit_locked(height)
+            with libtrace.span("consensus.finalize", height=height):
+                self._finalize_commit_locked(height)
         except FatalConsensusError:
             raise
         except Exception as e:
             raise FatalConsensusError(
                 f"failure finalizing height {height}: {e!r}"
             ) from e
+        finally:
+            self._phase_add("finalize", time.perf_counter_ns() - t0)
+        if started is not None:
+            # one observation a committed height: the per-height count
+            # the vote path's sums are divided by
+            libmetrics.node_metrics().consensus_vote_phase_seconds.labels(
+                "height"
+            ).observe(max(0.0, self._clock.monotonic() - started))
 
     def _finalize_commit_locked(self, height: int) -> None:
         rs = self.rs
@@ -1655,7 +1836,7 @@ class ConsensusState(BaseService):
         ):
             if rs.step != RoundStep.NEW_HEIGHT or rs.last_commit is None:
                 return False
-            if not rs.last_commit.add_vote(vote):
+            if not self._timed_admit(rs.last_commit.add_vote, vote):
                 return False
             if libtrace.enabled():
                 libtrace.event(
@@ -1700,7 +1881,7 @@ class ConsensusState(BaseService):
             if not self.block_exec.verify_vote_extension(vote, self.state):
                 raise ConsensusError("rejected vote extension")
 
-        added = rs.votes.add_vote(vote, peer_id)
+        added = self._timed_admit(rs.votes.add_vote, vote, peer_id)
         if not added:
             return False
         libhealth.record(
@@ -1728,6 +1909,14 @@ class ConsensusState(BaseService):
         else:
             self._on_precommit_added(vote)
         return True
+
+    def _timed_admit(self, add_vote, *args) -> bool:
+        """A VoteSet's admission of one vote, booked to ``add_vote``."""
+        t0 = time.perf_counter_ns()
+        try:
+            return add_vote(*args)
+        finally:
+            self._phase_add("add_vote", time.perf_counter_ns() - t0)
 
     def _on_prevote_added(self, vote: Vote) -> None:
         rs = self.rs

@@ -115,13 +115,22 @@ class WAL:
 
     # -- write -------------------------------------------------------------
 
-    def write(self, msg) -> None:
+    @staticmethod
+    def _frame(msg) -> bytes:
         payload = wal_codec.dumps(msg)
         if len(payload) > MAX_MSG_BYTES:
             raise WALError(f"msg of {len(payload)}B exceeds WAL limit")
-        frame = _FRAME.pack(zlib.crc32(payload), len(payload)) + payload
+        return _FRAME.pack(zlib.crc32(payload), len(payload)) + payload
+
+    def write(self, msg) -> None:
+        self.write_many((msg,))
+
+    def write_many(self, msgs) -> None:
+        """``write`` for each of ``msgs`` in order, as one write and one
+        flush: all of them are with the OS when the call returns."""
+        frames = b"".join(map(self._frame, msgs))
         with self._mtx:
-            self.group.write(frame)
+            self.group.write(frames)
             self.group.flush()
 
     def write_sync(self, msg, overlapped: bool = False) -> None:
@@ -240,6 +249,9 @@ class NopWAL:
         return False
 
     def write(self, msg) -> None:
+        pass
+
+    def write_many(self, msgs) -> None:
         pass
 
     def write_sync(self, msg, overlapped: bool = False) -> None:

@@ -31,6 +31,10 @@ class BatchVerifier:
     reference falls back to (types/validation.go:243-250).
     """
 
+    # where the last ``verify`` ran, for a backend that can tell
+    # ("device" / "host"); None for one that cannot
+    route: str | None = None
+
     def add(self, pub_key, msg: bytes, signature: bytes) -> None:
         raise NotImplementedError
 
@@ -352,8 +356,12 @@ class Ed25519BatchVerifier(BatchVerifier):
         # lanes (crypto/coalesce._launch_inner), never this batch's.
         co = coalesce.active()
         if co is not None and n < co.max_lanes:
-            bits = co.try_verify(self._pubkeys, self._msgs, self._sigs)
+            routes: list = []
+            bits = co.try_verify(
+                self._pubkeys, self._msgs, self._sigs, routes
+            )
             if bits is not None:
+                self.route = "device" if "device" in routes else "host"
                 _observe("ed25519-coalesce", t0, len(bits))
                 return all(bits), list(bits)
             # not served (stopped, tripped, deadline): the lone paths
@@ -361,7 +369,9 @@ class Ed25519BatchVerifier(BatchVerifier):
             # case a stalled-device ticket timeout) must not be charged
             # to the backend that then answers
             t0 = _time.perf_counter()
-        if n < host_batch_threshold():
+        on_host = n < host_batch_threshold()
+        self.route = "host" if on_host else "device"
+        if on_host:
             # the native RLC host batch (one multiscalar mult, the voi
             # algorithm), which itself falls back to sequential OpenSSL
             # when the engine can't build

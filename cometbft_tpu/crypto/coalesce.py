@@ -192,7 +192,8 @@ class _Ticket:
     """
 
     __slots__ = (
-        "n", "caller", "t_submit", "wait_span", "_done", "_bits", "_exc",
+        "n", "caller", "t_submit", "wait_span", "route", "_done", "_bits",
+        "_exc",
     )
 
     def __init__(self, n: int, caller: int = 0):
@@ -202,6 +203,9 @@ class _Ticket:
         # ledger's attribution key
         self.caller = caller
         self.t_submit = time.perf_counter()
+        # where the window that resolved this ticket ran ("device" /
+        # "host"); a rescue's host verdicts leave it "host" too
+        self.route = "host"
         # coalesce.queue_wait: begun here, on the submitter's thread and
         # under its innermost span (one request's spans stay one tree),
         # ended by the executor when it pops the ticket's window
@@ -517,8 +521,13 @@ class VerifyCoalescer(BaseService):
             self._cv.notify_all()
         return tickets
 
-    def try_verify(self, pubkeys, msgs, sigs) -> list[bool] | None:
+    def try_verify(
+        self, pubkeys, msgs, sigs, routes: list | None = None
+    ) -> list[bool] | None:
         """submit + wait with a clean not-routed signal.
+
+        ``routes``, where given, takes each served ticket's route
+        ("device" / "host": where its window ran).
 
         Returns the per-lane bits, or None when the coalescer cannot
         serve the request (stopped, ticket failed, wait expired) — the
@@ -560,6 +569,8 @@ class VerifyCoalescer(BaseService):
                 wait_s, capped = max(rem, 0.0), True
             try:
                 bits.extend(ticket.result(wait_s))
+                if routes is not None:
+                    routes.append(ticket.route)
             except TimeoutError:
                 # A ticket outliving the FULL result bound means the
                 # executor is wedged (stuck dispatch) or a transient
@@ -1082,6 +1093,7 @@ class VerifyCoalescer(BaseService):
         anchor = t_launch if t_launch is not None else now
         bw = bx = 0  # consensus-caller wait/exec sums (the budget row)
         for ticket, lo, n in staged:
+            ticket.route = backend
             ticket.resolve([bool(b) for b in bits[lo : lo + n]])
             m.coalesce_wait_seconds.observe(now - ticket.t_submit)
             if not ledger_on:

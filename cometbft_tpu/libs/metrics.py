@@ -298,7 +298,7 @@ _LABEL_VALUE_RULES: dict[str, "_re.Pattern"] = {
     # (they export as bounded 16-hex-char prefixes in JSON bodies only)
     "stage": _re.compile(
         r"^(proposal_wait|gossip|verify_queue|verify_execute|hash"
-        r"|wal_fsync|apply|residual"
+        r"|wal_fsync|spec_exec|apply|residual"
         r"|admit|gossip_send|gossip_recv|proposal|commit"
         r"|admit_to_send|hop|admit_to_proposal|proposal_to_commit)$"
     ),
@@ -456,6 +456,65 @@ class NodeMetrics:
         )
         for path in ("batched", "per_lane"):
             self.commit_sign_bytes_lanes_total.labels(path)
+        # the consensus vote path, phase by phase (libs/metrics.
+        # consensus_phase): receive_vote / receive_data / receive_state
+        # (the reactor's decode -> enqueue, one observation a message, on
+        # the peer's thread); on the receive routine one observation a
+        # drain each of drain (the whole micro-batch), queue_wait (the
+        # drained votes' enqueue -> drain waits, summed), preverify (the
+        # batched launch; sign_bytes inside it), wal_write, add_vote (the
+        # VoteSets' admission), vote_step (what an admitted vote moves in
+        # the FSM), block_part (proposal and parts), publish (deferred
+        # event delivery: the HasVote fan-out) and timeout, each a sum
+        # over the drain's items; finalize once a height. The phases
+        # from preverify on do not nest in one another, so they tile a
+        # drain. Same buckets as the verify phases.
+        self.consensus_vote_phase_seconds = r.histogram(
+            "consensus_vote_phase_seconds",
+            "Consensus vote path per-phase latency",
+            label_names=("phase",),
+            buckets=(
+                0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
+                0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5,
+            ),
+        )
+        # tocks the FSM handled: "acted" (it moved a step on one) or
+        # "stale" (the round had gone on before it fired)
+        self.consensus_timeouts_total = r.counter(
+            "consensus_timeouts_total",
+            "Consensus timeouts handled by the FSM, by what they did",
+            label_names=("result",),
+        )
+        for result in ("acted", "stale"):
+            self.consensus_timeouts_total.labels(result)
+        # what the receive routine drained, by kind of item
+        self.consensus_drain_items_total = r.counter(
+            "consensus_drain_items_total",
+            "Items the consensus receive routine drained, by kind",
+            label_names=("kind",),
+        )
+        for kind in ("vote", "other"):
+            self.consensus_drain_items_total.labels(kind)
+        # lanes a drain pre-verified in one launch, by where the launch
+        # ran (the verifier's own report: a coalescer window's route, or
+        # the lone batch's side of the static cut)
+        self.consensus_preverify_lanes_total = r.counter(
+            "consensus_preverify_lanes_total",
+            "Vote signatures pre-verified by the receive routine, by route",
+            label_names=("route",),
+        )
+        for route in ("device", "host"):
+            self.consensus_preverify_lanes_total.labels(route)
+        # how a VoteSet's admission had a vote's signature answered:
+        # "memo" (the drain's pre-verification, accept or refuse) or
+        # "verified_singly" (no memo entry: one signature verified alone)
+        self.vote_sig_admissions_total = r.counter(
+            "types_vote_sig_admissions_total",
+            "Vote signature checks at VoteSet admission by what answered",
+            label_names=("answer",),
+        )
+        for answer in ("memo", "verified_singly"):
+            self.vote_sig_admissions_total.labels(answer)
         # how ops/verify.pack_bytes built a wire buffer: "columnar" (key,
         # signature and message columns joined by C-level calls and one
         # native call that writes the transposed rows) or "per_lane" (a
@@ -1125,6 +1184,34 @@ def light_phase(phase: str, span_name: str, **fields) -> TimedPhase:
         node_metrics().light_verify_phase_seconds.labels(phase),
         span_name, **fields,
     )
+
+
+def consensus_phase(phase: str, span_name: str, **fields) -> TimedPhase:
+    """One phase of the consensus vote path: ``consensus_vote_phase_seconds
+    {phase}`` and the ``span_name`` span."""
+    return TimedPhase(
+        node_metrics().consensus_vote_phase_seconds.labels(phase),
+        span_name, **fields,
+    )
+
+
+def observe_consensus_phase_sum(phase: str, span_name: str, ns: int,
+                                n: int, event: bool = True) -> None:
+    """A phase that recurs per item of a drain (a WAL write, an
+    admission), summed over the drain: one histogram observation of the
+    sum, and one after-the-fact ``span_name`` event under the drain's
+    span (``dur_ns``, ``n``): a span an item would fill the ring."""
+    node_metrics().consensus_vote_phase_seconds.labels(phase).observe(
+        ns / 1e9
+    )
+    if event and libtrace.enabled():
+        libtrace.event(span_name, dur_ns=ns, n=n)
+
+
+def observe_vote_admission(answer: str) -> None:
+    """One signature check at VoteSet admission:
+    types_vote_sig_admissions_total{answer}."""
+    node_metrics().vote_sig_admissions_total.labels(answer).inc()
 
 
 def observe_valset_hash(reused: bool) -> None:

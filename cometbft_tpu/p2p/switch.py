@@ -199,6 +199,22 @@ class Switch(BaseService):
             origin_id=self._health_origin,
             logger=self.logger,
         )
+        self.admit_peer(peer)
+        if self.logger is not None:
+            self.logger.info(
+                "peer connected",
+                peer=peer.id[:10],
+                outbound=peer.outbound,
+                addr=peer.socket_addr,
+            )
+        return peer
+
+    def admit_peer(self, peer) -> None:
+        """Enter a built peer into the table and hand it to every
+        reactor (init_peer, start, add_peer): the tail of a completed
+        handshake, and the way in for a peer object that came by no
+        connection (it keeps the peer contract the reactors use:
+        id/start/stop/is_running/send/try_send/get/set)."""
         with self._peers_mtx:
             # A handshake that completed as (or after) on_stop snapshotted
             # the peer table would admit a peer nobody ever stops — its
@@ -221,14 +237,6 @@ class Switch(BaseService):
             with self._peers_mtx:
                 self._peers.pop(peer.id, None)
             raise
-        if self.logger is not None:
-            self.logger.info(
-                "peer connected",
-                peer=peer.id[:10],
-                outbound=peer.outbound,
-                addr=peer.socket_addr,
-            )
-        return peer
 
     def stop_and_remove_peer(self, peer: Peer, reason) -> None:
         with self._peers_mtx:

@@ -152,6 +152,35 @@ class Vote:
             raise VoteError("extension too large")
 
 
+def votes_sign_bytes(chain_id: str, votes) -> list[bytes]:
+    """``[v.sign_bytes(chain_id) for v in votes]``, byte for byte. Votes
+    that share type, height, round and block id differ in the timestamp
+    alone, exactly like a commit's lanes, and are encoded together by the
+    encoder those get (canonical.vote_sign_bytes_many); where it cannot
+    take them, and for a vote alone in its group, vote by vote."""
+    out: list = [None] * len(votes)
+    groups: dict = {}
+    for i, v in enumerate(votes):
+        psh = v.block_id.part_set_header
+        groups.setdefault(
+            (v.msg_type, v.height, v.round, v.block_id.hash, psh.total,
+             psh.hash), []
+        ).append(i)
+    for idxs in groups.values():
+        first = votes[idxs[0]]
+        encoded = None
+        if len(idxs) > 1:
+            encoded = canonical.vote_sign_bytes_many(
+                chain_id, first.msg_type, first.height, first.round,
+                first.block_id, [votes[i].timestamp_ns for i in idxs],
+            )
+        if encoded is None:
+            encoded = [votes[i].sign_bytes(chain_id) for i in idxs]
+        for i, sign_bytes in zip(idxs, encoded):
+            out[i] = sign_bytes
+    return out
+
+
 @dataclass(slots=True)
 class Proposal:
     """Block proposal (types/proposal.go)."""
@@ -189,6 +218,7 @@ class Proposal:
 
 __all__ = [
     "Vote",
+    "votes_sign_bytes",
     "Proposal",
     "VoteError",
     "BLOCK_ID_FLAG_ABSENT",

@@ -23,6 +23,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 
 from ..crypto import batch as crypto_batch
+from ..libs import metrics as libmetrics
 from ..libs import sync as libsync
 from ..libs.bits import BitArray
 from . import canonical
@@ -160,6 +161,12 @@ class VoteSet:
         with self._mtx:
             libsync.lockset_note("VoteSet.votes")
             self._check_vote(vote)
+            existing = self.votes[vote.validator_index]
+            if existing is not None and existing.block_id == vote.block_id:
+                # a second copy of the vote held (_check_vote compared
+                # the signatures): known, so nothing to verify
+                # (vote_set.go addVote returns before vote.Verify)
+                return False
             val = self.val_set.get_by_index(vote.validator_index)
             self._verify_vote_signature(vote, val.pub_key)
             return self._admit(vote, val)
@@ -334,6 +341,7 @@ class VoteSet:
     def _verify_vote_signature(self, vote: Vote, pub_key) -> None:
         if self.sig_memo is None:
             # No memo: the reference per-vote path, untouched.
+            libmetrics.observe_vote_admission("verified_singly")
             if self._needs_extension(vote):
                 vote.verify_vote_and_extension(self.chain_id, pub_key)
             else:
@@ -348,6 +356,9 @@ class VoteSet:
         ok = self.sig_memo.pop(
             (pub_key.bytes(), vote.sign_bytes(self.chain_id), vote.signature),
             None,
+        )
+        libmetrics.observe_vote_admission(
+            "verified_singly" if ok is None else "memo"
         )
         if ok is False:
             raise VoteError(
