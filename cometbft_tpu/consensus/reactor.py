@@ -265,13 +265,12 @@ class ConsensusReactor(Reactor):
         # threads are live — mutating FSM state needs the state mutex,
         # exactly like the reference (reactor.go:109 takes conS.mtx
         # before updateToState). update_to_state publishes the new-step
-        # event; deferral delivers it only after the mutex is released,
+        # event; the region delivers it only after the mutex is released,
         # same as the FSM receive loop.
-        with self.cs._deferred_events():
-            with self.cs._mtx:
-                self.cs.update_to_state(state)
-                self.cs.reconstruct_last_commit_if_needed(state)
-                self.cs.do_wal_catchup = not skip_wal
+        with self.cs._fsm_region():
+            self.cs.update_to_state(state)
+            self.cs.reconstruct_last_commit_if_needed(state)
+            self.cs.do_wal_catchup = not skip_wal
         self.cs.start()
 
     # -- event re-broadcast (reactor.go:415-530) ---------------------------

@@ -16,7 +16,6 @@ from __future__ import annotations
 
 import collections
 import contextlib
-import dataclasses
 import os
 import queue
 import threading
@@ -27,6 +26,7 @@ import time
 from ..config import ConsensusConfig
 from ..crypto import batch as crypto_batch
 from ..libs import health as libhealth
+from ..libs import lockprof as liblockprof
 from ..libs import metrics as libmetrics
 from ..libs import trace as libtrace
 from ..libs.events import EventSwitch
@@ -246,6 +246,18 @@ class ConsensusState(BaseService):
         # delivery (replay, init wiring, direct test calls).
         self._pending_events: list | None = None
 
+        # What get_round_state() hands to threads that do not hold the
+        # state mutex: a shallow copy of rs, stored by _fsm_region before
+        # it lets the mutex go. One reference, swapped whole.
+        # lockfree: written only under 'consensus.state' (by _fsm_region, before its release); readers take one GIL-atomic reference read of an object that is never written again, and copy it
+        self._rs_published: RoundState | None = None
+        # get_round_state() calls so far, [published, locked]: tallied
+        # with no lock (a counter's inc takes one, and 150 per-peer
+        # routines met on it), bridged into the registry once a drain
+        # lockfree: single-slot GIL-atomic increments from any thread (the libs/lockprof posture: a lost increment under a rare race costs one tally); read only by the FSM's owner at the bridge
+        self._rs_reads = [0, 0]
+        self._rs_reads_bridged = [0, 0]
+
         # Construction is single-threaded, but update_to_state mutates
         # the same FSM fields the live commit chain does — taking the
         # (reentrant, uncontended) mutex here keeps one machine-checked
@@ -257,10 +269,9 @@ class ConsensusState(BaseService):
         # _locked_dispatch defers it: 'consensus.state' must never be
         # held while a subscriber callback runs, and the runtime
         # lock-order sanitizer checks exactly that.
-        with self._deferred_events():
-            with self._mtx:
-                self.update_to_state(state)
-                self.reconstruct_last_commit_if_needed(state)
+        with self._fsm_region():
+            self.update_to_state(state)
+            self.reconstruct_last_commit_if_needed(state)
 
     def add_block_committed_hook(self, fn) -> None:
         self._on_block_committed.append(fn)
@@ -277,9 +288,22 @@ class ConsensusState(BaseService):
 
     def get_round_state(self) -> RoundState:
         """Shallow snapshot — never the live object (state.go GetRoundState
-        returns rs.Copy(); field-by-field mutation would tear readers)."""
-        with self._mtx:
-            return dataclasses.replace(self.rs)
+        returns rs.Copy(); field-by-field mutation would tear readers).
+
+        A thread that does not hold the state mutex takes no lock: it
+        copies what the last _fsm_region published before it let the
+        mutex go, which is what the mutex would have shown it at that
+        release and the only moment it could have had the mutex anyway.
+        A thread inside its own critical section (the FSM's owner
+        mid-region, a replay, a test under ``with cs._mtx``) reads its
+        own writes from the live object."""
+        published = self._rs_published
+        if published is None or self._mtx._is_owned():
+            self._rs_reads[1] += 1
+            with self._mtx:
+                return self.rs.copy()
+        self._rs_reads[0] += 1
+        return published.copy()
 
     def height(self) -> int:
         with self._mtx:
@@ -326,11 +350,10 @@ class ConsensusState(BaseService):
         # routine) writes it under the mutex, and uniform discipline is
         # what keeps the inferred guard machine-checkable. Replay
         # handlers publish; deferral delivers after release.
-        with self._deferred_events():
-            # cometlint: disable=CLNT009,CLNT010 -- single-threaded startup: replay I/O and event delivery run before any routine exists to contend for the mutex
-            with self._mtx:
-                if self.do_wal_catchup and not isinstance(self.wal, NopWAL):
-                    self._catchup_replay()
+        # cometlint: disable=CLNT009,CLNT010 -- single-threaded startup: replay I/O and event delivery run before any routine exists to contend for the mutex
+        with self._fsm_region():
+            if self.do_wal_catchup and not isinstance(self.wal, NopWAL):
+                self._catchup_replay()
         self.ticker.start()
         if self.sim_driven:
             # the simnet scheduler pumps the inbox (process_pending) and
@@ -492,6 +515,18 @@ class ConsensusState(BaseService):
                         phase, "consensus." + phase, ns, n,
                         event=phase != "finalize",  # that one is a span
                     )
+                # what this drain waited for locks (the state mutex
+                # first) and who read the round state meanwhile are in
+                # the registry beside its phases, and not only after a
+                # scrape
+                liblockprof.sample(libmetrics.node_metrics())
+                for i, path in enumerate(("published", "locked")):
+                    n = self._rs_reads[i]
+                    if n != self._rs_reads_bridged[i]:
+                        libmetrics.observe_round_state_reads(
+                            path, n - self._rs_reads_bridged[i]
+                        )
+                        self._rs_reads_bridged[i] = n
 
     def _note_queue_wait(self, n_votes: int) -> None:
         """The drained votes' waits, enqueue (the peer's thread) -> this
@@ -652,22 +687,35 @@ class ConsensusState(BaseService):
         )
 
     @contextlib.contextmanager
-    def _deferred_events(self):
-        """Collect _publish deliveries while the body runs; drain them
-        only after it exits. Wrapped around every ``with self._mtx:``
-        region that can reach a publish, so subscriber callbacks never
-        run while 'consensus.state' is held (the runtime lock-order
+    def _fsm_region(self):
+        """The one shape of every region that can write FSM state: hold
+        'consensus.state' over the body, publish the round state's
+        snapshot before letting the mutex go, and deliver the events the
+        body published only after it.
+
+        Events: _publish deliveries are collected while the body runs
+        and drained past the release, so subscriber callbacks never run
+        while 'consensus.state' is held (the runtime lock-order
         sanitizer observes acquisition edges and checks exactly this).
+        The snapshot is stored first, so a listener that calls
+        get_round_state() sees the step it was told of.
+
         Nests: an inner region feeds the buffer already live, and only
-        the outermost exit — past every mutex release — delivers."""
+        the outermost exit publishes the snapshot and, past every mutex
+        release, delivers."""
         if self._pending_events is not None:
-            yield
+            with self._mtx:
+                yield
             return
         pending: list = []
         # lockfree: FSM-owner plane — exactly one thread drives the FSM at any moment (init wiring -> on_start replay -> blocksync switch_to_consensus -> receive routine), and ownership hand-offs carry happens-before edges (Thread.start, the start/stop queue handshake), so the buffer is never installed or drained concurrently
         self._pending_events = pending
         try:
-            yield
+            with self._mtx:
+                try:
+                    yield
+                finally:
+                    self._rs_published = self.rs.copy()
         finally:
             # lockfree: same FSM-owner plane as the install above; the reset runs on the same thread that installed the buffer
             self._pending_events = None
@@ -697,15 +745,14 @@ class ConsensusState(BaseService):
         RPC/reactor observers see the same data marginally later —
         ordering among events is preserved.
         """
-        with self._deferred_events():
-            with self._mtx:
-                libsync.lockset_note("ConsensusState.state")
-                if kind == "timeout":
-                    self._handle_timeout(payload)
-                elif kind == "txs_available":
-                    self._handle_txs_available()
-                else:
-                    self._handle_msg(payload)
+        with self._fsm_region():
+            libsync.lockset_note("ConsensusState.state")
+            if kind == "timeout":
+                self._handle_timeout(payload)
+            elif kind == "txs_available":
+                self._handle_txs_available()
+            else:
+                self._handle_msg(payload)
 
     def _publish(self, fn, *args) -> None:
         """Route one event through the deferral buffer (or deliver
@@ -1018,7 +1065,7 @@ class ConsensusState(BaseService):
         # that PUBLISHED the event, not whatever rs ends up at
         self._publish(
             self.evsw.fire_event, EVENT_NEW_ROUND_STEP,
-            dataclasses.replace(rs),
+            rs.copy(),
         )
 
     # -- NewRound (state.go:1018) ------------------------------------------
@@ -1597,7 +1644,7 @@ class ConsensusState(BaseService):
             rs.proposal_block_parts = PartSet(maj23.part_set_header)
             self._publish(
                 self.evsw.fire_event, EVENT_VALID_BLOCK,
-                dataclasses.replace(rs),
+                rs.copy(),
             )
         self._try_finalize_commit(height)
 
@@ -1946,7 +1993,7 @@ class ConsensusState(BaseService):
                     rs.proposal_block_parts = PartSet(maj23.part_set_header)
                 self._publish(
                     self.evsw.fire_event, EVENT_VALID_BLOCK,
-                    dataclasses.replace(rs),
+                    rs.copy(),
                 )
 
         if rs.round < vote.round and prevotes.has_two_thirds_any():

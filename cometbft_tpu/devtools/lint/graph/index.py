@@ -535,6 +535,53 @@ class ProgramIndex:
                 ld = self.lock_for_attr(b, expr.attr)
                 if ld is not None:
                     return ld
+        if isinstance(expr, ast.Call):
+            # with self._region(): a @contextmanager helper that yields
+            # inside ``with <lock>:`` holds that lock for the caller's body
+            for callee in self.resolve_call(expr, fi, {}):
+                ld = self._lock_held_at_yield(callee)
+                if ld is not None:
+                    return ld
+        return None
+
+    def _lock_held_at_yield(self, fi: FuncInfo) -> LockDef | None:
+        """The innermost lock a ``@contextmanager`` function holds
+        lexically at its first ``yield`` under one, else None."""
+        node = fi.node
+        if not any(
+            (d.id if isinstance(d, ast.Name) else getattr(d, "attr", None))
+            == "contextmanager"
+            for d in node.decorator_list
+        ):
+            return None
+
+        def walk(n, held: LockDef | None) -> LockDef | None:
+            if isinstance(n, (ast.With, ast.AsyncWith)):
+                for item in n.items:
+                    if not isinstance(item.context_expr, ast.Call):
+                        held = (
+                            self.resolve_lock_expr(item.context_expr, fi)
+                            or held
+                        )
+                children = n.body
+            elif isinstance(
+                n, (ast.FunctionDef, ast.AsyncFunctionDef, ast.Lambda)
+            ):
+                return None
+            elif isinstance(n, (ast.Yield, ast.YieldFrom)):
+                return held
+            else:
+                children = ast.iter_child_nodes(n)
+            for child in children:
+                found = walk(child, held)
+                if found is not None:
+                    return found
+            return None
+
+        for stmt in node.body:
+            found = walk(stmt, None)
+            if found is not None:
+                return found
         return None
 
     def all_methods(self, types: set[str]) -> list[FuncInfo]:

@@ -214,6 +214,9 @@ _SITE_IDX: dict[str, int] = {"?": 0}
 # route through the sync factories it instruments (recursion), and it
 # serializes slow-path site interning only, never the record path
 _sites_mtx = threading.Lock()  # cometlint: disable=CLNT001 -- see above
+# serializes sample()'s watermark read-add-write between its two
+# callers; only ever tried, never waited for
+_sample_mtx = threading.Lock()  # cometlint: disable=CLNT001 -- see above
 
 
 def reset() -> None:
@@ -400,6 +403,18 @@ def sample(metrics=None) -> None:
     from . import metrics as libmetrics
 
     m = metrics if metrics is not None else libmetrics.node_metrics()
+    # the scrape path and the consensus receive routine (once a drain)
+    # both bridge: whoever finds the other at it leaves it to them, so
+    # no delta is added twice
+    if not _sample_mtx.acquire(False):
+        return
+    try:
+        _bridge(m)
+    finally:
+        _sample_mtx.release()
+
+
+def _bridge(m) -> None:
     wm = getattr(m, "_lockprof_wm", None)
     if wm is None:
         wm = m._lockprof_wm = {}
@@ -409,12 +424,15 @@ def sample(metrics=None) -> None:
         c = _contended[slot]
         if w == 0 and h == 0 and c == 0 and slot not in wm:
             continue  # never-contended slot: keep the scrape sparse
+        # a lock seen for the first time gets all three series, so that
+        # a never-contended lock's wait reads 0 and not nothing
+        first = slot not in wm
         seen_w, seen_h, seen_c = wm.get(slot, (0, 0, 0))
         name = NAMES[slot]
-        if w > seen_w:
-            m.lock_wait.labels(name).inc((w - seen_w) / 1e9)
-        if h > seen_h:
-            m.lock_hold.labels(name).inc((h - seen_h) / 1e9)
-        if c > seen_c:
-            m.lock_contended.labels(name).inc(c - seen_c)
+        if first or w > seen_w:
+            m.lock_wait.labels(name).inc(max(0, w - seen_w) / 1e9)
+        if first or h > seen_h:
+            m.lock_hold.labels(name).inc(max(0, h - seen_h) / 1e9)
+        if first or c > seen_c:
+            m.lock_contended.labels(name).inc(max(0, c - seen_c))
         wm[slot] = (w, h, c)

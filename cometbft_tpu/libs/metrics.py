@@ -495,6 +495,18 @@ class NodeMetrics:
         )
         for kind in ("vote", "other"):
             self.consensus_drain_items_total.labels(kind)
+        # get_round_state() calls by what answered: "published" (the
+        # snapshot the FSM's owner stored before it let the state mutex
+        # go; no lock taken) or "locked" (the caller holds the mutex, or
+        # nothing was published yet: a fresh copy under it). One tally a
+        # call, handed over by the receive routine once a drain
+        self.consensus_round_state_reads_total = r.counter(
+            "consensus_round_state_reads_total",
+            "ConsensusState.get_round_state() calls by path",
+            label_names=("path",),
+        )
+        for path in ("published", "locked"):
+            self.consensus_round_state_reads_total.labels(path)
         # lanes a drain pre-verified in one launch, by where the launch
         # ran (the verifier's own report: a coalescer window's route, or
         # the lone batch's side of the static cut)
@@ -1212,6 +1224,14 @@ def observe_vote_admission(answer: str) -> None:
     """One signature check at VoteSet admission:
     types_vote_sig_admissions_total{answer}."""
     node_metrics().vote_sig_admissions_total.labels(answer).inc()
+
+
+def observe_round_state_reads(path: str, n: int) -> None:
+    """``n`` more ``ConsensusState.get_round_state()`` calls answered by
+    ``path``: consensus_round_state_reads_total{path}. The calls tally
+    themselves with no lock; the receive routine hands the tally over
+    once a drain."""
+    node_metrics().consensus_round_state_reads_total.labels(path).inc(n)
 
 
 def observe_valset_hash(reused: bool) -> None:

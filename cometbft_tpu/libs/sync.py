@@ -505,6 +505,11 @@ class _InstrumentedMutex:
             return self._holder is not None
         return self._lock.locked()
 
+    def _is_owned(self) -> bool:
+        # the stdlib RLock's ownership probe, as the profiled tier has
+        # it: does the calling thread hold this lock
+        return self._holder == threading.get_ident()
+
     def _note_acquired(self, me: int) -> None:
         if self._reentrant and self._holder == me:
             self._depth += 1

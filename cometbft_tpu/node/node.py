@@ -572,8 +572,7 @@ class Node(BaseService):
         out, inb = self.switch.num_peers()
         self.metrics.peers.set(out + inb)
         self.metrics.mempool_size.set(self.mempool.size())
-        with self.consensus._mtx:
-            vals = self.consensus.rs.validators
+        vals = self.consensus.get_round_state().validators
         if vals is not None:
             self.metrics.validators.set(len(vals))
             self.metrics.validators_power.set(vals.total_voting_power())

@@ -24,6 +24,7 @@ from cometbft_tpu.consensus.wal import MsgInfo
 from cometbft_tpu.crypto import batch as cbatch
 from cometbft_tpu.crypto import host_batch
 from cometbft_tpu.crypto.keys import Ed25519PrivKey
+from cometbft_tpu.libs import lockprof as liblockprof
 from cometbft_tpu.libs import metrics as libmetrics
 from cometbft_tpu.libs import trace as libtrace
 from cometbft_tpu.libs.metrics import NodeMetrics
@@ -46,6 +47,8 @@ ROUND_METRICS = [
     "vote_sign_bytes_ms_per_height", "vote_admit_ms_per_height",
     "sig_memo_hit_pct.round", "reactor_receive_ms_per_vote",
     "wal_write_ms_per_height.round", "vote_span_coverage_pct.round",
+    "round_state_published_read_pct.round",
+    "state_mutex_wait_ms_per_vote.round",
 ]
 
 
@@ -487,6 +490,9 @@ def rendered_after_a_drain():
     drain and one received vote."""
     m = NodeMetrics()
     libmetrics.push_node_metrics(m)
+    # as while a node runs: the locks' ledger on, which a drain bridges
+    was_profiling = liblockprof.enabled()
+    liblockprof.enable()
     cs, parts, pvs, valset = _node(8)
     try:
         cs._process_batch(_items([_vote(pvs, valset, i) for i in range(4)]))
@@ -501,6 +507,8 @@ def rendered_after_a_drain():
     finally:
         helpers.stop_node(cs, parts)
         libmetrics.pop_node_metrics(m)
+        if not was_profiling:
+            liblockprof.disable()
     return {
         line.rpartition(" ")[0] for line in text.splitlines()
         if line and not line.startswith("#")

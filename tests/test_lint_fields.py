@@ -198,6 +198,66 @@ class TestGuardInference:
             {"fix.update"}
         )
 
+    def test_contextmanager_helper_holds_its_lock_for_the_body(
+        self, tmp_path
+    ):
+        # ``with self._region():`` — a @contextmanager method that yields
+        # inside ``with self._mtx:`` — guards the caller's body like the
+        # lock itself (the ConsensusState._fsm_region shape); a helper
+        # that yields outside its lock guards nothing
+        files = {
+            "state.py": """
+            import contextlib
+            import threading
+            from .libs import sync as libsync
+
+            class Switch:
+                def __init__(self):
+                    self._mtx = libsync.RLock("fix.state")
+                    self.peers = {}
+                    self.seen = {}
+                    self._thr = threading.Thread(
+                        target=self._loop, daemon=True
+                    )
+
+                @contextlib.contextmanager
+                def _region(self):
+                    with self._mtx:
+                        try:
+                            yield
+                        finally:
+                            self.seen = dict(self.peers)
+
+                @contextlib.contextmanager
+                def _after(self):
+                    with self._mtx:
+                        pass
+                    yield
+
+                def _loop(self):
+                    with self._region():
+                        self.peers["a"] = 1
+
+                def update(self):
+                    with self._region():
+                        self.peers["b"] = 2
+            """
+        }
+        fields = run_fields(tmp_path, files)
+        assert fields.findings() == [
+        ], [f.render() for f in fields.findings()]
+        guard = frozenset({"fix.state"})
+        assert fields.fields[("Switch", "peers")].guard == guard
+        assert fields.fields[("Switch", "seen")].guard == guard
+        files["state.py"] = files["state.py"].replace(
+            "with self._region():\n                        "
+            'self.peers["b"]',
+            "with self._after():\n                        "
+            'self.peers["b"]',
+        )
+        fs = run_fields(tmp_path, files).findings()
+        assert codes(fs) == ["CLNT012"], [f.render() for f in fs]
+
     def test_init_only_field_is_out_of_scope(self, tmp_path):
         # written once during construction, read everywhere: immutable
         # after publication, no guard needed

@@ -393,12 +393,60 @@ def test_pipelined_matches_serial_app_state(fresh_metrics):
     assert serial_store[b"alpha"] == b"1"
 
 
+def test_budget_credits_an_overlapped_fsync_beside_the_stages():
+    """An fsync flagged ``overlapped`` inside a height's commit window is
+    that height's ``overlapped.wal_fsync`` and no part of its stages;
+    one outside every window is nobody's; an unflagged one is the
+    ``wal_fsync`` stage. Pure function, hand-made events."""
+    ms = 1_000_000
+    events = [
+        {"event": "consensus.commit", "height": 7, "node": 1,
+         "ts": 100 * ms, "dur_ns": 50 * ms},
+        {"event": "wal.fsync", "ts": 70 * ms, "dur_ns": 4 * ms,
+         "overlapped": 1},
+        {"event": "wal.fsync", "ts": 80 * ms, "dur_ns": 2 * ms,
+         "overlapped": 0},
+        {"event": "wal.fsync", "ts": 120 * ms, "dur_ns": 8 * ms,
+         "overlapped": 1},
+    ]
+    hv = libhealth.budget_from_events(events)[7]
+    assert hv["overlapped"] == {"wal_fsync": 0.004, "spec_exec": 0.0}
+    assert hv["stages"]["wal_fsync"] == pytest.approx(0.002)
+    assert sum(hv["stages"].values()) == pytest.approx(hv["latency_s"])
+    del events[1]
+    assert "overlapped" not in libhealth.budget_from_events(events)[7]
+
+
+def test_budget_credits_a_speculation_longer_than_its_span():
+    """A winning speculation's execute time counts in its span's stages
+    up to the span's length; the rest is ``overlapped.spec_exec``. One
+    that fits its span is all stage and no credit."""
+    ms = 1_000_000
+    commit = {"event": "consensus.commit", "height": 3, "node": 1,
+              "ts": 100 * ms, "dur_ns": 20 * ms}
+
+    def budget_with(spec_ms):
+        return libhealth.budget_from_events([commit, {
+            "event": "spec.exec", "ts": 90 * ms,
+            "outcome": libhealth.SPEC_HIT, "dur_ns": spec_ms * ms,
+        }])[3]
+
+    hv = budget_with(50)
+    assert hv["stages"]["spec_exec"] == pytest.approx(0.020)
+    assert hv["overlapped"] == {"wal_fsync": 0.0, "spec_exec": 0.03}
+    assert sum(hv["stages"].values()) == pytest.approx(hv["latency_s"])
+    hv = budget_with(5)
+    assert hv["stages"]["spec_exec"] == pytest.approx(0.005)
+    assert "overlapped" not in hv
+
+
 def test_pipelined_burst_reconciles_and_covers(fresh_metrics):
     """Live pipelined 4-validator burst over a routed coalescer: the
     new workers (cs-commit-writer, cs-spec-exec, cs-prestage-next)
     declare caller classes — ZERO ``other``-classed verify lanes — the
-    ledger reconciles, speculation hits land, overlapped fsyncs are
-    credited without double-counting, and the budget stages still
+    ledger reconciles, speculation hits land (as many ring rows as
+    the metric counts), what the budget credits as overlapped is beside
+    the stages and no more than was recorded, and the stages still
     explain >= 90% of each commit's measured latency."""
     from cometbft_tpu.crypto import coalesce as crypto_coalesce
 
@@ -430,6 +478,9 @@ def test_pipelined_burst_reconciles_and_covers(fresh_metrics):
         crypto_coalesce.pop_active(co)
         co.stop()
         bud = libhealth.budget()
+        spec_rows = [
+            e for e in libhealth.recorder().dump() if e["event"] == "spec.exec"
+        ]
         libhealth.disable()
         libhealth.set_ring_capacity(libhealth.DEFAULT_RING_SIZE)
         libhealth.reset()
@@ -457,18 +508,31 @@ def test_pipelined_burst_reconciles_and_covers(fresh_metrics):
         for hv in bud["heights"]:
             stage_sum = sum(hv["stages"].values())
             assert stage_sum >= 0.9 * hv["latency_s"], hv
-        # overlapped credit shows up and never exceeds what one height
-        # could have run off-thread (no double-count: the sidebar is
-        # NOT part of the tiling sum above)
-        overlapped = [
-            hv["overlapped"]
-            for hv in bud["heights"]
-            if "overlapped" in hv
-        ]
-        assert overlapped, "no height credited overlapped fsync/apply"
-        for ov in overlapped:
-            assert set(ov) == {"wal_fsync", "spec_exec"}
-            assert ov["wal_fsync"] >= 0 and ov["spec_exec"] >= 0
+        # every consumed speculation left one ring row, by outcome: the
+        # same counts as the metric's, whatever the load
+        for code, name in libhealth._SPEC_OUTCOMES.items():
+            rows = [e for e in spec_rows if e["outcome"] == code]
+            assert len(rows) == c[name], (name, len(rows), c)
+        # Where the budget credits work that ran off the FSM's thread
+        # (these nodes have no WAL to fsync, so: a winning speculation's
+        # execute time beyond what its span can hold), it is beside the
+        # stages (the sidebar is NOT part of the tiling sum above) and
+        # no more than was recorded. WHETHER a height is credited is
+        # wall clock against wall clock: a warm 0.4 s burst had none
+        # (tier-1, PR 31's run), so the credit itself is pinned on the
+        # hand-made event lists above.
+        hit_s = sum(
+            e["dur_ns"] for e in spec_rows
+            if e["outcome"] == libhealth.SPEC_HIT
+        ) / 1e9
+        credited_s = 0.0
+        for hv in bud["heights"]:
+            ov = hv.get("overlapped")
+            if ov is not None:
+                assert set(ov) == {"wal_fsync", "spec_exec"}
+                assert ov["wal_fsync"] == 0 and ov["spec_exec"] >= 0
+                credited_s += ov["spec_exec"]
+        assert credited_s <= hit_s + 1e-6, (credited_s, hit_s)
     finally:
         devledger.reset()
         devledger.enable() if was else devledger.disable()
