@@ -41,7 +41,7 @@ from ..types.event_bus import (
     NopEventBus,
 )
 from ..types.part_set import PartSetError
-from ..types.vote import Proposal, Vote, votes_sign_bytes
+from ..types.vote import Proposal, Vote, VoteError, votes_sign_bytes
 from ..types.vote_set import ConflictingVoteError, VoteSet
 from ..types import serialization as ser
 from .height_vote_set import HeightVoteSet
@@ -658,7 +658,8 @@ class ConsensusState(BaseService):
     def _timed_dispatch(self, kind: str, payload) -> None:
         """_locked_dispatch, its time booked to the phase of the item's
         kind (vote_step / block_part / timeout) less what the phases
-        inside it booked for themselves (add_vote, finalize, publish)."""
+        inside it booked for themselves (verify_extension, add_vote,
+        finalize, publish)."""
         if kind == "timeout":
             phase = "timeout"
         elif kind == "txs_available":
@@ -682,7 +683,8 @@ class ConsensusState(BaseService):
         if not phases:
             return 0
         return sum(
-            phases[p][0] for p in ("add_vote", "finalize", "publish")
+            phases[p][0]
+            for p in ("verify_extension", "add_vote", "finalize", "publish")
             if p in phases
         )
 
@@ -825,14 +827,27 @@ class ConsensusState(BaseService):
                 encoded = votes_sign_bytes(
                     chain_id, [vote for _, vote, _ in wanted]
                 )
-                for (to, vote, pub_key), sign_bytes in zip(wanted, encoded):
-                    lanes.append((to, pub_key, sign_bytes, vote.signature))
-                    if (
+                def extended(vote) -> bool:
+                    return (
                         extensions_enabled
                         and vote.msg_type == canonical.PRECOMMIT_TYPE
                         and not vote.block_id.is_nil()
-                        and vote.extension_signature
+                        and bool(vote.extension_signature)
+                    )
+
+                ext_votes = [v for _, v, _ in wanted if extended(v)]
+                if ext_votes:
+                    # each vote keeps its encoding: the lanes below, the
+                    # pre-app check and admission's memo key read it
+                    with libmetrics.consensus_phase(
+                        "ext_sign_bytes", "consensus.ext_sign_bytes",
+                        lanes=len(ext_votes),
                     ):
+                        for vote in ext_votes:
+                            vote.extension_sign_bytes(chain_id)
+                for (to, vote, pub_key), sign_bytes in zip(wanted, encoded):
+                    lanes.append((to, pub_key, sign_bytes, vote.signature))
+                    if extended(vote):
                         lanes.append((
                             to, pub_key,
                             vote.extension_sign_bytes(chain_id),
@@ -1920,13 +1935,25 @@ class ConsensusState(BaseService):
                 != bytes(self.priv_validator_pub_key.address())
             )
         ):
-            # App-level extension check (sig checked in VoteSet).
+            # The extension's signature, then the app's own check: the
+            # app is never shown an extension whose signature has not
+            # verified (state.go:2207-2215).
             val = rs.validators.get_by_index(vote.validator_index)
             if val is None:
                 return False
-            vote.verify_extension(self.state.chain_id, val.pub_key)
-            if not self.block_exec.verify_vote_extension(vote, self.state):
-                raise ConsensusError("rejected vote extension")
+            t0 = time.perf_counter_ns()
+            try:
+                self._verify_extension_signature(
+                    vote, val.pub_key, rs.votes.sig_memo
+                )
+                if not self.block_exec.verify_vote_extension(
+                    vote, self.state
+                ):
+                    raise ConsensusError("rejected vote extension")
+            finally:
+                self._phase_add(
+                    "verify_extension", time.perf_counter_ns() - t0
+                )
 
         added = self._timed_admit(rs.votes.add_vote, vote, peer_id)
         if not added:
@@ -1956,6 +1983,28 @@ class ConsensusState(BaseService):
         else:
             self._on_precommit_added(vote)
         return True
+
+    def _verify_extension_signature(self, vote: Vote, pub_key, memo) -> None:
+        """vote.verify_extension, answered by the drain's batched
+        pre-verification where it holds the exact (pubkey, extension
+        sign-bytes, extension signature) triple: True goes on, False
+        refuses as a failed verify does, no entry verifies singly. A
+        read, not a pop: the VoteSet pops the entry at admission, where
+        the address binding is enforced whatever answered here."""
+        ok = None
+        if memo is not None:
+            ok = memo.get((
+                pub_key.bytes(),
+                vote.extension_sign_bytes(self.state.chain_id),
+                vote.extension_signature,
+            ))
+        libmetrics.observe_extension_sig_check(
+            "verified_singly" if ok is None else "memo"
+        )
+        if ok is None:
+            vote.verify_extension(self.state.chain_id, pub_key)
+        elif not ok:
+            raise VoteError("invalid extension signature")
 
     def _timed_admit(self, add_vote, *args) -> bool:
         """A VoteSet's admission of one vote, booked to ``add_vote``."""

@@ -527,6 +527,17 @@ class NodeMetrics:
         )
         for answer in ("memo", "verified_singly"):
             self.vote_sig_admissions_total.labels(answer)
+        # how a precommit's extension signature was answered, at the
+        # pre-app check of ConsensusState._add_vote and at VoteSet
+        # admission: "memo" (the drain's pre-verification, accept or
+        # refuse) or "verified_singly" (no entry: a verify of its own)
+        self.extension_sig_checks_total = r.counter(
+            "consensus_extension_sig_checks_total",
+            "Vote extension signature checks by what answered",
+            label_names=("answer",),
+        )
+        for answer in ("memo", "verified_singly"):
+            self.extension_sig_checks_total.labels(answer)
         # how ops/verify.pack_bytes built a wire buffer: "columnar" (key,
         # signature and message columns joined by C-level calls and one
         # native call that writes the transposed rows) or "per_lane" (a
@@ -1224,6 +1235,13 @@ def observe_vote_admission(answer: str) -> None:
     """One signature check at VoteSet admission:
     types_vote_sig_admissions_total{answer}."""
     node_metrics().vote_sig_admissions_total.labels(answer).inc()
+
+
+def observe_extension_sig_check(answer: str) -> None:
+    """One extension-signature check, before the app's
+    VerifyVoteExtension or at VoteSet admission:
+    consensus_extension_sig_checks_total{answer}."""
+    node_metrics().extension_sig_checks_total.labels(answer).inc()
 
 
 def observe_round_state_reads(path: str, n: int) -> None:

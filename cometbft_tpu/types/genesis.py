@@ -88,6 +88,7 @@ class GenesisDoc:
                 "genesis_time_ns": self.genesis_time_ns,
                 "chain_id": self.chain_id,
                 "initial_height": self.initial_height,
+                "consensus_params": self.consensus_params.to_dict(),
                 "app_hash": self.app_hash.hex(),
                 "app_state": self.app_state,
                 "validators": [
@@ -122,6 +123,9 @@ class GenesisDoc:
             chain_id=d["chain_id"],
             genesis_time_ns=int(d.get("genesis_time_ns", 0)),
             initial_height=int(d.get("initial_height", 1)),
+            consensus_params=ConsensusParams.from_dict(
+                d.get("consensus_params", {})
+            ),
             validators=validators,
             app_hash=bytes.fromhex(d.get("app_hash", "")),
             app_state=d.get("app_state", {}),

@@ -5,7 +5,7 @@ Hashed into Header.ConsensusHash; updatable by the ABCI app per block.
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field as dc_field, replace
+from dataclasses import asdict, dataclass, field as dc_field, replace
 
 from ..crypto import tmhash
 from . import proto
@@ -80,6 +80,27 @@ class ConsensusParams:
             raise ValueError("validator.pub_key_types cannot be empty")
         if self.abci.vote_extensions_enable_height < 0:
             raise ValueError("abci.vote_extensions_enable_height negative")
+
+    def to_dict(self) -> dict:
+        """The genesis file's ``consensus_params`` object (types/params.go
+        ConsensusParams as genesis.json carries it): every section, every
+        field by its name here."""
+        return asdict(self)
+
+    @classmethod
+    def from_dict(cls, d: dict) -> "ConsensusParams":
+        """to_dict's inverse; a section or field left out keeps its
+        default, an unknown one is an error."""
+        out = cls()
+        for section, fields in d.items():
+            if section not in cls.__slots__:
+                raise ValueError(f"unknown consensus_params section {section!r}")
+            values = {f: tuple(v) if isinstance(v, list) else v
+                      for f, v in fields.items()}
+            out = replace(
+                out, **{section: replace(getattr(out, section), **values)}
+            )
+        return out
 
     def update(self, updates) -> "ConsensusParams":
         """Apply an ABCI ConsensusParams update (partial)."""

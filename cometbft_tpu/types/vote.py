@@ -9,6 +9,7 @@ Sign bytes are the canonical length-delimited protobuf of CanonicalVote
 from __future__ import annotations
 
 from dataclasses import dataclass
+from dataclasses import field as dc_field
 
 from . import canonical
 from .block import (
@@ -39,6 +40,13 @@ class Vote:
     extension: bytes = b""
     extension_signature: bytes = b""
 
+    # ((chain_id, height, round, extension), sign bytes): the extension's
+    # sign-bytes as last encoded, answered again only for the same four.
+    # An in-memory cache like Commit._hash: never compared or serialized.
+    _ext_sign_bytes: tuple | None = dc_field(
+        default=None, compare=False, repr=False
+    )
+
     def is_nil(self) -> bool:
         return self.block_id.is_nil()
 
@@ -53,9 +61,16 @@ class Vote:
         )
 
     def extension_sign_bytes(self, chain_id: str) -> bytes:
-        return canonical.vote_extension_sign_bytes(
-            chain_id, self.height, self.round, self.extension
-        )
+        """CanonicalVoteExtension sign bytes, encoded once a vote: the
+        drain's pre-verification, the pre-app check and admission's memo
+        key all ask for the same bytes of the same object."""
+        key = (chain_id, self.height, self.round, self.extension)
+        kept = self._ext_sign_bytes
+        if kept is None or kept[0] != key:
+            kept = self._ext_sign_bytes = (
+                key, canonical.vote_extension_sign_bytes(*key)
+            )
+        return kept[1]
 
     def verify(self, chain_id: str, pub_key) -> None:
         """Signature + address check (types/vote.go:210-232).
@@ -143,7 +158,9 @@ class Vote:
             raise VoteError("missing signature")
         if len(self.signature) > 64:
             raise VoteError("signature too long")
-        if self.msg_type == canonical.PREVOTE_TYPE and self.extension:
+        if self.msg_type == canonical.PREVOTE_TYPE and (
+            self.extension or self.extension_signature
+        ):
             raise VoteError("prevotes cannot carry extensions")
         if self.is_nil() and (self.extension or self.extension_signature):
             # issue #8487: nil precommits must not carry extension data

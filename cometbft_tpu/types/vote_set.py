@@ -343,6 +343,7 @@ class VoteSet:
             # No memo: the reference per-vote path, untouched.
             libmetrics.observe_vote_admission("verified_singly")
             if self._needs_extension(vote):
+                libmetrics.observe_extension_sig_check("verified_singly")
                 vote.verify_vote_and_extension(self.chain_id, pub_key)
             else:
                 vote.verify(self.chain_id, pub_key)
@@ -373,6 +374,9 @@ class VoteSet:
                     vote.extension_signature,
                 ),
                 None,
+            )
+            libmetrics.observe_extension_sig_check(
+                "verified_singly" if ext_ok is None else "memo"
             )
             if ext_ok is False:
                 raise VoteError(
