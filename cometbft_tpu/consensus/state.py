@@ -28,6 +28,7 @@ from ..crypto import batch as crypto_batch
 from ..libs import health as libhealth
 from ..libs import lockprof as liblockprof
 from ..libs import metrics as libmetrics
+from ..libs import profile as libprofile
 from ..libs import trace as libtrace
 from ..libs.events import EventSwitch
 from ..libs.service import BaseService
@@ -504,6 +505,9 @@ class ConsensusState(BaseService):
         with libmetrics.consensus_phase(
             "drain", "consensus.drain", items=len(items), votes=len(votes)
         ):
+            # this thread's CPU over the phase's extent, on every drain
+            # (the span's cpu_ns is the same reading, tracing on only)
+            cpu0 = time.thread_time_ns()
             self._note_queue_wait(len(votes))
             self._drain_phases = {}
             try:
@@ -516,10 +520,12 @@ class ConsensusState(BaseService):
                         event=phase != "finalize",  # that one is a span
                     )
                 # what this drain waited for locks (the state mutex
-                # first) and who read the round state meanwhile are in
-                # the registry beside its phases, and not only after a
-                # scrape
-                liblockprof.sample(libmetrics.node_metrics())
+                # first), who read the round state meanwhile and what
+                # CPU each thread role used are in the registry beside
+                # its phases, and not only after a scrape
+                metrics = libmetrics.node_metrics()
+                liblockprof.sample(metrics)
+                libprofile.sample(metrics)
                 for i, path in enumerate(("published", "locked")):
                     n = self._rs_reads[i]
                     if n != self._rs_reads_bridged[i]:
@@ -527,6 +533,7 @@ class ConsensusState(BaseService):
                             path, n - self._rs_reads_bridged[i]
                         )
                         self._rs_reads_bridged[i] = n
+                libmetrics.observe_drain_cpu(time.thread_time_ns() - cpu0)
 
     def _note_queue_wait(self, n_votes: int) -> None:
         """The drained votes' waits, enqueue (the peer's thread) -> this

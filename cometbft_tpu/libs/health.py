@@ -137,9 +137,11 @@ EV_TX = 13
 EV_LOCK = 14
 # prof.window: one sampling-profiler flush window for one subsystem
 # (libs/profile, ~1/s per subsystem with samples) — r=subsystem index
-# (libs/profile.SUBSYSTEMS, decoded as ``subsystem``), a=estimated
-# on-CPU ns (on-CPU samples x the sampling period), b=total samples
-# (on-CPU + blocked). critical_path_from_events window-assigns these to
+# (libs/profile.SUBSYSTEMS, decoded as ``subsystem``), a=the kernel CPU
+# ns the subsystem's threads used in the window (their thread CPU
+# clocks; not on-CPU samples x the period, which counted a thread
+# waiting for the interpreter lock or asleep in C as working), b=total
+# samples (on-CPU + blocked). critical_path_from_events window-assigns these to
 # name commits gated by GIL-bound Python (``cpu:<subsystem>``), and the
 # cpu_saturated postmortem detector scores them.
 EV_PROF = 15
@@ -955,8 +957,10 @@ def critical_path_from_events(events) -> dict[int, dict]:
     budget stage, the lock with the largest in-window slow-wait total
     (with the blocking holder's acquire site), the dominant device
     plane, and — when the sampling profiler ran — the subsystem with
-    the largest in-window on-CPU time (EV_PROF window rows, so a commit
-    gated by GIL-bound Python in the FSM says ``cpu:consensus``, not
+    the largest in-window kernel CPU time (EV_PROF window rows: the
+    threads' own CPU clocks, so a thread that only waits for the
+    interpreter lock or sleeps in C never gates; a commit gated by
+    GIL-bound Python in the FSM says ``cpu:consensus``, not
     just ``stage:verify_execute``) — ``gate`` names whichever dimension
     explains the most time.  Pure function of the decoded event stream
     (the postmortem timeline merge reuses it for its per-height
@@ -1023,7 +1027,7 @@ def critical_path_from_events(events) -> dict[int, dict]:
         for lk, v in waits.items():
             if v > lock_wait_s:
                 lock, lock_wait_s = lk, v
-        # hottest on-CPU subsystem: EV_PROF flush windows are stamped
+        # hottest subsystem by kernel CPU: EV_PROF flush windows are stamped
         # at window END, so a row belongs to the commit window when its
         # flush landed inside it (the per-second granularity matches
         # the ~100 ms-to-seconds commit windows this joins against)

@@ -316,6 +316,10 @@ _LABEL_VALUE_RULES: dict[str, "_re.Pattern"] = {
     # from the two-value on_cpu/blocked enum — never caller input
     "subsystem": _re.compile(r"^[a-z0-9_]{1,16}$"),
     "state": _re.compile(r"^(on_cpu|blocked)$"),
+    # thread roles (libs/profile.role_of): a thread name without its
+    # peer-id or ordinal suffix, cut to 32 of these characters; at most
+    # 32 values, the rest folded into "other" (libs/profile._MAX_ROLES)
+    "role": _re.compile(r"^[A-Za-z0-9_.\-]{1,32}$"),
 }
 
 # Explosion backstop, not a tight bound: the largest legitimate family
@@ -495,6 +499,14 @@ class NodeMetrics:
         )
         for kind in ("vote", "other"):
             self.consensus_drain_items_total.labels(kind)
+        # the receive routine's own CPU inside its drains (two readings
+        # of its thread's clock a drain, tracing on or off): over
+        # consensus_vote_phase_seconds_sum{phase="drain"}, the share of
+        # a drain's wall time spent on a CPU
+        self.consensus_drain_cpu_seconds_total = r.counter(
+            "consensus_drain_cpu_seconds_total",
+            "CPU time of the consensus receive routine inside its drains",
+        )
         # get_round_state() calls by what answered: "published" (the
         # snapshot the FSM's owner stored before it let the state mutex
         # go; no lock taken) or "locked" (the caller holds the mutex, or
@@ -709,6 +721,18 @@ class NodeMetrics:
             "state (on_cpu vs blocked; ~67 Hz default, "
             "COMETBFT_TPU_PROF_HZ)",
             label_names=("subsystem", "state"),
+        )
+        # the kernel's CPU clock of every live thread, read by the
+        # profiler's sampler thread (4 Hz and at stop) and summed by
+        # role: the thread name without its peer-id suffix, bounded to
+        # 32 values by libs/profile and audited by the "role" shape
+        # rule. Bridged by profile.sample (scrape, and once a drain)
+        self.thread_cpu_seconds = r.counter(
+            "thread_cpu_seconds_total",
+            "Kernel CPU time of the process's threads by role (thread "
+            "name without its peer-id suffix), while the sampling "
+            "profiler runs",
+            label_names=("role",),
         )
         # light-client proof service (light/service.py): request
         # outcomes, the commit-verification result cache, and the
@@ -1242,6 +1266,12 @@ def observe_extension_sig_check(answer: str) -> None:
     VerifyVoteExtension or at VoteSet admission:
     consensus_extension_sig_checks_total{answer}."""
     node_metrics().extension_sig_checks_total.labels(answer).inc()
+
+
+def observe_drain_cpu(ns: int) -> None:
+    """``ns`` of the receive routine's CPU in one drain:
+    consensus_drain_cpu_seconds_total."""
+    node_metrics().consensus_drain_cpu_seconds_total.inc(ns / 1e9)
 
 
 def observe_round_state_reads(path: str, n: int) -> None:

@@ -28,7 +28,7 @@ stack can actually see, and the ranked result is the **verdict**:
                         blocking holder's acquire site)
     cpu_saturated       one subsystem's GIL-bound Python burned most
                         of the window's wall time (libs/profile
-                        EV_PROF sampling windows name the subsystem —
+                        EV_PROF windows: the subsystem's kernel CPU —
                         the commit was compute-gated, not waiting)
 
 Scores live in [0, 1]; only findings at or above the report threshold
@@ -492,9 +492,11 @@ def _window_findings(
             ))
 
     # -- CPU saturation (wall-domain rings only, like fsync/lock: the
-    # sampler's on-CPU estimate is wall-measured, so virtual merges
-    # drop EV_PROF rows): the sampling profiler's window rows sum
-    # per-subsystem on-CPU time; when one subsystem's GIL-bound Python
+    # threads' CPU clocks are wall-domain, so virtual merges drop
+    # EV_PROF rows): the sampling profiler's window rows sum
+    # per-subsystem kernel CPU time (the threads' own clocks, so a
+    # thread waiting for the interpreter lock or asleep in C adds
+    # nothing, however it samples); when one subsystem's GIL-bound Python
     # consumed most of the window's wall clock, the commit was
     # compute-gated — the verdict names the subsystem (the profiler's
     # own sampler thread never counts)

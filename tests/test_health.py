@@ -899,6 +899,38 @@ class TestLockContention:
         agg = libhealth.critical_path(events)
         assert agg["gates"] == {"cpu:consensus": 1}
 
+    def test_critical_path_reads_kernel_cpu_not_samples(self):
+        # EV_PROF's oncpu_ns is the subsystem's kernel CPU over the
+        # window (libs/profile reads its threads' CPU clocks): threads
+        # that sampled on-CPU the whole window (asleep in C, or waiting
+        # for the interpreter lock) but used 3 ms of CPU never gate the
+        # commit, however many samples they left
+        t0 = 1_000_000_000
+        dur = 200_000_000
+        events = [
+            {
+                "event": "consensus.step", "height": 9, "node": "n0",
+                "step": 4, "ts": t0 + 50_000_000,
+            },
+            {
+                "event": "consensus.step", "height": 9, "node": "n0",
+                "step": 8, "ts": t0 + 110_000_000,
+            },
+            {
+                "event": "consensus.commit", "height": 9, "node": "n0",
+                "ts": t0 + dur, "dur_ns": dur,
+            },
+            {
+                "event": "prof.window", "subsystem": "mempool",
+                "ts": t0 + 150_000_000, "oncpu_ns": 3_000_000,
+                "samples": 1_340,
+            },
+        ]
+        row = libhealth.critical_path_from_events(events)[9]
+        assert row["cpu"] == "mempool"
+        assert row["cpu_s"] == pytest.approx(0.003)
+        assert not row["gate"].startswith("cpu:"), row["gate"]
+
 
 class TestHealthSample:
     def test_sample_sets_gauges_and_score_degrades(self, health):

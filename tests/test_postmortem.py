@@ -785,6 +785,25 @@ class TestAttributionUnits:
             f.cause != "cpu_saturated" for f in rep3.run.findings
         )
 
+    def test_cpu_saturated_reads_kernel_cpu_not_samples(self):
+        """EV_PROF's oncpu_ns is the subsystem's kernel CPU over the
+        window: 200 threads that sampled on-CPU all window (asleep in C,
+        or waiting for the interpreter lock) and used 40 ms of CPU in
+        all are not a saturated subsystem, however many samples."""
+        parked = [
+            _ev("prof.window", 1_200_000_000 + i * 250_000_000,
+                subsystem="consensus", oncpu_ns=10_000_000,
+                samples=200 * 17)
+            for i in range(4)
+        ]
+        evs = _height_events("node0", 1, 1_000_000_000) + _height_events(
+            "node0", 2, 1_100_000_000, lat_ns=900_000_000
+        ) + parked
+        rep = attribute(merge([Source("node0", evs, domain="wall")]))
+        assert all(
+            f.cause != "cpu_saturated" for f in rep.run.findings
+        )
+
     def test_latency_detector_scores_against_baseline(self):
         slow_hops = [
             _ev("p2p.gossip", 1_101_000_000 + i * 100_000, 0, 0,
