@@ -54,6 +54,7 @@ single-flight leader verifies OUTSIDE it and publishes code-last.
 from __future__ import annotations
 
 import os
+import struct
 import threading
 import time
 from collections import OrderedDict
@@ -289,10 +290,56 @@ class CommitResultCache:
             }
 
 
+# the columns' fixed header: tag, height, round, lanes, part-set total,
+# block-hash length, part-set-hash length
+_COLUMNS_HEAD = struct.Struct(">BqqIqII")
+# no JSON text starts with this byte: a digest of the columns and one of
+# the fallback encoding never share a preimage
+_COLUMNS_TAG = 0
+
+
 def _commit_digest(commit) -> bytes:
-    """Stable digest of a commit's full content (block id + every
-    commit-sig) — the cache key component that pins WHAT was verified."""
-    return tmhash.sum(ser.dumps(commit))
+    """SHA-256 of a commit's full content (block id + every commit-sig):
+    the cache key component that pins WHAT was verified.
+
+    The preimage is the commit laid out as columns: the fixed header, the
+    block hash and the part-set hash, then one column a ``CommitSig``
+    field — a flag byte a lane, a length byte a lane before the joined
+    addresses, a 12-byte signed timestamp a lane, a length byte a lane
+    before the joined signatures. The lane count and every length are
+    in it, so the layout is injective: two commits that differ in any
+    field ``ser.dumps`` covers differ here. Comprehensions and joins, no
+    Python statement per field per lane. A commit the columns cannot
+    hold (a flag or a length past a byte, an integer past its width, a
+    field of another type) is hashed from its JSON encoding, as before.
+
+    No memo on the commit: ``Commit`` is mutable, and a kept digest
+    would pin a stale key to a commit changed after its first check."""
+    sigs = commit.signatures
+    bid = commit.block_id
+    psh = bid.part_set_header
+    try:
+        addrs = [cs.validator_address for cs in sigs]
+        lane_sigs = [cs.signature for cs in sigs]
+        columns = b"".join((
+            _COLUMNS_HEAD.pack(
+                _COLUMNS_TAG, commit.height, commit.round, len(sigs),
+                psh.total, len(bid.hash), len(psh.hash),
+            ),
+            bid.hash,
+            psh.hash,
+            bytes([cs.block_id_flag for cs in sigs]),
+            bytes(map(len, addrs)),
+            b"".join(addrs),
+            b"".join([cs.timestamp_ns.to_bytes(12, "big", signed=True)
+                      for cs in sigs]),
+            bytes(map(len, lane_sigs)),
+            b"".join(lane_sigs),
+        ))
+    except (AttributeError, TypeError, ValueError, OverflowError,
+            struct.error):
+        return tmhash.sum(ser.dumps(commit))
+    return tmhash.sum(columns)
 
 
 class CachedCommitVerifier(light_verifier.CommitVerifier):
@@ -312,17 +359,19 @@ class CachedCommitVerifier(light_verifier.CommitVerifier):
     def verify_commit_light(
         self, chain_id, vals, block_id, height, commit
     ) -> None:
-        key = (
-            "light",
-            chain_id,
-            height,
-            bytes(vals.hash()),
-            _commit_digest(commit),
-            # the FULL expected block id, not just its hash:
-            # verify_commit_light compares part_set_header too, and a
-            # cached success must never mask a mismatch there
-            tmhash.sum(ser.dumps(block_id)),
-        )
+        vals_hash = bytes(vals.hash())
+        with libmetrics.light_phase("cache_key", "light.cache_key"):
+            key = (
+                "light",
+                chain_id,
+                height,
+                vals_hash,
+                _commit_digest(commit),
+                # the FULL expected block id, not just its hash:
+                # verify_commit_light compares part_set_header too, and
+                # a cached success must never mask a mismatch there
+                block_id.encode(),
+            )
         # outermost ledger tenant: a proof-service client's coalescer
         # lanes attribute to "light", not the commit-verify mechanism
         with libdevledger.caller_class("light"):
@@ -336,14 +385,16 @@ class CachedCommitVerifier(light_verifier.CommitVerifier):
     def verify_commit_light_trusting(
         self, chain_id, vals, commit, trust_level
     ) -> None:
-        key = (
-            "trusting",
-            chain_id,
-            commit.height,
-            bytes(vals.hash()),
-            _commit_digest(commit),
-            (trust_level.numerator, trust_level.denominator),
-        )
+        vals_hash = bytes(vals.hash())
+        with libmetrics.light_phase("cache_key", "light.cache_key"):
+            key = (
+                "trusting",
+                chain_id,
+                commit.height,
+                vals_hash,
+                _commit_digest(commit),
+                (trust_level.numerator, trust_level.denominator),
+            )
         with libdevledger.caller_class("light"):
             self._cached(
                 key,

@@ -4,8 +4,12 @@ coalescer batch-submit + deadline propagation, backpressure, RPC
 routes, and THE acceptance storm — 64 concurrent clients over a
 10k-height chain, bit-identical to standalone Client verification."""
 
+import dataclasses
+import hashlib
+import struct
 import threading
 import time
+import types
 
 import pytest
 
@@ -25,10 +29,19 @@ from cometbft_tpu.light.service import (
     DeadlineExceededError,
     ServiceBusyError,
     ServiceStoppedError,
+    _commit_digest,
 )
 from cometbft_tpu.rpc.client import RPCError as ClientRPCError
 from cometbft_tpu.rpc.core.env import Environment
 from cometbft_tpu.rpc.core.routes import RPCError, light_status, light_verify
+from cometbft_tpu.types.block import (
+    BLOCK_ID_FLAG_COMMIT,
+    BLOCK_ID_FLAG_NIL,
+    BlockID,
+    Commit,
+    CommitSig,
+    PartSetHeader,
+)
 from cometbft_tpu.types.validation import VerificationError
 
 SECOND = 1_000_000_000
@@ -209,6 +222,233 @@ class TestCommitResultCache:
         # underlying run, nothing cached
         assert len(errs) == 2 and len(calls) == 1
         assert cache.size() == 0
+
+
+# ---------------------------------------------------------------------------
+# the result cache's commit digest
+# ---------------------------------------------------------------------------
+
+
+def _b(tag, i, n):
+    """``n`` deterministic bytes, a fresh object every call."""
+    return hashlib.shake_256(f"{tag}/{i}".encode()).digest(n)
+
+
+def _commit(lanes=175, height=1000, absent=5):
+    """A commit of ``lanes`` lanes built anew from fixed content: two calls
+    give two distinct objects with equal content. Lane ``absent`` is an
+    absent vote (empty fields, the zero time) as real commits have."""
+    sigs = [
+        CommitSig() if i == absent else CommitSig(
+            BLOCK_ID_FLAG_COMMIT, _b("addr", i, 20), T0 + 1000 * i,
+            _b("sig", i, 64))
+        for i in range(lanes)
+    ]
+    return Commit(height, 2, BlockID(_b("block", 0, 32),
+                                     PartSetHeader(3, _b("parts", 0, 32))),
+                  sigs)
+
+
+def _with_lane(c, i, **fields):
+    sigs = list(c.signatures)
+    sigs[i] = dataclasses.replace(sigs[i], **fields)
+    return dataclasses.replace(c, signatures=sigs)
+
+
+def _flip(b, bit=0):
+    return bytes([b[0] ^ (1 << bit)]) + b[1:]
+
+
+def _digest_by_loop(c):
+    """The columns written out field by field, lane by lane: the layout
+    ``_commit_digest`` builds with joins, as a plain reference."""
+    bid, psh = c.block_id, c.block_id.part_set_header
+    out = bytearray(struct.pack(
+        ">BqqIqII", 0, c.height, c.round, len(c.signatures), psh.total,
+        len(bid.hash), len(psh.hash)))
+    out += bid.hash + psh.hash
+    for cs in c.signatures:
+        out.append(cs.block_id_flag)
+    for cs in c.signatures:
+        out.append(len(cs.validator_address))
+    for cs in c.signatures:
+        out += cs.validator_address
+    for cs in c.signatures:
+        out += cs.timestamp_ns.to_bytes(12, "big", signed=True)
+    for cs in c.signatures:
+        out.append(len(cs.signature))
+    for cs in c.signatures:
+        out += cs.signature
+    return hashlib.sha256(bytes(out)).digest()
+
+
+def _moved_byte(c, field):
+    """Lane 7's ``field`` one byte shorter and lane 8's one byte longer:
+    the joined column is the same bytes, only the lengths tell."""
+    a, b = getattr(c.signatures[7], field), getattr(c.signatures[8], field)
+    c = _with_lane(c, 7, **{field: a[:-1]})
+    return _with_lane(c, 8, **{field: a[-1:] + b})
+
+
+def _swapped(c):
+    sigs = list(c.signatures)
+    sigs[7], sigs[8] = sigs[8], sigs[7]
+    return dataclasses.replace(c, signatures=sigs)
+
+
+def _psh(c, **fields):
+    bid = c.block_id
+    return dataclasses.replace(c, block_id=dataclasses.replace(
+        bid, part_set_header=dataclasses.replace(
+            bid.part_set_header, **fields)))
+
+
+# (name, commit a, commit b): each pair differs in one thing
+_DIGEST_PAIRS = [
+    ("height", _commit(), _commit(height=1001)),
+    ("round", _commit(), dataclasses.replace(_commit(), round=3)),
+    ("block_hash", _commit(), dataclasses.replace(
+        _commit(), block_id=dataclasses.replace(
+            _commit().block_id, hash=_flip(_commit().block_id.hash)))),
+    ("part_set_total", _commit(), _psh(_commit(), total=4)),
+    ("part_set_hash", _commit(),
+     _psh(_commit(), hash=_flip(_commit().block_id.part_set_header.hash))),
+    ("lane_flag", _commit(),
+     _with_lane(_commit(), 7, block_id_flag=BLOCK_ID_FLAG_NIL)),
+    ("lane_address", _commit(), _with_lane(
+        _commit(), 7, validator_address=_flip(_b("addr", 7, 20)))),
+    ("lane_timestamp", _commit(),
+     _with_lane(_commit(), 7, timestamp_ns=T0 + 7001)),
+    ("lane_signature_bit", _commit(), _with_lane(
+        _commit(), 7, signature=_flip(_b("sig", 7, 64), bit=5))),
+    ("lane_dropped", _commit(), dataclasses.replace(
+        _commit(), signatures=_commit().signatures[:7]
+        + _commit().signatures[8:])),
+    ("lanes_swapped", _commit(), _swapped(_commit())),
+    ("signature_byte_moved", _commit(), _moved_byte(_commit(), "signature")),
+    ("address_byte_moved", _commit(),
+     _moved_byte(_commit(), "validator_address")),
+    ("absent_vs_nil_lane", _commit(),
+     _with_lane(_commit(), 5, block_id_flag=BLOCK_ID_FLAG_NIL)),
+    ("lanes_175_vs_1", _commit(), _commit(lanes=1)),
+    ("lanes_175_vs_0", _commit(), _commit(lanes=0)),
+    ("lanes_1_vs_0", _commit(lanes=1), _commit(lanes=0)),
+    # commits the columns cannot hold take the JSON encoding: still told apart
+    ("json_height", _commit(height=2**64), _commit(height=2**64 + 1)),
+    ("json_vs_columns", _commit(), _with_lane(
+        _commit(), 7, signature=_b("sig", 7, 64) + bytes(300))),
+]
+
+
+class TestCommitDigest:
+    @pytest.mark.parametrize(
+        "build",
+        [lambda: _commit(), lambda: _commit(lanes=1),
+         lambda: _commit(lanes=0), lambda: _commit(height=2**64)],
+        ids=["lanes_175", "lanes_1", "lanes_0", "json_encoded"],
+    )
+    def test_equal_content_gives_equal_digest(self, build):
+        a, b = build(), build()
+        assert a is not b and a == b
+        assert _commit_digest(a) == _commit_digest(b)
+        assert len(_commit_digest(a)) == 32
+
+    @pytest.mark.parametrize("lanes", [175, 1, 0])
+    def test_digest_is_sha256_of_the_columns(self, lanes):
+        c = _commit(lanes=lanes)
+        assert _commit_digest(c) == _digest_by_loop(c)
+
+    @pytest.mark.parametrize(
+        "a, b", [p[1:] for p in _DIGEST_PAIRS],
+        ids=[p[0] for p in _DIGEST_PAIRS])
+    def test_one_difference_gives_another_digest(self, a, b):
+        assert a != b
+        assert _commit_digest(a) != _commit_digest(b)
+
+    def test_no_memo_on_the_commit(self):
+        c = _commit()
+        first = _commit_digest(c)
+        c.signatures[7] = dataclasses.replace(
+            c.signatures[7], signature=_flip(c.signatures[7].signature))
+        assert _commit_digest(c) != first
+
+    @pytest.mark.parametrize(
+        "lane, target_misses", [(0, 1), (2, 2)],
+        ids=["refused_by_trusting_check", "refused_by_light_check"])
+    def test_altered_lane_misses_a_cached_success(self, lane, target_misses):
+        """The bad-target traffic: a height's sound commit verified and
+        cached, then the same height served with one lane's signature
+        altered. The altered commit's checks miss the cache and the
+        request is refused naming the lane. Four validators of 10: the
+        trusting check (1/3) counts lanes 0-1, the light check lanes 0-2."""
+        blocks = helpers.make_light_chain(6)
+        now = blocks[6].time_ns + SECOND
+        provider = DictProvider(dict(blocks))
+        svc = LightService(
+            provider, helpers.CHAIN_ID, trusting_period_ns=PERIOD
+        )
+        svc.start()
+        try:
+            svc.verify_at_height(6, trust_height=1, now_ns=now)
+            before = svc.cache.stats()
+            sound = blocks[6]
+            commit = sound.signed_header.commit
+            altered = Commit(commit.height, commit.round, commit.block_id,
+                             _with_lane(commit, lane, signature=_flip(
+                                 commit.signatures[lane].signature)
+                             ).signatures)
+            provider.blocks[6] = dataclasses.replace(
+                sound, signed_header=dataclasses.replace(
+                    sound.signed_header, commit=altered))
+            with pytest.raises(Exception) as ei:
+                svc.verify_at_height(6, trust_height=1, now_ns=now)
+            after = svc.cache.stats()
+        finally:
+            svc.stop()
+        exc, named = ei.value, None
+        while exc is not None and named is None:
+            if isinstance(exc, VerificationError):
+                named = str(exc)
+            exc = getattr(exc, "reason", None) or exc.__cause__
+        assert named is not None and f"wrong signature (#{lane})" in named
+        # the root's own check is the one hit; the target's checks missed
+        assert after["hits"] - before["hits"] == 1
+        assert after["misses"] - before["misses"] == target_misses
+        assert after["shared"] == before["shared"]
+
+    def test_cache_key_phase_feeds_the_benchmark_metric(self):
+        """The benchmark's own snapshot, delta and reader over one request:
+        a key build a commit check (root, trusting, target), read as
+        ``cache_key_ms_per_request``; a window with no request reads
+        nothing."""
+        from benchmark.harness import counters, spec
+        from benchmark.readers import counter_ratio
+
+        name = "cache_key_ms_per_request"
+        (metric,) = [m for m in spec.load_cell(
+            "qa175-relayers-backfill").per_layer if m["name"] == name]
+        assert metric["layer"] == "light service"
+        blocks = helpers.make_light_chain(6)
+        svc = LightService(
+            DictProvider(blocks), helpers.CHAIN_ID, trusting_period_ns=PERIOD
+        )
+        svc.start()
+        try:
+            before = counters.snapshot()
+            svc.verify_at_height(6, trust_height=1,
+                                 now_ns=blocks[6].time_ns + SECOND)
+            after = counters.snapshot()
+        finally:
+            svc.stop()
+        window = counters.delta(before, after)
+        builds = ("prom.cometbft_tpu_light_verify_phase_seconds_count"
+                  '{phase="cache_key"}')
+        assert window[builds] == 3
+        assert counter_ratio.read(
+            metric, types.SimpleNamespace(counters=window)) > 0
+        idle = counters.delta(after, after)
+        assert counter_ratio.read(
+            metric, types.SimpleNamespace(counters=idle)) is None
 
 
 # ---------------------------------------------------------------------------
