@@ -8,8 +8,10 @@ on timeout or bad data. The reactor consumes blocks strictly in order via
 
 from __future__ import annotations
 
-from ..libs import sync as libsync
+import threading
 import time
+
+from ..libs import sync as libsync
 
 REQUEST_WINDOW = 20  # max heights in flight (pool.go maxPendingRequests≈)
 REQUEST_TIMEOUT = 15.0  # per-height peer response timeout
@@ -72,6 +74,9 @@ class BlockPool:
         self.requesters: dict[int, _Requester] = {}
         self.max_peer_height = 0
         self._running = True
+        # what the sync loop waits on between steps: set when a block
+        # lands, one is refused, or a peer comes or goes (arm_wait/wait)
+        self._news = threading.Event()
 
     # -- peers -------------------------------------------------------------
 
@@ -85,6 +90,7 @@ class BlockPool:
             else:
                 p.base, p.height = base, height
             self.max_peer_height = max(self.max_peer_height, height)
+        self._news.set()
 
     def remove_peer(self, peer_id: str) -> None:
         with self._mtx:
@@ -95,6 +101,7 @@ class BlockPool:
             self.max_peer_height = max(
                 (p.height for p in self.peers.values()), default=0
             )
+        self._news.set()
 
     def _pick_peer(self, height: int, banned: set[str]) -> _Peer | None:
         candidates = [
@@ -193,7 +200,8 @@ class BlockPool:
             if peer is not None:
                 peer.num_pending = max(0, peer.num_pending - 1)
                 peer.timeout_count = 0
-            return True
+        self._news.set()
+        return True
 
     def redo_request(self, height: int) -> None:
         """Block at ``height`` failed verification: ban the peer, refetch
@@ -209,6 +217,20 @@ class BlockPool:
             r.peer_id = None
             r.block = None
             r.ext_commit = None
+        self._news.set()
+
+    # -- the sync loop's wait ----------------------------------------------
+
+    def arm_wait(self) -> None:
+        """Forget the news the sync loop has seen. Called before a step,
+        so that what lands during the step still ends the wait after it
+        (pool.go's didProcessCh / requestsCh, without a polling tick)."""
+        self._news.clear()
+
+    def wait(self, timeout: float) -> bool:
+        """Block until a block lands, one is refused or a peer comes or
+        goes (since :meth:`arm_wait`), or ``timeout`` passes."""
+        return self._news.wait(timeout)
 
     # -- ordered consumption ----------------------------------------------
 

@@ -11,8 +11,9 @@ from __future__ import annotations
 import threading
 import time
 
+from ..libs import metrics as libmetrics
 from ..libs import netstats as libnetstats
-from ..libs import trace as libtrace
+from ..libs import profile as libprofile
 from ..p2p.base_reactor import ChannelDescriptor, Reactor
 from ..types import serialization as ser
 from ..types.validation import VerificationError, verify_commit_light
@@ -131,7 +132,13 @@ class BlocksyncReactor(Reactor):
     # -- receive (reactor.go Receive) --------------------------------------
 
     def receive(self, ch_id: int, peer, msg_bytes: bytes) -> None:
-        msg = ser.loads(msg_bytes)
+        # every message's decode is a span; a block's is the phase
+        with libmetrics.TimedPhase(None, "blocksync.decode") as ph:
+            msg = ser.loads(msg_bytes)
+        if isinstance(msg, BlockResponseMessage):
+            libmetrics.node_metrics().blocksync_phase_seconds.labels(
+                "decode"
+            ).observe(ph.dur_ns / 1e9)
         if isinstance(msg, StatusRequestMessage):
             peer.try_send(
                 BLOCKSYNC_CHANNEL,
@@ -193,17 +200,28 @@ class BlocksyncReactor(Reactor):
     # -- the sync loop (reactor.go:272 poolRoutine) ------------------------
 
     # _pool_step outcomes
-    STEP_IDLE = 0  # nothing applied; caller may sleep a beat
+    STEP_IDLE = 0  # nothing applied; caller waits for news
     STEP_APPLIED = 1  # a block landed; step again immediately
     STEP_SWITCHED = 2  # handed off to consensus; the loop is done
 
     def _pool_routine(self) -> None:
         while not self.quit_event().is_set():
-            outcome = self._pool_step(self._now())
+            pool = self.pool
+            pool.arm_wait()
+            now = self._now()
+            outcome = self._pool_step(now)
             if outcome == self.STEP_SWITCHED:
                 return
             if outcome == self.STEP_IDLE:
-                time.sleep(0.05)
+                # until a block lands, one is refused or a peer comes or
+                # goes; at the latest when the status broadcast or the
+                # caught-up check is due
+                due = min(
+                    self._last_status + STATUS_INTERVAL,
+                    self._last_switch_check + SWITCH_TO_CONSENSUS_INTERVAL,
+                )
+                with libmetrics.blocksync_phase("wait", "blocksync.wait"):
+                    pool.wait(max(0.0, due - now))
 
     def _pool_step(self, now: float) -> int:
         """One iteration of the sync loop (also the simnet tick: the
@@ -242,59 +260,80 @@ class BlocksyncReactor(Reactor):
         return self.STEP_IDLE
 
     def _apply_first(self, first, first_ext, second) -> None:
-        """reactor.go:447: first's validity is proven by second.LastCommit."""
+        """reactor.go:447: first's validity is proven by second.LastCommit.
+
+        One ``blocksync.block`` span (fields ``height``, ``lanes``: the
+        seen commit's signatures, ``outcome``: applied / refused) and
+        ``blocksync_phase_seconds{phase="block"}``; its phases
+        ``part_set``, ``verify_light``, ``store``, ``validate`` and
+        ``apply`` nest in no other."""
+        height = first.header.height
+        lanes = (
+            len(second.last_commit.signatures)
+            if second.last_commit is not None else 0
+        )
+        with libmetrics.blocksync_phase(
+            "block", "blocksync.block", height=height, lanes=lanes
+        ) as block_ph:
+            applied = self._verify_and_apply(first, first_ext, second)
+            block_ph.set(outcome="applied" if applied else "refused")
+        if applied:
+            self._n_synced += 1
+            self.pool.pop_request()
+        # thread_cpu_seconds_total{role} reaches the registry once a
+        # block, as the consensus receive routine bridges it once a drain
+        libprofile.sample()
+
+    def _verify_and_apply(self, first, first_ext, second) -> bool:
+        from ..libs import devledger
         from ..types import BlockID, PartSet
 
-        t0 = time.perf_counter() if libtrace.enabled() else 0.0
-        parts = PartSet.from_data(ser.dumps(first))
-        first_id = BlockID(first.hash(), parts.header)
+        phase = libmetrics.blocksync_phase
+        with phase("part_set", "blocksync.part_set"):
+            parts = PartSet.from_data(ser.dumps(first))
+            first_id = BlockID(first.hash(), parts.header)
         try:
-            if second.last_commit is None:
-                raise VerificationError("second block missing last commit")
-            if second.last_commit.block_id != first_id:
-                raise VerificationError("second block commits a fork?")
-            from ..libs import devledger
-
-            with devledger.caller_class("blocksync"):
-                verify_commit_light(
-                    self.state.chain_id,
-                    self.state.validators,
-                    first_id,
-                    first.header.height,
-                    second.last_commit,
-                )  # ◄◄ HOT BATCH (types/validation.go via TPU verifier)
+            with phase("verify_light", "blocksync.verify_light"):
+                if second.last_commit is None:
+                    raise VerificationError(
+                        "second block missing last commit"
+                    )
+                if second.last_commit.block_id != first_id:
+                    raise VerificationError("second block commits a fork?")
+                with devledger.caller_class("blocksync"):
+                    verify_commit_light(
+                        self.state.chain_id,
+                        self.state.validators,
+                        first_id,
+                        first.header.height,
+                        second.last_commit,
+                    )  # ◄◄ HOT BATCH (types/validation.go via TPU verifier)
         except (VerificationError, ValueError):
             # Either block may be the forged one: redo BOTH and punish both
             # serving peers (reactor.go:447-470).
-            if t0:
-                libtrace.event(
-                    "blocksync.reject", height=first.header.height
-                )
             self.pool.redo_request(first.header.height)
             self.pool.redo_request(second.header.height)
-            return
-        seen_commit = second.last_commit
-        if self.block_store.height() < first.header.height:
-            if first_ext is not None and self.state.consensus_params.vote_extensions_enabled(
-                first.header.height
-            ):
-                self.block_store.save_block_with_extended_commit(
-                    first, parts, first_ext
-                )
-            else:
-                self.block_store.save_block(first, parts, seen_commit)
+            return False
+        params = self.state.consensus_params
+        with phase("store", "blocksync.store"):
+            if self.block_store.height() < first.header.height:
+                if first_ext is not None and params.vote_extensions_enabled(
+                    first.header.height
+                ):
+                    self.block_store.save_block_with_extended_commit(
+                        first, parts, first_ext
+                    )
+                else:
+                    self.block_store.save_block(
+                        first, parts, second.last_commit
+                    )
         # ApplyBlock failure on a commit-verified block is a LOCAL fault —
         # fail-stop like the reference's panic, never punish the peer.
-        self.state = self.block_exec.apply_block(self.state, first_id, first)
-        if t0:
-            libtrace.event(
-                "blocksync.apply",
-                height=first.header.height,
-                lanes=len(seen_commit.signatures),
-                dur_ns=int((time.perf_counter() - t0) * 1e9),
-            )
-        self._n_synced += 1
-        self.pool.pop_request()
+        self.state = self.block_exec.apply_block(
+            self.state, first_id, first,
+            phase=lambda name: phase(name, "blocksync." + name),
+        )
+        return True
 
     def _switch_to_consensus(self) -> None:
         """reactor.go:383-386 → consensus/reactor.go:109."""

@@ -454,47 +454,66 @@ class Sr25519BatchVerifier(BatchVerifier):
         native = host_batch.available()
         host_cut = host_batch_threshold() if native else self.HOST_THRESHOLD
         if n < host_cut:
-            bitmap = None
-            if native:
-                bitmap = host_batch.verify_quads(
-                    sr.verification_encs_batch(
-                        self._pubkeys, self._msgs, self._sigs
-                    )
-                )
-            if bitmap is None:
-                bitmap = [
-                    sr.verify(p, m, s)
-                    for p, m, s in zip(
-                        self._pubkeys, self._msgs, self._sigs
-                    )
-                ]
-            libmetrics.observe_verify_phase(
-                "fallback", "sr25519-host", _time.perf_counter() - t0, n
-            )
+            with libmetrics.verify_phase("fallback", "sr25519-host", lanes=n):
+                bitmap = None
+                if native:
+                    bitmap = host_batch.verify_quads(_sr_prep(
+                        "sr25519-host", self._pubkeys, self._msgs,
+                        self._sigs))
+                if bitmap is None:
+                    bitmap = [
+                        sr.verify(p, m, s)
+                        for p, m, s in zip(
+                            self._pubkeys, self._msgs, self._sigs
+                        )
+                    ]
             _observe("sr25519-host", t0, n)
             return all(bitmap), bitmap
         from ..ops import verify as ov
 
-        parts = sr.verification_encs_batch(
-            self._pubkeys, self._msgs, self._sigs
-        )
-        buf, host_ok = ov.pack_parts(parts)
-        # The expanded-point cache is keyed by the edwards A encoding, so
-        # sr25519 validators (converted ristretto points) share the same
-        # arena as ed25519 pubkeys.
-        a_keys = [p[0] if p is not None else b"" for p in parts]
-        t1 = _time.perf_counter()
-        libmetrics.observe_verify_phase("pack", "sr25519-tpu", t1 - t0, n)
-        done = ov.verify_prepacked(buf, a_keys, n, "sr25519-tpu")
-        t2 = _time.perf_counter()
-        libmetrics.observe_verify_phase("dispatch", "sr25519-tpu", t2 - t1, n)
-        device_ok = done()
-        libmetrics.observe_verify_phase(
-            "readback", "sr25519-tpu", _time.perf_counter() - t2, n
-        )
+        backend = "sr25519-tpu"
+        with libmetrics.verify_phase("pack", backend, ed_lanes=0, sr_lanes=n):
+            parts = _sr_prep(backend, self._pubkeys, self._msgs, self._sigs)
+            buf, host_ok = ov.pack_parts(parts)
+            # The expanded-point cache is keyed by the edwards A encoding,
+            # so sr25519 validators (converted ristretto points) share the
+            # same arena as ed25519 pubkeys.
+            a_keys = [p[0] if p is not None else b"" for p in parts]
+        device_ok = _launch_prepacked(buf, a_keys, n, backend)
         valid = device_ok & host_ok
-        _observe("sr25519-tpu", t0, n)
+        _observe(backend, t0, n)
         return bool(valid.all()), list(np.asarray(valid, bool))
+
+
+def _sr_prep(backend: str, pubkeys, msgs, sigs) -> list:
+    """The sr25519 lanes' host share of a verify: ristretto -> edwards
+    conversion and merlin challenges, batched through the native engine
+    (``verify.sr_prep`` span, ``crypto_verify_phase_seconds{phase=
+    "sr_prep"}``)."""
+    from . import sr25519 as sr
+
+    with libmetrics.verify_phase("sr_prep", backend, lanes=len(pubkeys)):
+        return sr.verification_encs_batch(pubkeys, msgs, sigs)
+
+
+def _launch_prepacked(buf, a_keys, n: int, backend: str) -> np.ndarray:
+    """Dispatch a pre-packed wire buffer (arena lookup and launch: the
+    ``verify.dispatch`` span) and read its verdicts back (``verify.
+    readback``, the launch's ``verify.kernel_wait`` inside), through
+    ops/verify's shared launch and materialise functions."""
+    from ..ops import verify as ov
+
+    # a lane the host refused has no key (an sr25519 point that does not
+    # decode, a malformed key): it reads a live lane's table, its verdict
+    # is host_ok's False whatever the device says, and the arena builds
+    # no table for an empty key
+    live = next((k for k in a_keys if k), None)
+    if live is not None and not all(a_keys):
+        a_keys = [k or live for k in a_keys]
+    with libmetrics.verify_phase("dispatch", backend, lanes=n):
+        done = ov.verify_prepacked(buf, a_keys, n, backend)
+    with libmetrics.verify_phase("readback", backend, lanes=n):
+        return done()
 
 
 class MixedBatchVerifier(BatchVerifier):
@@ -561,15 +580,14 @@ class MixedBatchVerifier(BatchVerifier):
             len(ed_idx),
         )
 
-    def _sr_quads(self, out: list) -> list[int]:
+    def _sr_quads(self, out: list, backend: str) -> list[int]:
         """Scatter sr25519 lane quads into ``out``; returns the sr lane
         indices. The ONE home of sr admission + scatter, shared by the
         host (_quads) and device (_pack_rows) paths."""
-        from . import sr25519 as sr
-
         sr_idx = [i for i, t in enumerate(self._types) if t == "sr25519"]
         if sr_idx:
-            sq = sr.verification_encs_batch(
+            sq = _sr_prep(
+                backend,
                 [self._pubkeys[i] for i in sr_idx],
                 [self._msgs[i] for i in sr_idx],
                 [self._sigs[i] for i in sr_idx],
@@ -578,7 +596,7 @@ class MixedBatchVerifier(BatchVerifier):
                 out[i] = sq[j]
         return sr_idx
 
-    def _quads(self) -> list:
+    def _quads(self, backend: str) -> list:
         """Per-lane (A_enc, R_enc, s, k), challenges batched per scheme
         through the native engine (merlin STROBE for sr25519, fused
         SHA-512 for ed25519); None marks a structurally invalid lane."""
@@ -586,7 +604,7 @@ class MixedBatchVerifier(BatchVerifier):
 
         n = len(self._pubkeys)
         quads: list = [None] * n
-        self._sr_quads(quads)
+        self._sr_quads(quads, backend)
         ed_idx = self._ed_lane_idxs()
         if not ed_idx:
             return quads
@@ -628,12 +646,13 @@ class MixedBatchVerifier(BatchVerifier):
         from ..ops import verify as ov
         from . import host_batch
 
+        backend = "mixed-tpu"
         if not host_batch.available():
             # toolchain-less: build everything through the shared quad
             # packer (one Python challenge loop lives in _quads) —
             # checked FIRST so the ed record/message blobs aren't joined
             # just to learn pack_challenges must return None
-            quads = self._quads()
+            quads = self._quads(backend)
             buf, host_ok = ov.pack_parts(quads)
             a_keys = [q[0] if q is not None else b"" for q in quads]
             return buf, host_ok, a_keys
@@ -641,7 +660,7 @@ class MixedBatchVerifier(BatchVerifier):
         rows: list = [None] * n
         a_keys: list = [b""] * n
         sq: list = [None] * n
-        for i in self._sr_quads(sq):
+        for i in self._sr_quads(sq, backend):
             q = sq[i]
             if q is None:
                 continue
@@ -650,7 +669,7 @@ class MixedBatchVerifier(BatchVerifier):
         ed_idx = self._ed_lane_idxs()
         packed = self._ed_knegs(ed_idx) if ed_idx else None
         if ed_idx and packed is None:  # engine vanished mid-flight
-            quads = self._quads()
+            quads = self._quads(backend)
             buf, host_ok = ov.pack_parts(quads)
             return buf, host_ok, [
                 q[0] if q is not None else b"" for q in quads
@@ -702,40 +721,36 @@ class MixedBatchVerifier(BatchVerifier):
                 else host_batch_threshold()
             )
         if n < host_cut:
-            bitmap = host_batch.verify_quads(self._quads()) if native \
-                else None
-            if bitmap is None:
-                from .sr25519 import verify as sr_verify
+            with libmetrics.verify_phase("fallback", "mixed-host", lanes=n):
+                bitmap = (
+                    host_batch.verify_quads(self._quads("mixed-host"))
+                    if native else None
+                )
+                if bitmap is None:
+                    from .sr25519 import verify as sr_verify
 
-                bitmap = [
-                    (
-                        keys.Ed25519PubKey(pk).verify_signature(m, s)
-                        if t == keys.ED25519_KEY_TYPE
-                        else sr_verify(pk, m, s)
-                    )
-                    for t, pk, m, s in zip(
-                        self._types, self._pubkeys, self._msgs, self._sigs
-                    )
-                ]
-            libmetrics.observe_verify_phase(
-                "fallback", "mixed-host", _time.perf_counter() - t0, n
-            )
+                    bitmap = [
+                        (
+                            keys.Ed25519PubKey(pk).verify_signature(m, s)
+                            if t == keys.ED25519_KEY_TYPE
+                            else sr_verify(pk, m, s)
+                        )
+                        for t, pk, m, s in zip(
+                            self._types, self._pubkeys, self._msgs,
+                            self._sigs,
+                        )
+                    ]
             _observe("mixed-host", t0, n)
             return all(bitmap), list(bitmap)
-        from ..ops import verify as ov
-
-        buf, host_ok, a_keys = self._pack_rows()
-        t1 = _time.perf_counter()
-        libmetrics.observe_verify_phase("pack", "mixed-tpu", t1 - t0, n)
-        done = ov.verify_prepacked(buf, a_keys, n, "mixed-tpu")
-        t2 = _time.perf_counter()
-        libmetrics.observe_verify_phase("dispatch", "mixed-tpu", t2 - t1, n)
-        device_ok = done()
-        libmetrics.observe_verify_phase(
-            "readback", "mixed-tpu", _time.perf_counter() - t2, n
-        )
+        backend = "mixed-tpu"
+        n_sr = self._types.count("sr25519")
+        with libmetrics.verify_phase(
+            "pack", backend, ed_lanes=n - n_sr, sr_lanes=n_sr
+        ):
+            buf, host_ok, a_keys = self._pack_rows()
+        device_ok = _launch_prepacked(buf, a_keys, n, backend)
         valid = device_ok & host_ok
-        _observe("mixed-tpu", t0, n)
+        _observe(backend, t0, n)
         return bool(valid.all()), list(np.asarray(valid, bool))
 
 

@@ -482,6 +482,26 @@ class NodeMetrics:
                 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5,
             ),
         )
+        # a block fetched by block sync, phase by phase (libs/metrics.
+        # blocksync_phase): block (the whole _apply_first, one a block
+        # verified, refused ones too: the count the sums are divided by),
+        # decode (the reactor's decode of a BlockResponse, on the
+        # serving peer's thread), verify_light (the +2/3 check of the
+        # first block by the second's LastCommit), part_set, store,
+        # validate (apply_block's validation, the block's own LastCommit
+        # in full inside) and apply (the rest of apply_block); wait (the
+        # sync loop waiting for the next two blocks, one observation a
+        # wait). verify_light, part_set, store, validate and apply nest
+        # in no other and tile block. Same buckets as the verify phases.
+        self.blocksync_phase_seconds = r.histogram(
+            "blocksync_phase_seconds",
+            "Block sync per-phase latency",
+            label_names=("phase",),
+            buckets=(
+                0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
+                0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5,
+            ),
+        )
         # tocks the FSM handled: "acted" (it moved a step on one) or
         # "stale" (the round had gone on before it fired)
         self.consensus_timeouts_total = r.counter(
@@ -1239,6 +1259,24 @@ def consensus_phase(phase: str, span_name: str, **fields) -> TimedPhase:
     return TimedPhase(
         node_metrics().consensus_vote_phase_seconds.labels(phase),
         span_name, **fields,
+    )
+
+
+def blocksync_phase(phase: str, span_name: str, **fields) -> TimedPhase:
+    """One phase of block sync: ``blocksync_phase_seconds{phase}`` and
+    the ``span_name`` span."""
+    return TimedPhase(
+        node_metrics().blocksync_phase_seconds.labels(phase),
+        span_name, **fields,
+    )
+
+
+def verify_phase(phase: str, backend: str, **fields) -> TimedPhase:
+    """One phase of a batch verify: ``crypto_verify_phase_seconds{phase,
+    backend}`` and the ``verify.<phase>`` span (field ``backend``)."""
+    return TimedPhase(
+        node_metrics().verify_phase_seconds.labels(phase, backend),
+        "verify." + phase, backend=backend, **fields,
     )
 
 

@@ -10,6 +10,7 @@ the vote-extension hooks.
 
 from __future__ import annotations
 
+import contextlib
 import time
 
 from ..abci import types as abci
@@ -231,6 +232,10 @@ def validator_updates_to_validators(updates: list[abci.ValidatorUpdate]):
     return out
 
 
+def _untimed(_name: str):
+    return contextlib.nullcontext()
+
+
 class BlockExecutor:
     def __init__(
         self,
@@ -371,14 +376,20 @@ class BlockExecutor:
         return resp, post
 
     def apply_block(
-        self, state: State, block_id: BlockID, block: Block
+        self, state: State, block_id: BlockID, block: Block, phase=None
     ) -> State:
         """execution.go:204 ApplyBlock: validate → FinalizeBlock → update
-        state → Commit → prune → events. Returns the next State."""
+        state → Commit → prune → events. Returns the next State.
+
+        ``phase(name)``: a caller's timed phase (blocksync's), entered
+        around ``validate`` and around ``apply``, the rest of the call."""
+        timed = phase if phase is not None else _untimed
         t0 = time.perf_counter()
-        self.validate_block(state, block)
-        new_state, resp = self.begin_apply(state, block_id, block)
-        self.complete_apply(new_state, block_id, block, resp, t0=t0)
+        with timed("validate"):
+            self.validate_block(state, block)
+        with timed("apply"):
+            new_state, resp = self.begin_apply(state, block_id, block)
+            self.complete_apply(new_state, block_id, block, resp, t0=t0)
         return new_state
 
     def begin_apply(

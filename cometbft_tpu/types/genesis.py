@@ -49,11 +49,11 @@ class GenesisDoc:
             self.initial_height = 1
         self.consensus_params.validate_basic()
         # The validator-set hash proto-encodes every key through the
-        # tendermint.crypto.PublicKey oneof, which carries ONLY ed25519
-        # and secp256k1 (keys.proto; the reference's PubKeyToProto
-        # errors identically, crypto/encoding/codec.go:20-38). Reject
-        # here with a clear message instead of crashing the consensus
-        # FSM at enter-new-round.
+        # tendermint.crypto.PublicKey oneof, which carries ed25519,
+        # secp256k1 and sr25519 (keys.proto; validator_set.
+        # pubkey_proto_encode). Reject any other type here with a clear
+        # message instead of crashing the consensus FSM at
+        # enter-new-round.
         from .validator_set import pubkey_proto_encode
 
         for v in self.validators:
@@ -64,8 +64,8 @@ class GenesisDoc:
             except ValueError as e:
                 raise ValueError(
                     f"genesis validator key not wire-encodable: {e} "
-                    "(tendermint.crypto.PublicKey supports ed25519 and "
-                    "secp256k1 only)"
+                    "(tendermint.crypto.PublicKey supports ed25519, "
+                    "secp256k1 and sr25519 only)"
                 ) from e
         if self.genesis_time_ns == 0:
             self.genesis_time_ns = time.time_ns()

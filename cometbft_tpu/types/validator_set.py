@@ -35,11 +35,14 @@ def _clip(v: int) -> int:
 
 def pubkey_proto_encode(pub_key) -> bytes:
     """tendermint.crypto.PublicKey oneof body (keys.proto: ed25519=1,
-    secp256k1=2)."""
+    secp256k1=2; sr25519=3 as Tendermint v0.35's keys.proto numbers it,
+    so that a set of sr25519 validators, or a mixed one, has a hash)."""
     if pub_key.type == "ed25519":
         return proto.field_bytes(1, pub_key.bytes())
     if pub_key.type == "secp256k1":
         return proto.field_bytes(2, pub_key.bytes())
+    if pub_key.type == "sr25519":
+        return proto.field_bytes(3, pub_key.bytes())
     raise ValueError(f"unsupported key type {pub_key.type}")
 
 

@@ -319,11 +319,9 @@ def test_validator_updates_rejected_outside_pub_key_types():
             [ValidatorUpdate(pub_key_type="sr25519",
                              pub_key_bytes=sr.data, power=5)], params
         )
-    # params naming a non-wire type still can't smuggle it past the
-    # proto gate
+    # params that name sr25519 admit it: the PublicKey oneof carries it
     loose = ValidatorParams(pub_key_types=("ed25519", "sr25519"))
-    with pytest.raises(ValueError, match="not wire-encodable"):
-        validate_validator_updates(
-            [ValidatorUpdate(pub_key_type="sr25519",
-                             pub_key_bytes=sr.data, power=5)], loose
-        )
+    validate_validator_updates(
+        [ValidatorUpdate(pub_key_type="sr25519",
+                         pub_key_bytes=sr.data, power=5)], loose
+    )

@@ -404,15 +404,15 @@ class TestValidatorSetRootMemo:
         assert hash_counts() == {"computed": 1, "reused": 1}
 
     def test_unhashable_key_type_keeps_no_root(self):
-        class _Sr:
-            type = "sr25519"
+        class _Bls:
+            type = "bls12381"
 
             def bytes(self):
-                return b"\x01" * 32
+                return b"\x01" * 48
 
         _, vals = _pv_set(3)
         vals.hash()
-        vals.validators[1].pub_key = _Sr()
+        vals.validators[1].pub_key = _Bls()
         with pytest.raises(ValueError):
             vals.hash()
         with pytest.raises(ValueError):  # no stale root the second time
@@ -752,22 +752,25 @@ class TestVerifyCommitMixedKeys:
 
 
 class TestValidatorKeyWireScope:
-    """The tendermint.crypto.PublicKey oneof carries only ed25519 and
-    secp256k1 (keys.proto; the reference's PubKeyToProto errors for
-    anything else, crypto/encoding/codec.go:20-38): sr25519 stays a
-    crypto/batch citizen but cannot be a wire-encodable validator key,
-    and genesis must say so clearly instead of crashing the FSM at the
-    first validator-set hash."""
+    """The tendermint.crypto.PublicKey oneof carries ed25519 (1),
+    secp256k1 (2) and sr25519 (3, as Tendermint v0.35's keys.proto
+    numbers it): a mixed ed25519 + sr25519 set has a hash and a genesis;
+    any other key type is refused at genesis with a clear message instead
+    of crashing the FSM at the first validator-set hash."""
 
-    def test_valset_hash_rejects_sr25519(self):
+    def test_valset_hash_encodes_sr25519_as_field_3(self):
         from cometbft_tpu.crypto.sr25519 import Sr25519PrivKey
+        from cometbft_tpu.types.validator_set import pubkey_proto_encode
 
         pk = Sr25519PrivKey.from_seed(b"\x09" * 32).pub_key()
+        assert pubkey_proto_encode(pk) == b"\x1a\x20" + pk.data
         vs = ValidatorSet([Validator(pub_key=pk, voting_power=1)])
-        with pytest.raises(ValueError, match="unsupported key type"):
-            vs.hash()
+        other = ValidatorSet([Validator(
+            pub_key=Sr25519PrivKey.from_seed(b"\x0c" * 32).pub_key(),
+            voting_power=1)])
+        assert len(vs.hash()) == 32 and vs.hash() != other.hash()
 
-    def test_genesis_rejects_sr25519_validator_early(self):
+    def test_genesis_accepts_sr25519_and_rejects_unknown_types(self):
         from cometbft_tpu.crypto.sr25519 import Sr25519PrivKey
         from cometbft_tpu.types.genesis import (
             GenesisDoc,
@@ -781,6 +784,24 @@ class TestValidatorKeyWireScope:
             validators=[
                 GenesisValidator(pub_key=pv.pub_key(), power=10)
             ],
+        )
+        doc.validate_and_complete()
+        back = GenesisDoc.from_json(doc.to_json())
+        assert back.validators[0].pub_key == pv.pub_key()
+
+        class _Bls:
+            type = "bls12381"
+
+            def address(self):
+                return b"\x01" * 20
+
+            def bytes(self):
+                return b"\x00" * 48
+
+        doc = GenesisDoc(
+            chain_id="wire-scope",
+            genesis_time_ns=1,
+            validators=[GenesisValidator(pub_key=_Bls(), power=10)],
         )
         with pytest.raises(ValueError, match="not wire-encodable"):
             doc.validate_and_complete()
