@@ -12,6 +12,7 @@ from __future__ import annotations
 import time
 from bisect import bisect_right
 
+from . import jsoncodec
 from . import sync as libsync
 from . import trace as libtrace
 
@@ -754,6 +755,16 @@ class NodeMetrics:
             "profiler runs",
             label_names=("role",),
         )
+        # time inside top-level libs/jsoncodec Codec.encode / dumps
+        # calls, every codec of the process (storage records, WAL
+        # records, wire messages), counted from when this registry was
+        # made. Bridged by observe_codec_encode once a synced block
+        self.codec_encode_seconds = r.counter(
+            "codec_encode_seconds_total",
+            "Time inside top-level storage/wire codec encodes "
+            "(Codec.encode and Codec.dumps of libs/jsoncodec)",
+        )
+        self._codec_encode_wm = jsoncodec.encode_ns()
         # light-client proof service (light/service.py): request
         # outcomes, the commit-verification result cache, and the
         # backpressure surface (in-flight occupancy + slot queue wait).
@@ -1318,6 +1329,18 @@ def observe_round_state_reads(path: str, n: int) -> None:
     themselves with no lock; the receive routine hands the tally over
     once a drain."""
     node_metrics().consensus_round_state_reads_total.labels(path).inc(n)
+
+
+def observe_codec_encode(metrics: NodeMetrics | None = None) -> None:
+    """The codec's encode time since the last call:
+    codec_encode_seconds_total. The encodes add to a process-wide sum
+    with no lock; one thread hands the sum over (the sync loop, once a
+    block)."""
+    m = metrics if metrics is not None else node_metrics()
+    now = jsoncodec.encode_ns()
+    if now > m._codec_encode_wm:
+        m.codec_encode_seconds.inc((now - m._codec_encode_wm) / 1e9)
+        m._codec_encode_wm = now
 
 
 def observe_valset_hash(reused: bool) -> None:
