@@ -430,6 +430,9 @@ class NodeMetrics:
         # fetch (the provider's reply), valset_hash, header_basic,
         # sign_bytes. The last four do not nest in one another, so with
         # the verify phases above they tile a header; same buckets.
+        # trusting (a skipping step's check of the trusted set's share,
+        # light/verifier.verify_non_adjacent) holds the sign_bytes and
+        # verify phases of its own commit check.
         self.light_verify_phase_seconds = r.histogram(
             "light_verify_phase_seconds",
             "Light-client header verification per-phase latency",
@@ -461,6 +464,17 @@ class NodeMetrics:
         )
         for path in ("batched", "per_lane"):
             self.commit_sign_bytes_lanes_total.labels(path)
+        # the attempts of a light client's bisection (light/client.py
+        # _verify_skipping), by outcome: "verified" (the header came to
+        # be trusted), "cant_trust" (too little of the trusted set signed
+        # it: the client pivots) or "refused" (any other refusal)
+        self.light_bisection_attempts_total = r.counter(
+            "light_bisection_attempts_total",
+            "Light-client bisection attempts by outcome",
+            label_names=("outcome",),
+        )
+        for outcome in ("verified", "cant_trust", "refused"):
+            self.light_bisection_attempts_total.labels(outcome)
         # the consensus vote path, phase by phase (libs/metrics.
         # consensus_phase): receive_vote / receive_data / receive_state
         # (the reactor's decode -> enqueue, one observation a message, on
@@ -584,15 +598,23 @@ class NodeMetrics:
         for path in ("columnar", "per_lane"):
             self.verify_pack_lanes_total.labels(path)
         # how the device key arena answered a lookup: "memo" (the whole
-        # key column was the one a kept slot vector was computed for) or
-        # "walked" (key by key through the LRU)
+        # key column was the one a kept slot vector was computed for),
+        # "walked" (key by key through the LRU) or "uncached" (the lookup
+        # gave up: more distinct keys than slots, or eviction churn won
+        # three builds; the lanes go to the uncached kernel)
         self.pubkey_lookup_lanes_total = r.counter(
             "ops_pubkey_lookup_lanes_total",
             "Key-arena lookup lanes by how their slots were found",
             label_names=("result",),
         )
-        for result in ("memo", "walked"):
+        for result in ("memo", "walked", "uncached"):
             self.pubkey_lookup_lanes_total.labels(result)
+        # keys the arena's builder launches expanded into tables (the
+        # launch bucket's padding not counted)
+        self.pubkey_tables_built_total = r.counter(
+            "ops_pubkey_tables_built_total",
+            "Expanded pubkey tables built for the device key arena",
+        )
         # cross-caller verify coalescer (crypto/coalesce.py): window
         # shape, flush triggers, and the submit-to-verdict latency its
         # callers actually pay. Lane-count buckets are the shape
@@ -1376,6 +1398,17 @@ def observe_pubkey_lookup(result: str, lanes: int) -> None:
     ``slots`` on the enclosing ``verify.pack`` span."""
     node_metrics().pubkey_lookup_lanes_total.labels(result).inc(lanes)
     _set_on_pack_span(slots=result)
+
+
+def observe_tables_built(keys: int) -> None:
+    """One builder launch of the key arena: ops_pubkey_tables_built_total."""
+    node_metrics().pubkey_tables_built_total.inc(keys)
+
+
+def observe_bisection_attempt(outcome: str) -> None:
+    """One attempt of a light client's bisection:
+    light_bisection_attempts_total{outcome}."""
+    node_metrics().light_bisection_attempts_total.labels(outcome).inc()
 
 
 def observe_hash_phase(phase: str, seconds: float, lanes: int) -> None:

@@ -251,6 +251,7 @@ class Client:
                     self.commit_verifier,
                 )
             except NewValSetCantBeTrustedError:
+                libmetrics.observe_bisection_attempt("cant_trust")
                 # pivot deeper: fetch an intermediate block
                 if depth == len(block_cache) - 1:
                     pivot = (
@@ -264,9 +265,11 @@ class Client:
                 depth += 1
                 continue
             except Exception as e:
+                libmetrics.observe_bisection_attempt("refused")
                 raise VerificationFailedError(
                     verified.height, block_cache[depth].height, e
                 ) from e
+            libmetrics.observe_bisection_attempt("verified")
             # verified block_cache[depth]
             if depth == 0:
                 trace.append(target)

@@ -139,10 +139,12 @@ def _byzantine_validators(common, primary_block, divergent) -> list:
     commit = primary_block.signed_header.commit
     from ..types.block import BLOCK_ID_FLAG_COMMIT
 
+    vals = common.validator_set
+    index = vals.address_index()  # one map for the whole commit
     for sig in commit.signatures:
         if sig.block_id_flag != BLOCK_ID_FLAG_COMMIT:
             continue
-        idx, val = common.validator_set.get_by_address(sig.validator_address)
+        idx = index.get(sig.validator_address, -1)
         if idx >= 0:
-            out.append(val)
+            out.append(vals.validators[idx])
     return out

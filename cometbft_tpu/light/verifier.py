@@ -9,6 +9,7 @@ configuration.
 
 from __future__ import annotations
 
+from ..libs import metrics as libmetrics
 from ..types.light_block import SignedHeader
 from ..types.validation import (
     DEFAULT_TRUST_LEVEL,
@@ -183,12 +184,16 @@ def verify_non_adjacent(
         raise InvalidHeaderError(e) from e
 
     try:
-        cv.verify_commit_light_trusting(
-            trusted_header.chain_id,
-            trusted_vals,
-            untrusted_header.commit,
-            trust_level,
-        )
+        with libmetrics.light_phase(
+            "trusting", "light.trusting",
+            height=untrusted_header.height, validators=len(trusted_vals),
+        ):
+            cv.verify_commit_light_trusting(
+                trusted_header.chain_id,
+                trusted_vals,
+                untrusted_header.commit,
+                trust_level,
+            )
     except NotEnoughVotingPowerError as e:
         raise NewValSetCantBeTrustedError(e) from e
 

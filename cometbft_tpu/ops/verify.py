@@ -640,8 +640,8 @@ class PubkeyTableCache:
                 libmetrics.observe_pubkey_lookup("memo", n)
                 return idxs, arena, arena_ok
         hit = self._walk(pubkeys, column, width)
-        if hit is not None:
-            libmetrics.observe_pubkey_lookup("walked", n)
+        libmetrics.observe_pubkey_lookup(
+            "walked" if hit is not None else "uncached", n)
         return hit
 
     def _memo_get(self, column: bytes, width: int):
@@ -738,7 +738,22 @@ class PubkeyTableCache:
             # still missing. A key evicted between iterations (another
             # thread filling the arena mid-build) sends us around again;
             # with in_use pinned per call that is vanishingly rare.
-            m = len(to_build)
+            tables, oks = self._build(builder, to_build)
+            built.append((to_build, tables, oks))
+            built_keys.update(to_build)
+        return None  # churn won the race 3x: uncached kernel fallback
+
+    def _build(self, builder, to_build: list[bytes]):
+        """One builder launch for ``to_build``, padded to its bucket:
+        (tables, oks) on the device, a malformed key's ``ok`` cleared.
+        The ``table_build`` phase (span ``verify.table_build``, field
+        ``keys``) times the host buffer and the launch's dispatch; its
+        backend is ``arena`` whichever verify path asked, since it lies
+        inside that path's ``pack`` phase and is not one of its tiles."""
+        import jax.numpy as jnp
+
+        m = len(to_build)
+        with libmetrics.verify_phase("table_build", "arena", keys=m):
             size = _builder_bucket(m)
             buf = np.zeros((32, size), np.uint8)
             for j, pk in enumerate(to_build):
@@ -747,16 +762,13 @@ class PubkeyTableCache:
             self.builds += 1
             tables, oks = builder(buf)
             libdevstats.record_h2d(buf.nbytes)
-            import jax.numpy as jnp
-
             host_wellformed = np.array(
                 [len(pk) == 32 for pk in to_build] + [True] * (size - m),
                 bool,
             )
             oks = jnp.logical_and(oks, jnp.asarray(host_wellformed))
-            built.append((to_build, tables, oks))
-            built_keys.update(to_build)
-        return None  # churn won the race 3x: uncached kernel fallback
+        libmetrics.observe_tables_built(m)
+        return tables, oks
 
 
 _PUBKEY_CACHE = PubkeyTableCache()

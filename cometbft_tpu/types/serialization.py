@@ -113,6 +113,7 @@ def _valset_dec(payload: dict) -> ValidatorSet:
     vs.validators = list(payload["validators"])
     vs._total = None
     vs._root_memo = None  # _valset_enc never writes it
+    vs._addr_memo = None  # nor this
     vs.proposer = None
     addr = payload["proposer_address"]
     if addr:

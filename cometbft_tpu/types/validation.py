@@ -163,6 +163,8 @@ def _select_lanes(vals, commit, needed, count_all, by_index):
     seen: dict[int, int] = {}
     tallied = 0
     validators = vals.validators
+    # one address index for the whole walk: a lookup per signature
+    index = None if by_index else vals.address_index()
     for idx, cs in enumerate(commit.signatures):
         flag = cs.block_id_flag
         if flag != BLOCK_ID_FLAG_COMMIT and (
@@ -172,9 +174,10 @@ def _select_lanes(vals, commit, needed, count_all, by_index):
         if by_index:
             val = validators[idx]
         else:
-            val_idx, val = vals.get_by_address(cs.validator_address)
-            if val is None:
+            val_idx = index.get(cs.validator_address, -1)
+            if val_idx < 0:
                 continue
+            val = validators[val_idx]
             if val_idx in seen:
                 return idxs, lane_vals, tallied, VerificationError(
                     f"double vote from validator {val_idx} "

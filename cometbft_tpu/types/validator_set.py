@@ -9,6 +9,9 @@ Reference: types/validator.go, types/validator_set.go:
 * set hash = merkle root of SimpleValidator proto encodings
   (validator.go:117-133), kept per set behind a check of what the leaves
   were made of (ValidatorSet.hash);
+* GetByAddress answers from an address -> first index map, built on the
+  first lookup and kept behind a check of the addresses it was built from
+  (ValidatorSet.address_index);
 * updates: changed/added vals merged, added vals start at
   -1.125*new-total priority (validator_set.go:477-495).
 
@@ -105,6 +108,8 @@ class ValidatorSet:
         self._total: int | None = None
         # (root, pub_keys, voting_powers) of the last hash(); see hash()
         self._root_memo: tuple[bytes, tuple, tuple] | None = None
+        # (addresses, address -> index) of the last address_index()
+        self._addr_memo: tuple[list[bytes], dict[bytes, int]] | None = None
         if self.validators:
             self.increment_proposer_priority(1)
 
@@ -128,10 +133,35 @@ class ValidatorSet:
         return self._total
 
     def get_by_address(self, address: bytes) -> tuple[int, Validator | None]:
-        for i, v in enumerate(self.validators):
-            if v.address == address:
-                return i, v
-        return -1, None
+        """The first validator with ``address`` and its index, or
+        (-1, None) (validator_set.go GetByAddress)."""
+        idx = self.address_index().get(address, -1)
+        return (idx, self.validators[idx]) if idx >= 0 else (-1, None)
+
+    def address_index(self) -> dict[bytes, int]:
+        """Each address's first index in the set; the caller only reads it.
+
+        Built on the first call, never by construction, ``copy`` or
+        ``update_with_change_set``. The set keeps it with a witness, as
+        ``hash()`` keeps its root: the ordered addresses it was built
+        from. Every call lists the present addresses and compares them with
+        the witness (element by element, the same bytes objects compare by
+        identity), so a change made in place (a validator replaced, added,
+        removed or reordered, an address rewritten) is seen by the next
+        call, which builds the map again. A caller that looks up many
+        addresses at once (a commit walk) takes the map once, and a set of
+        10,000 validators answers each lookup from a dict instead of a
+        scan. ``copy()`` carries the memo, ``update_with_change_set`` drops
+        it, and types/serialization never writes it.
+        """
+        addrs = [v.address for v in self.validators]
+        memo = self._addr_memo
+        if memo is not None and memo[0] == addrs:
+            return memo[1]
+        # reversed, so that the first index of a repeated address wins
+        index = dict(zip(reversed(addrs), range(len(addrs) - 1, -1, -1)))
+        self._addr_memo = (addrs, index)
+        return index
 
     def get_by_index(self, index: int) -> Validator | None:
         if 0 <= index < len(self.validators):
@@ -182,11 +212,19 @@ class ValidatorSet:
         cp.validators = [v.copy() for v in self.validators]
         cp.proposer = None
         cp._total = self._total
-        # Validator.copy() keeps the pub_key object: the witness holds
+        # Validator.copy() keeps the pub_key and address objects: the
+        # witnesses hold
         cp._root_memo = self._root_memo
+        cp._addr_memo = self._addr_memo
         if self.proposer is not None:
-            idx, _ = cp.get_by_address(self.proposer.address)
-            cp.proposer = cp.validators[idx] if idx >= 0 else self.proposer.copy()
+            # one pass for one address: a lookup would build the copy's
+            # address index, which copying never does
+            addr = self.proposer.address
+            cp.proposer = next(
+                (v for v in cp.validators if v.address == addr), None
+            )
+            if cp.proposer is None:
+                cp.proposer = self.proposer.copy()
         return cp
 
     # --- proposer rotation ---------------------------------------------------
@@ -273,9 +311,14 @@ class ValidatorSet:
                 raise ValueError("negative voting power in update")
             by_addr[c.address] = c
 
+        # the present validators by address: membership here, then the
+        # merge (the address index is not built for an update)
+        merged: dict[bytes, Validator] = {
+            v.address: v for v in self.validators
+        }
         removals = {a for a, c in by_addr.items() if c.voting_power == 0}
         for addr in removals:
-            if not self.has_address(addr):
+            if addr not in merged:
                 raise ValueError(
                     f"cannot remove unknown validator {addr.hex()}"
                 )
@@ -285,16 +328,13 @@ class ValidatorSet:
             upd = by_addr.get(v.address)
             new_total += v.voting_power if upd is None else upd.voting_power
         for addr, c in by_addr.items():
-            if not self.has_address(addr):
+            if addr not in merged:
                 new_total += c.voting_power
         if new_total > MAX_TOTAL_VOTING_POWER:
             raise ValueError("updates exceed max total voting power")
         if new_total == 0:
             raise ValueError("updates would remove all validators")
 
-        merged: dict[bytes, Validator] = {
-            v.address: v for v in self.validators
-        }
         for addr, c in by_addr.items():
             if addr in removals:
                 merged.pop(addr, None)
@@ -310,6 +350,7 @@ class ValidatorSet:
         self.validators = sorted(merged.values(), key=_sort_key)
         self._total = None
         self._root_memo = None
+        self._addr_memo = None
         self.rescale_priorities(
             PRIORITY_WINDOW_SIZE_FACTOR * self.total_voting_power()
         )

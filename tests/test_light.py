@@ -249,6 +249,51 @@ class TestClient:
         for h in heights:
             assert client.trusted_store.light_block(h).height == h
 
+    def test_bisection_attempts_and_trusting_checks_are_counted(self):
+        """light_bisection_attempts_total{outcome} counts each attempt of
+        the bisection once; the trusting phase times each skipping step's
+        check of the trusted set, refused for trust or not."""
+        from cometbft_tpu.libs import metrics as libmetrics
+
+        m = libmetrics.NodeMetrics()
+        libmetrics.push_node_metrics(m)
+        try:
+            blocks = helpers.make_light_chain(20, rotate=2)
+            client, _ = make_client(blocks)
+            client.verify_light_block_at_height(20, now_after(blocks, 20))
+            heights = [b.height for b in client.latest_trace]
+            tally = m.light_bisection_attempts_total.labels
+            verified = tally("verified").value()
+            cant_trust = tally("cant_trust").value()
+            assert verified == len(heights) - 1
+            assert cant_trust >= 1
+            assert tally("refused").value() == 0
+            skips = sum(1 for a, b in zip(heights, heights[1:]) if b > a + 1)
+            trusting = m.light_verify_phase_seconds.labels("trusting")
+            assert trusting._n == skips + cant_trust
+            # every commit past the root with its first signature altered:
+            # the first attempt that passes the trust level is refused
+            forged = dict(blocks)
+            for h in range(2, 21):
+                commit = blocks[h].signed_header.commit
+                sigs = list(commit.signatures)
+                sig = sigs[0].signature
+                sigs[0] = dataclasses.replace(
+                    sigs[0], signature=bytes([sig[0] ^ 1]) + sig[1:])
+                forged[h] = dataclasses.replace(
+                    blocks[h],
+                    signed_header=dataclasses.replace(
+                        blocks[h].signed_header,
+                        commit=dataclasses.replace(commit, signatures=sigs),
+                    ),
+                )
+            client, _ = make_client(forged)
+            with pytest.raises(Exception):
+                client.verify_light_block_at_height(20, now_after(blocks, 20))
+            assert tally("refused").value() == 1
+        finally:
+            libmetrics.pop_node_metrics(m)
+
     def test_backwards_verification(self):
         blocks = helpers.make_light_chain(10)
         client, _ = make_client(blocks, trust_height=8)

@@ -372,3 +372,30 @@ def test_memo_under_churn_never_hands_out_a_foreign_slot(monkeypatch):
     assert not errs, errs
     assert cache.evictions > 0 and cache.hits > 0
     assert len(cache._slots) <= cache.capacity
+
+
+def test_builds_and_give_ups_are_counted(fresh_cache):
+    """ops_pubkey_tables_built_total counts the keys of each builder launch
+    (not its bucket's padding) and the table_build phase times it; a lookup
+    that gives up counts its lanes as "uncached" in
+    ops_pubkey_lookup_lanes_total."""
+    from cometbft_tpu.libs import metrics as libmetrics
+
+    m = libmetrics.NodeMetrics()
+    libmetrics.push_node_metrics(m)
+    try:
+        pks, _msgs, _sigs = make_batch(5)
+        assert fresh_cache.lookup(pks) is not None
+        assert m.pubkey_tables_built_total.value() == 5
+        phase = m.verify_phase_seconds.labels("table_build", "arena")
+        assert phase._n == 1
+        assert fresh_cache.lookup(pks) is not None  # all resident
+        assert m.pubkey_tables_built_total.value() == 5
+        lanes = m.pubkey_lookup_lanes_total.labels
+        assert lanes("uncached").value() == 0
+        too_many = [i.to_bytes(32, "little") for i in range(65)]
+        assert fresh_cache.lookup(too_many) is None
+        assert lanes("uncached").value() == 65
+        assert m.pubkey_tables_built_total.value() == 5
+    finally:
+        libmetrics.pop_node_metrics(m)
