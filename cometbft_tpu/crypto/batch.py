@@ -760,13 +760,25 @@ _BATCH_BACKENDS: dict[str, type] = {
 }
 
 
+def _key_types(validator_set) -> frozenset:
+    """The key types of a set's validators: a ValidatorSet answers from
+    the profile it keeps behind a witness of its keys
+    (``ValidatorSet.key_types``); anything else is scanned."""
+    from ..types.validator_set import ValidatorSet
+
+    if isinstance(validator_set, ValidatorSet):
+        return validator_set.key_types()
+    return frozenset(
+        getattr(v.pub_key, "type", None)
+        for v in getattr(validator_set, "validators", [])
+    )
+
+
 def supports_commit_batch(validator_set) -> bool:
     """True when every key type in the set has a batch backend (a mixed
     set rides MixedBatchVerifier)."""
-    vals = getattr(validator_set, "validators", [])
-    return bool(vals) and all(
-        getattr(v.pub_key, "type", None) in _BATCH_BACKENDS for v in vals
-    )
+    types = _key_types(validator_set)
+    return bool(types) and all(t in _BATCH_BACKENDS for t in types)
 
 
 def create_commit_batch_verifier(validator_set) -> BatchVerifier:
@@ -776,10 +788,7 @@ def create_commit_batch_verifier(validator_set) -> BatchVerifier:
     the fused native happy path); mixed sets get MixedBatchVerifier —
     one launch where the reference falls back to per-signature verifies.
     """
-    types = {
-        getattr(v.pub_key, "type", None)
-        for v in getattr(validator_set, "validators", [])
-    }
+    types = _key_types(validator_set)
     if len(types) == 1:
         backend = _BATCH_BACKENDS.get(next(iter(types)))
         if backend is not None:

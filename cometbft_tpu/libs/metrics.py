@@ -453,6 +453,16 @@ class NodeMetrics:
         )
         for result in ("computed", "reused"):
             self.valset_hash_total.labels(result)
+        # ValidatorSet.key_types() calls by what answered: "computed" (the
+        # keys' types scanned) or "reused" (the set's kept profile, its
+        # witness checked). Both series exist from the start.
+        self.valset_key_types_total = r.counter(
+            "types_valset_key_types_total",
+            "ValidatorSet.key_types() calls by result",
+            label_names=("result",),
+        )
+        for result in ("computed", "reused"):
+            self.valset_key_types_total.labels(result)
         # lanes whose sign-bytes a commit check encoded, by encoder:
         # "batched" (Commit.vote_sign_bytes_many, one encoding a commit)
         # or "per_lane" (canonical.vote_sign_bytes lane by lane: the
@@ -1368,6 +1378,14 @@ def observe_codec_encode(metrics: NodeMetrics | None = None) -> None:
 def observe_valset_hash(reused: bool) -> None:
     """One ``ValidatorSet.hash()`` call: types_valset_hash_total{result}."""
     node_metrics().valset_hash_total.labels(
+        "reused" if reused else "computed"
+    ).inc()
+
+
+def observe_valset_key_types(reused: bool) -> None:
+    """One ``ValidatorSet.key_types()`` call:
+    types_valset_key_types_total{result}."""
+    node_metrics().valset_key_types_total.labels(
         "reused" if reused else "computed"
     ).inc()
 

@@ -114,6 +114,7 @@ def _valset_dec(payload: dict) -> ValidatorSet:
     vs._total = None
     vs._root_memo = None  # _valset_enc never writes it
     vs._addr_memo = None  # nor this
+    vs._types_memo = None  # nor this
     vs.proposer = None
     addr = payload["proposer_address"]
     if addr:

@@ -12,6 +12,9 @@ Reference: types/validator.go, types/validator_set.go:
 * GetByAddress answers from an address -> first index map, built on the
   first lookup and kept behind a check of the addresses it was built from
   (ValidatorSet.address_index);
+* the set of its keys' types, which picks a commit check's batch backend
+  (crypto/batch.create_commit_batch_verifier), kept the same way behind a
+  check of the keys (ValidatorSet.key_types);
 * updates: changed/added vals merged, added vals start at
   -1.125*new-total priority (validator_set.go:477-495).
 
@@ -110,6 +113,8 @@ class ValidatorSet:
         self._root_memo: tuple[bytes, tuple, tuple] | None = None
         # (addresses, address -> index) of the last address_index()
         self._addr_memo: tuple[list[bytes], dict[bytes, int]] | None = None
+        # (pub_keys, key types) of the last key_types()
+        self._types_memo: tuple[list, frozenset] | None = None
         if self.validators:
             self.increment_proposer_priority(1)
 
@@ -162,6 +167,39 @@ class ValidatorSet:
         index = dict(zip(reversed(addrs), range(len(addrs) - 1, -1, -1)))
         self._addr_memo = (addrs, index)
         return index
+
+    def key_types(self) -> frozenset:
+        """The ``type`` of each validator's key, as a set (None for a key
+        without one); the caller only reads it.
+
+        Computed on the first call and kept with a witness, as
+        ``address_index`` keeps its map: the ordered ``pub_key`` objects
+        it was computed from. Every call compares them with the present
+        keys (identity first; the key classes are frozen and compare by
+        class and bytes), so a key replaced in place on a ``Validator``,
+        or a validator added, removed or reordered, is seen by the next
+        call, which scans the types again. A commit check asks twice
+        (``crypto/batch.supports_commit_batch``, then the factory), and a
+        light client's bisection checks one set many times a step: at
+        10,000 keys the witness costs a fraction of the scan. One tuple
+        published in one store; ``copy()`` carries it,
+        ``update_with_change_set`` drops it, and types/serialization never
+        writes it.
+        """
+        # a comprehension: the slot read is specialised, about twice the
+        # speed of list(map(attrgetter("pub_key"), ...))
+        pub_keys = [v.pub_key for v in self.validators]
+        memo = self._types_memo
+        reused = memo is not None and memo[0] == pub_keys
+        if reused:
+            types = memo[1]
+        else:
+            types = frozenset(
+                [getattr(pk, "type", None) for pk in pub_keys]
+            )
+            self._types_memo = (pub_keys, types)
+        libmetrics.observe_valset_key_types(reused)
+        return types
 
     def get_by_index(self, index: int) -> Validator | None:
         if 0 <= index < len(self.validators):
@@ -216,6 +254,7 @@ class ValidatorSet:
         # witnesses hold
         cp._root_memo = self._root_memo
         cp._addr_memo = self._addr_memo
+        cp._types_memo = self._types_memo
         if self.proposer is not None:
             # one pass for one address: a lookup would build the copy's
             # address index, which copying never does
@@ -351,6 +390,7 @@ class ValidatorSet:
         self._total = None
         self._root_memo = None
         self._addr_memo = None
+        self._types_memo = None
         self.rescale_priorities(
             PRIORITY_WINDOW_SIZE_FACTOR * self.total_voting_power()
         )
